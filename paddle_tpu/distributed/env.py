@@ -148,17 +148,7 @@ def init_parallel_env(ndev_per_proc=None):
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
         if ndev_per_proc is None:
             ndev_per_proc = _env_int("PADDLE_LOCAL_DEVICES", 1)
-        try:
-            jax.config.update("jax_num_cpu_devices", int(ndev_per_proc))
-        except AttributeError:
-            # jax builds without the config option take the device count
-            # from XLA_FLAGS; only effective before backend init, which
-            # holds here — workers call this before touching devices
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags + " --xla_force_host_platform_device_count=%d"
-                    % int(ndev_per_proc)).strip()
+        jax.config.update("jax_num_cpu_devices", int(ndev_per_proc))
     if coord_addr:
         rank, world, coordinator = _coord_bootstrap()
         if world <= 1:

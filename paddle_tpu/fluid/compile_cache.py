@@ -23,13 +23,19 @@ re-formed mesh — therefore MISSES cleanly instead of loading garbage.
 
 Robustness contract: a corrupted, truncated or otherwise unloadable
 entry is never fatal — it is quarantined (renamed aside, counted in
-``compile_cache_quarantined_total``) and the step compiles live.
-Concurrent processes sharing one cache dir are safe: reads see either
-a complete entry or none (atomic rename), and the last writer wins.
+``compile_cache_quarantined_total``) and the step compiles live. The
+same holds for an entry that loads but fails its first call. Concurrent
+processes sharing one cache dir are safe: reads see either a complete
+entry or none (atomic rename), and the last writer wins.
 
 Disabled (``PADDLE_COMPILE_CACHE_DIR`` unset) the module is inert:
 ``wrap_jit`` hands back the jit object unchanged, so behavior is
 bit-identical to a build without this file.
+
+Beside this private tier sits JAX's own persistent compilation cache
+(every ``jit`` in the process, keyed by JAX). ``use_jax_cache`` is what
+an entry-point script calls to give it a home; importing the package
+never does.
 """
 
 import contextlib
@@ -46,6 +52,7 @@ __all__ = [
     "ENV_DIR", "ENV_MAX_BYTES", "ENTRY_SUFFIX", "PRELOWERED_DIRNAME",
     "cache_dir", "enabled", "active", "override_dir", "program_digest",
     "step_key", "entry_path", "wrap_jit", "prewarm", "disk_hit_count",
+    "use_jax_cache",
 ]
 
 logger = logging.getLogger(__name__)
@@ -57,7 +64,8 @@ QUARANTINE_SUFFIX = ".quarantined"
 PRELOWERED_DIRNAME = "__prelowered__"   # model-adjacent read-only tier
 # Bump on any incompatible change to the entry pickle layout — old
 # entries then miss via the key hash AND fail the format check.
-FORMAT_VERSION = 1
+# 2: entries record the ids of the devices they were compiled for.
+FORMAT_VERSION = 2
 
 # -- monitor series -----------------------------------------------------------
 _M_DISK_HIT = _monitor.counter(
@@ -251,8 +259,15 @@ def _load_entry(path):
             deserialize_and_load,
         )
 
-        exe = deserialize_and_load(entry["payload"], entry["in_tree"],
-                                   entry["out_tree"])
+        import jax
+
+        # execution_devices=None would mean EVERY device of the backend:
+        # a one-device step reloaded on a multi-device host would load
+        # and then die at its first call expecting one shard per device
+        by_id = {d.id: d for d in jax.devices()}
+        exe = deserialize_and_load(
+            entry["payload"], entry["in_tree"], entry["out_tree"],
+            execution_devices=[by_id[i] for i in entry["devices"]])
     except Exception as e:
         logger.warning("compile cache entry %s is unloadable (%s: %s); "
                        "quarantining and compiling live",
@@ -277,9 +292,12 @@ def _save_entry(dirname, key, compiled, label=""):
         from jax.experimental.serialize_executable import serialize
 
         payload, in_tree, out_tree = serialize(compiled)
+        # the attribute serialize() itself reads the executable from
+        devices = [d.id for d in
+                   compiled._executable._unloaded_executable.device_list]
         blob = pickle.dumps(
             {"format": FORMAT_VERSION, "label": label, "payload": payload,
-             "in_tree": in_tree, "out_tree": out_tree},
+             "in_tree": in_tree, "out_tree": out_tree, "devices": devices},
             protocol=pickle.HIGHEST_PROTOCOL)
         os.makedirs(dirname, exist_ok=True)
         from . import io as _io
@@ -336,11 +354,11 @@ def wrap_jit(jfn, key, read_dirs=None, label=""):
 
     The executor/compiler call this at step-build time (i.e. on an
     in-memory cache MISS). The first real call resolves the executable
-    once: try each read dir then the write dir for ``key``; a loadable
-    entry skips trace AND compile (disk hit), otherwise the step is
-    ``lower().compile()``d live, serialized, and saved (disk miss).
-    Subsequent calls go straight to the resolved executable — the same
-    object a plain ``jit`` dispatch would use.
+    once: try each read dir then the write dir for ``key``; an entry
+    that loads and runs skips trace AND compile (disk hit), otherwise
+    the step is ``lower().compile()``d live, serialized, and saved (disk
+    miss). Subsequent calls go straight to the resolved executable —
+    the same object a plain ``jit`` dispatch would use.
 
     With no cache dir configured (and no ``read_dirs``) or ``key is
     None``, returns ``jfn`` unchanged — the disabled path is
@@ -355,37 +373,46 @@ def wrap_jit(jfn, key, read_dirs=None, label=""):
     resolved = []
     lock = threading.Lock()
 
-    def _resolve(args):
+    def _first_call(args):
+        """Resolve the executable AND run it once. A disk entry counts
+        as a hit only after its first call returns: one that loads but
+        cannot execute here is quarantined like any other bad entry.
+        (A call that fails only after consuming donated buffers leaves
+        the live compile nothing to run on; that error propagates.)"""
         for d in dirs:
             path = entry_path(d, key)
             if not os.path.exists(path):
                 continue
             exe = _load_entry(path)
-            if exe is not None:
-                _M_DISK_HIT.inc()
-                _M_HIT_TIER_DISK.inc()
-                return exe
+            if exe is None:
+                continue
+            try:
+                out = exe(*args)
+            except Exception:
+                logger.warning("compile cache entry %s loaded but failed "
+                               "its first call; quarantining and "
+                               "compiling live", path, exc_info=True)
+                _quarantine(path)
+                continue
+            _M_DISK_HIT.inc()
+            _M_HIT_TIER_DISK.inc()
+            return exe, out
         _M_DISK_MISS.inc()
         _M_MISS_TIER_DISK.inc()
-        try:
-            compiled = jfn.lower(*args).compile()
-        except Exception as e:
-            # AOT lowering is the same trace a plain call does, so this
-            # is rare (e.g. an executable XLA refuses to serialize);
-            # falling back to the undecorated jit keeps the run alive.
-            logger.warning("compile cache AOT lower/compile failed "
-                           "(%s: %s); running uncached",
-                           type(e).__name__, e)
-            return jfn
+        # AOT lowering is the same trace a plain call does: a failure
+        # here would have failed the undecorated jit the same way
+        compiled = jfn.lower(*args).compile()
         if write_dir:
             _save_entry(write_dir, key, compiled, label=label)
-        return compiled
+        return compiled, compiled(*args)
 
     def call(*args):
         if not resolved:
             with lock:
                 if not resolved:
-                    resolved.append(_resolve(args))
+                    exe, out = _first_call(args)
+                    resolved.append(exe)
+                    return out
         return resolved[0](*args)
 
     return call
@@ -431,3 +458,24 @@ def disk_hit_count():
     """Current value of the disk-hit counter (serving warm-up snapshots
     it around the ladder to report how many compiles a restart skipped)."""
     return _M_DISK_HIT.value
+
+
+# -- JAX's own persistent compilation cache -----------------------------------
+def use_jax_cache():
+    """Give JAX's persistent compilation cache a home, for entry-point
+    scripts (``chip_smoke.py``, ``bench.py``) — never called on import,
+    so a test run writes no executables into the checkout.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it
+    and nothing is set here. Otherwise the cache goes to ``.jax_cache``
+    at the root of the checkout: a path fixed by this file's location,
+    because the directory is part of what a later process must find
+    again. Returns the directory in use."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(root, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir

@@ -16,8 +16,6 @@ import numpy as np
 from . import compile_cache as _compile_cache
 from . import monitor as _monitor
 from . import rng as _rng
-from .. import jax_compat as _jax_compat
-from ..jax_compat import shard_map as _shard_map_compat
 
 __all__ = ["CompiledProgram", "BuildStrategy", "ExecutionStrategy",
            "UnsupportedStrategyError", "RESERVED_AXES",
@@ -1102,7 +1100,7 @@ class CompiledProgram:
                                       for n in params}
                 jfn_box["r_specs"] = {n: rest_specs.get(n, P())
                                       for n in rest}
-                smapped = _shard_map_compat(
+                smapped = jax.shard_map(
                     kernel, mesh=mesh,
                     in_specs=(jfn_box["p_specs"], jfn_box["r_specs"],
                               {n: mb_spec for n in mbf},
@@ -1111,7 +1109,7 @@ class CompiledProgram:
                                jfn_box["r_specs"], P()),
                     check_vma=False)
                 donate = ((0, 1) if self._build_strategy.enable_inplace
-                          and _jax_compat.SHARD_MAP_DONATION_OK else ())
+                          else ())
                 jfn_box["jfn"] = self._cache_wrap(
                     jax.jit(smapped, donate_argnums=donate), "pipeline")
             put_state = lambda tree, specs: {
@@ -1161,15 +1159,14 @@ class CompiledProgram:
                     out.append(jax.lax.pmax(f, axis))
             return out, new_state, new_rng
 
-        smapped = _shard_map_compat(
+        smapped = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=({n: P() for n in state_names}, feed_specs, P()),
             out_specs=([P() for _ in fetch_names], {n: P() for n in state_names}, P()),
             check_vma=False,
         )
-        donate = ((0,) if self._build_strategy.enable_inplace
-                  and _jax_compat.SHARD_MAP_DONATION_OK else ())
+        donate = (0,) if self._build_strategy.enable_inplace else ()
         jfn = self._cache_wrap(jax.jit(smapped, donate_argnums=donate),
                                "shard_map")
         feed_shardings = {n: NamedSharding(mesh, feed_specs[n]) for n in feed}
@@ -1544,7 +1541,7 @@ class CompiledProgram:
                         (stk_mb, stk_full), length=k)
                     return traj, p, r, rk
 
-                smapped = _shard_map_compat(
+                smapped = jax.shard_map(
                     window, mesh=mesh,
                     in_specs=(jfn_box["p_specs"], jfn_box["r_specs"],
                               {n: stk_mb_spec for n in stk_mb},
@@ -1555,7 +1552,7 @@ class CompiledProgram:
                                jfn_box["r_specs"], P()),
                     check_vma=False)
                 donate = ((0, 1) if self._build_strategy.enable_inplace
-                          and _jax_compat.SHARD_MAP_DONATION_OK else ())
+                          else ())
                 jfn_box["jfn"] = self._cache_wrap(
                     jax.jit(smapped, donate_argnums=donate),
                     "pipeline_batched")

@@ -457,10 +457,23 @@ class _WindowPrefetch:
 
 
 class Executor:
-    """Reference ``executor.py:418``. ``place`` is advisory — JAX device
-    placement is controlled by the default backend / shardings."""
+    """Reference ``executor.py:418``. JAX device placement is controlled
+    by the default backend / shardings, so ``place`` selects nothing —
+    but a ``TPUPlace`` on a host whose default backend is not a TPU
+    raises instead of training on the CPU without a word. ``None`` and
+    ``CPUPlace`` run wherever JAX runs."""
 
     def __init__(self, place=None):
+        from . import TPUPlace
+
+        if isinstance(place, TPUPlace):
+            import jax
+
+            platform = jax.devices()[0].platform
+            if platform != "tpu":
+                raise RuntimeError(
+                    "Executor(%r): JAX's default backend is %r, not a "
+                    "tpu; pass no place to run there" % (place, platform))
         self.place = place
         self._cache = {}
         # extra read-only disk-cache tiers consulted on a memory miss

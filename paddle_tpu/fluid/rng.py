@@ -43,11 +43,8 @@ def _impl():
     # executor/tracer), never from graph construction
     import jax
 
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = "cpu"
-    _IMPL = "unsafe_rbg" if platform == "tpu" else "threefry2x32"
+    _IMPL = ("unsafe_rbg" if jax.devices()[0].platform == "tpu"
+             else "threefry2x32")
     return _IMPL
 
 

@@ -86,7 +86,7 @@ batched-GEMM chain end-to-end:
   einsum chain 87-89 ms | resident 122 ms | per-head fused 126 ms |
   packed-chunked 157 ms; ablation puts the attention core at ~16 ms of
   the 88 ms step, so the chain leaves little on the table that kernel
-  relayout/latency costs don't eat (full analysis: PROFILE_r05.md §1).
+  relayout/latency costs don't eat.
 They are kept as correct, tested building blocks for shapes with
 larger S·heads per block; BERT's ``use_fused_attention="auto"`` picks
 the GEMM chain below S=256.
@@ -100,14 +100,40 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _MAX_FUSED_SEQ = 1024
 
 
 def _interpret():
     """PADDLE_TPU_PALLAS_INTERPRET=1 runs the kernels through the pallas
-    interpreter (CPU CI exercises the real kernel bodies)."""
-    return os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "") == "1"
+    interpreter (CPU CI exercises the real kernel bodies). On a TPU it
+    is an error, not a mode: a stray setting would leave the chip idle
+    behind the interpreter and say nothing."""
+    on = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "") == "1"
+    if on and jax.devices()[0].platform == "tpu":
+        raise RuntimeError(
+            "PADDLE_TPU_PALLAS_INTERPRET=1 on a tpu platform: the Pallas "
+            "kernels would run interpreted instead of compiled; unset it")
+    return on
+
+
+KERNEL_TIERS = ("block", "block_bwd", "long", "long_bwd", "flash",
+                "flash_bwd", "decode", "paged")
+
+
+def _count_kernel(tier):
+    """Trace-time record of which Pallas tier a dispatch took (one per
+    traced ``pallas_call`` site, not per step) — how a caller proves the
+    kernel, not the jnp fallback, is in its compiled program."""
+    from ..fluid import monitor as _monitor
+
+    assert tier in KERNEL_TIERS, tier
+    _monitor.counter(
+        "attn_kernel_dispatch_total",
+        "Pallas attention kernel dispatches by tier (trace-time: one "
+        "per traced program, not per step)", labels={"tier": tier}).inc()
 
 
 _ATTN_FORCE_VALUES = ("flash", "packed", "decode", "paged", "ring",
@@ -130,17 +156,7 @@ def _attn_force():
 
 
 def _supports_pallas():
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    except Exception:
-        return False
-    if _interpret():
-        return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return _interpret() or jax.devices()[0].platform == "tpu"
 
 
 def _uniform_from_bits(bits):
@@ -233,7 +249,6 @@ def _fallback_attention(q, k, v, bias, scale, p_drop, seed):
 def _attn_block_fwd(q, k, v, bias_b, seed_ref, scale, p_drop, stream):
     """Shared per-(batch-block, head) forward math: q/k/v [Bb, S, d],
     bias_b [Bb, Sq|1, S] additive. Returns o [Bb, S, d] f32."""
-    from jax.experimental.pallas import tpu as pltpu
 
     dn = (((2,), (2,)), ((0,), (0,)))            # batched q·kᵀ
     # matmuls in the input dtype (bf16 MXU under AMP), f32 accumulate
@@ -257,7 +272,6 @@ def _attn_block_bwd(q, k, v, do, bias_b, seed_ref, scale, p_drop, stream):
     recomputed flash-style, dropout mask regenerated from the forward's
     stream). Returns (dq, dk, dv, ds) — ds [Bb, S, S] f32 pre-reduction
     for the bias gradient."""
-    from jax.experimental.pallas import tpu as pltpu
 
     dn_qk = (((2,), (2,)), ((0,), (0,)))
     s = jax.lax.dot_general(q, k, dn_qk,
@@ -295,7 +309,6 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
                 scale, p_drop, n_heads):
     """One grid step = a BLOCK of batches for one head: batched matmuls
     keep the MXU busy (a single (b, h) pair at S=128 is DMA-bound)."""
-    from jax.experimental import pallas as pl
 
     b, h = pl.program_id(0), pl.program_id(1)
     o = _attn_block_fwd(q_ref[:, 0], k_ref[:, 0], v_ref[:, 0],
@@ -307,8 +320,6 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dbias_ref, *, scale, p_drop,
                 n_heads, acc_heads, reduce_rows):
-    from jax.experimental import pallas as pl
-
     b, h = pl.program_id(0), pl.program_id(1)
     dq, dk, dv, ds = _attn_block_bwd(
         q_ref[:, 0], k_ref[:, 0], v_ref[:, 0], do_ref[:, 0],
@@ -365,8 +376,6 @@ def _fwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
     (batch, head) sit in VMEM (S·d is small even when S² is not); each
     step computes one [Qb, S] score tile and its softmax in one pass —
     no online recurrence, no [S, S] materialization."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     q = q_ref[0, 0]                               # [Qb, d]
     k = k_ref[0, 0]                               # [S, d]
@@ -393,8 +402,6 @@ def _bwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     """Long-sequence backward: q-tile is the fastest grid dim, so the
     (b, h)-indexed dk/dv blocks are revisited across tiles and accumulate
     in VMEM (same revisit-accumulate idiom as dbias in _bwd_kernel)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     q = q_ref[0, 0]                               # [Qb, d]
     k = k_ref[0, 0]                               # [S, d]
@@ -474,8 +481,6 @@ def _bwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 
 
 def _long_specs(q, bias):
-    from jax.experimental import pallas as pl
-
     B, H, S, d = q.shape
     QB = _long_qb(S, d)
     nq = S // QB
@@ -508,9 +513,7 @@ def _use_long_kernel(q, p_drop, bias):
 
 
 def _pallas_attention_long(q, k, v, bias, scale, p_drop, seed):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    _count_kernel("long")
     B, H, S, d = q.shape
     grid, qspec, kvspec, bspec, nq, QB = _long_specs(q, bias)
     return pl.pallas_call(
@@ -526,9 +529,7 @@ def _pallas_attention_long(q, k, v, bias, scale, p_drop, seed):
 
 
 def _pallas_attention_long_bwd(q, k, v, bias, seed, do, scale, p_drop):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    _count_kernel("long_bwd")
     B, H, S, d = q.shape
     grid, qspec, kvspec, bspec, nq, QB = _long_specs(q, bias)
     acc_heads = bias.shape[1] == 1
@@ -562,6 +563,13 @@ def _pallas_attention_long_bwd(q, k, v, bias, seed, do, scale, p_drop):
 # the k-sweep; Tb=1024 still fits scoped VMEM with the dropout PRNG
 # tile live (22.2 ms measured with p=0.1).
 _FLASH_BLOCK_CANDIDATES = (1024, 512, 256, 128)
+
+# Scoped-VMEM ceiling for the flash kernels. Mosaic's default is 16 MB;
+# at Tb=1024 with f32 operands and the dropout tile live the dk/dv
+# kernel asks for 17.97 MB (v5e, S=8192, libtpu 0.0.34 — bf16 operands,
+# and f32 at S=4096, stay under 16). The chip has 128 MiB of VMEM.
+_FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=32 * 1024 * 1024)
 
 
 def _flash_block(S):
@@ -607,8 +615,6 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     and the row logsumexp L are written on the last k-tile. Dropout
     masks only the value accumulation — the denominator uses undropped
     weights (same semantics as _blockwise_attention)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     j = pl.program_id(3)
     q = q_ref[0, 0]                               # [Tb, d]
@@ -655,8 +661,6 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     steps. Probabilities regenerate from the saved logsumexp: p =
     exp(s - L) is exactly softmax without a second online pass. Also
     emits per-(q-tile) dbias partials, reduced outside the kernel."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     j = pl.program_id(3)
     q = q_ref[0, 0]                               # [Tb, d]
@@ -702,8 +706,6 @@ def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     q-tile steps. The PRNG seed uses the same (i, j) formula as the
     forward, so the regenerated mask is bit-exact despite the
     transposed grid order."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     j, i = pl.program_id(2), pl.program_id(3)
     q = q_ref[0, 0]                               # [Tb, d]
@@ -747,8 +749,6 @@ def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 
 
 def _flash_specs(q, bias):
-    from jax.experimental import pallas as pl
-
     B, H, S, d = q.shape
     TB = _flash_block(S)
     nt = S // TB
@@ -767,9 +767,8 @@ def _flash_specs(q, bias):
 
 def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed):
     """Returns (o, lse): lse [B, H, S] f32 feeds the split backward."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
+    _count_kernel("flash")
     B, H, S, d = q.shape
     TB, nt, qspec, kspec, bspec, rowspec = _flash_specs(q, bias)
     f32 = jnp.float32
@@ -785,15 +784,14 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed):
         scratch_shapes=[pltpu.VMEM((TB, d), f32),
                         pltpu.VMEM((TB, 1), f32),
                         pltpu.VMEM((TB, 1), f32)],
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=_interpret(),
     )(seed, q, k, v, bias)
 
 
 def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
                                 p_drop):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    _count_kernel("flash_bwd")
     B, H, S, d = q.shape
     TB, nt, qspec, kspec, bspec, rowspec = _flash_specs(q, bias)
     f32 = jnp.float32
@@ -815,6 +813,7 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
         out_specs=[qspec, dbpspec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct((B, H * nt, 1, S), f32)],
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=_interpret(),
     )(seed, q, k, v, bias, do, lse, dd)
     # transposed grid: k-tile is the SLOW tile dim so dk/dv accumulate
@@ -835,6 +834,7 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
         out_specs=[kspec_t, kspec_t],
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct(q.shape, f32)],
+        compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=_interpret(),
     )(seed, q, k, v, bias, do, lse, dd)
     dbias = jnp.sum(dbp.reshape(B, H, nt, S), axis=2,
@@ -920,8 +920,6 @@ def _packed_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
     dot over (Bb, H): scores -> softmax -> dropout -> PV with the
     [Bb, H, S, S] tile never leaving VMEM; the head split/merge is an
     in-VMEM relayout, so HBM only ever sees the packed layout."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     q = _split_heads_vmem(q_ref[...])             # [Bb*H, S, d], b-major
     k = _split_heads_vmem(k_ref[...])
@@ -960,9 +958,6 @@ def _packed_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
 def _packed_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                        dq_ref, dk_ref, dv_ref, dbias_ref, *, scale, p_drop,
                        n_heads):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     q = _split_heads_vmem(q_ref[...])             # [Bb*H, S, d], b-major
     k = _split_heads_vmem(k_ref[...])
     v = _split_heads_vmem(v_ref[...])
@@ -1027,8 +1022,6 @@ def _packed_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 
 
 def _packed_specs4(B, S, H, d, bias, Bb):
-    from jax.experimental import pallas as pl
-
     # q/k/v ride as 4D [B, S, H, d] bitcast views (free outside the
     # kernel): block minor dims (H, d) equal the array dims, satisfying
     # the TPU block-shape rule, and the kernel's head transpose happens
@@ -1040,9 +1033,6 @@ def _packed_specs4(B, S, H, d, bias, Bb):
 
 def _pallas_attention_packed(q3, k3, v3, bias, scale, p_drop, seed,
                              n_heads):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     B, S, HD = q3.shape
     d = HD // n_heads
     Bb = _packed_bb(B, S, HD, n_heads)
@@ -1063,9 +1053,6 @@ def _pallas_attention_packed(q3, k3, v3, bias, scale, p_drop, seed,
 
 def _pallas_attention_packed_bwd(q3, k3, v3, bias, seed, do, scale,
                                  p_drop, n_heads):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     B, S, HD = q3.shape
     d = HD // n_heads
     Bb = _packed_bb(B, S, HD, n_heads)
@@ -1154,23 +1141,18 @@ def _use_res_kernel(q3, n_heads, p_drop, bias):
 def _res_pair(ref, hp, d):
     """Load the 128-lane-aligned head PAIR ``hp`` and split it into two
     [Bb, S, d] halves (static sub-128 slices relayout in VMEM)."""
-    from jax.experimental import pallas as pl
 
     pair = ref[:, :, pl.dslice(hp * 2 * d, 2 * d)]
     return pair[:, :, :d], pair[:, :, d:]
 
 
 def _res_put_pair(ref, hp, d, a, b):
-    from jax.experimental import pallas as pl
-
     ref[:, :, pl.dslice(hp * 2 * d, 2 * d)] = jnp.concatenate(
         [a, b], axis=-1)
 
 
 def _res_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
                     scale, p_drop, n_heads, d):
-    from jax.experimental import pallas as pl
-
     b, hp = pl.program_id(0), pl.program_id(1)
     qs = _res_pair(q_ref, hp, d)
     ks = _res_pair(k_ref, hp, d)
@@ -1195,8 +1177,6 @@ def _res_bias(bias_ref, j):
 def _res_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                    dq_ref, dbias_ref, *, scale, p_drop, n_heads, d,
                    acc_heads):
-    from jax.experimental import pallas as pl
-
     b, hp = pl.program_id(0), pl.program_id(1)
     qs = _res_pair(q_ref, hp, d)
     ks = _res_pair(k_ref, hp, d)
@@ -1227,8 +1207,6 @@ def _res_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 
 def _res_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                      dk_ref, dv_ref, *, scale, p_drop, n_heads, d):
-    from jax.experimental import pallas as pl
-
     b, hp = pl.program_id(0), pl.program_id(1)
     qs = _res_pair(q_ref, hp, d)
     ks = _res_pair(k_ref, hp, d)
@@ -1246,8 +1224,6 @@ def _res_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 
 
 def _res_specs(q3, n_heads, bias):
-    from jax.experimental import pallas as pl
-
     B, S, HD = q3.shape
     d = HD // n_heads
     Bb = _res_blocks(B, S, HD, jnp.dtype(q3.dtype).itemsize)
@@ -1261,9 +1237,6 @@ def _res_specs(q3, n_heads, bias):
 
 
 def _pallas_attention_res(q3, k3, v3, bias, scale, p_drop, seed, n_heads):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     grid, qspec, bspec, d = _res_specs(q3, n_heads, bias)
     return pl.pallas_call(
         functools.partial(_res_fwd_kernel, scale=scale, p_drop=p_drop,
@@ -1279,9 +1252,6 @@ def _pallas_attention_res(q3, k3, v3, bias, scale, p_drop, seed, n_heads):
 
 def _pallas_attention_res_bwd(q3, k3, v3, bias, seed, do, scale, p_drop,
                               n_heads):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     B, S, HD = q3.shape
     grid, qspec, bspec, d = _res_specs(q3, n_heads, bias)
     acc_heads = bias.shape[1] == 1
@@ -1412,8 +1382,6 @@ def _batch_block(B, S, tile_budget):
 
 
 def _specs(q, bias, tile_budget=2 * 1024 * 1024):
-    from jax.experimental import pallas as pl
-
     B, H, S, d = q.shape
     Bb = _batch_block(B, S, tile_budget)
     grid = (B // Bb, H)
@@ -1436,9 +1404,7 @@ def _fwd_budget(p_drop):
 
 
 def _pallas_attention(q, k, v, bias, scale, p_drop, seed):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    _count_kernel("block")
     B, H, S, d = q.shape
     grid, qspec, _, bspec = _specs(q, bias,
                                    tile_budget=_fwd_budget(p_drop))
@@ -1455,9 +1421,7 @@ def _pallas_attention(q, k, v, bias, scale, p_drop, seed):
 
 
 def _pallas_attention_bwd(q, k, v, bias, seed, do, scale, p_drop):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    _count_kernel("block_bwd")
     B, H, S, d = q.shape
     grid, qspec, sspec, bspec = _specs(q, bias, tile_budget=_BWD_BUDGET)
     acc_heads = bias.shape[1] == 1
@@ -1640,7 +1604,7 @@ def _decode_kb(C):
 
 def _use_decode_kernel(k_cache):
     """Pallas decode tier: same dispatch shape as training attention —
-    the S>=1024 regime where the Pallas tiers win (PROFILE_r05), with
+    the S>=1024 regime where the Pallas tiers win, with
     PADDLE_TPU_ATTN_FORCE=decode as the escape hatch that forces the
     kernel at any capacity (tests run it on CPU under interpret)."""
     if not _supports_pallas():
@@ -1659,7 +1623,6 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     or past it (including ring capacity padding) mask to -1e30.
     ``causal_window`` shifts the per-row limit for the speculative
     verify step (row r of Q sees col < len - (Q-1-r))."""
-    from jax.experimental import pallas as pl
 
     b, j = pl.program_id(0), pl.program_id(2)
     q = q_ref[0, 0]                               # [Q, d]
@@ -1697,9 +1660,7 @@ def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _pallas_attention_decode(q, k_cache, v_cache, cache_len, scale,
                              causal_window=False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    _count_kernel("decode")
     B, H, Q, d = q.shape
     C = k_cache.shape[2]
     KB = _decode_kb(C)
@@ -1835,7 +1796,6 @@ def _paged_decode_fwd_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
     dense [B, H, C, d] cache. tab_ref/len_ref are the scalar-prefetch
     operands (PrefetchScalarGridSpec passes them to the kernel AND to
     every BlockSpec index map)."""
-    from jax.experimental import pallas as pl
 
     b, j = pl.program_id(0), pl.program_id(2)
     q = q_ref[0, 0]                               # [Q, d]
@@ -1869,9 +1829,7 @@ def _paged_decode_fwd_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
 
 def _pallas_attention_paged(q, k_pool, v_pool, page_table, cache_len,
                             scale):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    _count_kernel("paged")
     B, H, Q, d = q.shape
     P, _, ptok, _ = k_pool.shape
     npages = page_table.shape[1]
@@ -1920,12 +1878,6 @@ def paged_attention_cache(q, k_pool, v_pool, page_table, cache_len,
     scale = float(scale)
     ptok = k_pool.shape[2]
     if _use_paged_kernel(page_table, ptok):
-        from ..fluid import monitor as _monitor
-
-        _monitor.counter(
-            "attn_paged_kernel_dispatch_total",
-            "paged-attention Pallas kernel dispatches (trace-time: one "
-            "per traced decode program, not per step)").inc()
         return _pallas_attention_paged(q, k_pool, v_pool, page_table,
                                        cache_len, scale)
     dense_k = gather_paged_cache(k_pool, page_table)
@@ -2347,7 +2299,6 @@ def sequence_parallel_attention(q, k, v, n_heads, bias=None, mesh=None,
         return _sp_local(q, k, v, bias_k, seed, strategy=strategy,
                          axis_name=None, batch_axis=None, n=1, n_heads=H,
                          causal=causal, scale=scale, p_drop=p_drop)
-    from paddle_tpu import jax_compat
     P = jax.sharding.PartitionSpec
     ba = None
     if batch_axis and batch_axis in mesh.shape:
@@ -2360,7 +2311,7 @@ def sequence_parallel_attention(q, k, v, n_heads, bias=None, mesh=None,
                               p_drop=p_drop)
     spec = P(ba, seq_axis, None)
     bspec = P(ba, None, None, seq_axis)
-    sm = jax_compat.shard_map(
-        local, mesh, in_specs=(spec, spec, spec, bspec, P(None)),
+    sm = jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec, bspec, P(None)),
         out_specs=spec, check_vma=False)
     return sm(q, k, v, bias_k, seed)

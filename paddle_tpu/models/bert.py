@@ -1,5 +1,5 @@
 """BERT/ERNIE-style transformer encoder for MLM pretraining —
-BASELINE.md config 3 (the Fleet-collective workload).
+BASELINE.json `configs` entry 3 (the Fleet-collective workload).
 
 Parity: the reference trains ERNIE/BERT through its transformer building
 blocks (``tests/unittests/dist_transformer.py``, multihead attention as the

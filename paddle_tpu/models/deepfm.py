@@ -1,5 +1,5 @@
-"""DeepFM / Wide&Deep CTR model — BASELINE.md config 4 (the sparse
-embedding + parameter-server workload).
+"""DeepFM / Wide&Deep CTR model — BASELINE.json `configs` entry 4 (the
+sparse embedding + parameter-server workload).
 
 Parity: the reference's CTR path (``tests/unittests/dist_ctr.py``,
 ``ctr_dataset_reader``) drives sparse ``lookup_table`` ops whose gradients

@@ -1,4 +1,4 @@
-"""LeNet-5 on MNIST — BASELINE.md config 1 (reference
+"""LeNet-5 on MNIST — BASELINE.json `configs` entry 1 (reference
 ``tests/book/test_recognize_digits.py`` conv_net)."""
 
 import numpy as np

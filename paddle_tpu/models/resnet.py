@@ -1,4 +1,5 @@
-"""ResNet for ImageNet-style classification — BASELINE.md config 2.
+"""ResNet for ImageNet-style classification — BASELINE.json `configs`
+entry 2.
 
 Parity: reference ``tests/unittests/dist_se_resnext.py`` /
 ``tests/book/test_image_classification.py`` model family; built from the

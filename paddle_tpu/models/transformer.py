@@ -1,5 +1,5 @@
-"""Transformer NMT model in DyGraph (eager) mode — BASELINE.md config 5
-(dygraph tracer -> XLA JIT).
+"""Transformer NMT model in DyGraph (eager) mode — BASELINE.json
+`configs` entry 5 (dygraph tracer -> XLA JIT).
 
 Parity: reference ``tests/unittests/dist_transformer.py`` (the
 Transformer-big NMT workload) and the dygraph transformer tests

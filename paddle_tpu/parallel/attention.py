@@ -22,9 +22,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from ..jax_compat import axis_size as _axis_size_compat
-from ..jax_compat import shard_map as _shard_map_compat
 from jax.sharding import PartitionSpec as P
 
 from .mesh import SP
@@ -63,7 +60,7 @@ def ring_attention_sharded(q, k, v, axis_name=SP, causal=False, scale=None):
     contiguously by rank along the ring. Runs inside shard_map."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    n = _axis_size_compat(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     chunk = q.shape[1]
     q_off = rank * chunk
@@ -128,7 +125,7 @@ def ulysses_attention_sharded(q, k, v, axis_name=SP, causal=False,
 
 def _wrap_sp(kernel, mesh, axis_name):
     spec = P(None, axis_name, None, None)
-    return _shard_map_compat(
+    return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

@@ -23,9 +23,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from ..jax_compat import axis_size as _axis_size_compat
-from ..jax_compat import shard_map as _shard_map_compat
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -154,7 +151,7 @@ def _stage_fn(cfg, heads_local, stage_params, x):
 def _loss_sharded(params, ids, labels, cfg, tp_size):
     """Per-shard global-mean LM loss. ids/labels: [b_local, s_local]."""
     heads_local = cfg.n_heads // tp_size
-    pp_n = _axis_size_compat("pp")
+    pp_n = jax.lax.axis_size("pp")
     pp_rank = jax.lax.axis_index("pp")
 
     x = params["emb"][ids] + params["pos"][None, :, :]
@@ -208,7 +205,7 @@ def make_train_step(cfg, mesh, lr=0.1):
 
     specs = param_specs()
     data_spec = P("dp", "sp")
-    smapped = _shard_map_compat(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(specs, data_spec, data_spec),
         out_specs=(specs, P()),

@@ -13,9 +13,6 @@ queues, one XLA program.
 
 import jax
 import jax.numpy as jnp
-
-from ..jax_compat import axis_size as _axis_size_compat
-from ..jax_compat import shard_map as _shard_map_compat
 from jax.sharding import PartitionSpec as P
 
 from .mesh import PP
@@ -30,7 +27,7 @@ def pipeline_sharded(stage_fn, stage_params, microbatches, axis_name=PP):
     microbatches: [M, ...] microbatch inputs (replicated; only rank 0 reads).
     Returns [M, ...] outputs, valid on the last rank (zeros elsewhere).
     """
-    n = _axis_size_compat(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     m = microbatches.shape[0]
     fwd = [(i, i + 1) for i in range(n - 1)]  # non-cyclic: rank0 recvs zeros
@@ -70,13 +67,13 @@ def pipeline(stage_fn, stacked_params, microbatches, mesh, axis_name=PP):
     def kernel(params, mbs):
         local = jax.tree_util.tree_map(lambda l: l[0], params)
         out = pipeline_sharded(stage_fn, local, mbs, axis_name)
-        n = _axis_size_compat(axis_name)
+        n = jax.lax.axis_size(axis_name)
         rank = jax.lax.axis_index(axis_name)
         return jax.lax.psum(
             jnp.where(rank == n - 1, out, jnp.zeros_like(out)), axis_name)
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params)
-    return _shard_map_compat(
+    return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),

@@ -21,8 +21,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..jax_compat import axis_size as _axis_size_compat
-
 from .mesh import TP
 
 
@@ -72,7 +70,7 @@ def pmean_exact(x, axis_name):
     transpose to another psum, scaling cotangents by the axis size; any
     loss reduction inside a differentiated per-shard program must use this
     (or ``reduce_from_tp_region``) instead."""
-    return reduce_from_tp_region(x / _axis_size_compat(axis_name), axis_name)
+    return reduce_from_tp_region(x / jax.lax.axis_size(axis_name), axis_name)
 
 
 def column_parallel_linear(x, w_local, b_local=None, axis_name=TP):

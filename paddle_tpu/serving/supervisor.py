@@ -17,7 +17,13 @@ and exits 0 — then respawns warm by default; ``stop()`` SIGTERMs
 everything with respawn disabled and reaps.
 
 No jax imports here: the supervisor is pure process management and is
-importable from a lightweight control process.
+importable from a lightweight control process. It must stay that way on
+a TPU host: a chip belongs to one process, so a parent that has touched
+JAX holds the chip its children need. Children inherit the caller's
+environment untouched apart from ``env=``, which every replica of one
+supervisor gets alike: it can name ONE chip (``TPU_VISIBLE_CHIPS`` and
+the like), so a host with several chips takes one supervisor per chip.
+A CPU fleet is the caller choosing that platform in its own environment.
 """
 
 import json
@@ -79,8 +85,6 @@ class FleetSupervisor:
         env[_replica.ENV_SPEC] = self._spec_path
         env[_replica.ENV_REPLICA_ID] = rid
         env[_flight.ENV_DIR] = self.flight_dir
-        env.setdefault("JAX_PLATFORMS", os.environ.get(
-            "JAX_PLATFORMS", "cpu"))
         return env
 
     def _spawn(self, rid):

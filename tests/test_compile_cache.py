@@ -330,3 +330,41 @@ def test_restore_on_restart_prewarms(tmp_path, monkeypatch):
     assert mgr.restore_on_restart() is None  # no checkpoint yet
     _, _, q1 = _counters()
     assert q1 - q0 == 1 and not os.path.exists(bad)
+
+
+def test_one_device_entry_reloads_on_multi_device_host(tmp_path,
+                                                       monkeypatch):
+    """An executable compiled for ONE of the 8 devices reloads for that
+    device — not for every device of the backend, which loads and then
+    dies at the first call wanting 8 shards. And an entry that does
+    load but cannot run is quarantined at that call, not fatal."""
+    import pickle
+
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setenv(compile_cache.ENV_DIR, str(tmp_path))
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.arange(4.0), dev)
+
+    def restart():
+        return compile_cache.wrap_jit(jax.jit(lambda v: v * 2), "pin")(x)
+
+    h0, m0, q0 = _counters()
+    cold = restart()
+    warm = restart()
+    h1, m1, q1 = _counters()
+    assert (h1 - h0, m1 - m0, q1 - q0) == (1, 1, 0)
+    assert warm.devices() == {dev}
+    assert np.array_equal(np.asarray(warm), np.asarray(cold))
+
+    path = compile_cache.entry_path(str(tmp_path), "pin")
+    with open(path, "rb") as f:
+        entry = pickle.load(f)  # bytes this test's own cold run wrote
+    entry["devices"] = [0]      # loads fine, cannot take x on device 3
+    with open(path, "wb") as f:
+        pickle.dump(entry, f)
+    again = restart()
+    h2, m2, q2 = _counters()
+    assert (h2 - h1, m2 - m1, q2 - q1) == (0, 1, 1)
+    assert np.array_equal(np.asarray(again), np.asarray(cold))

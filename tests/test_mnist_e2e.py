@@ -1,5 +1,5 @@
 """End-to-end LeNet/MNIST training — the reference "book" suite milestone
-(``tests/book/test_recognize_digits.py``), config 1 of BASELINE.md.
+(``tests/book/test_recognize_digits.py``), entry 1 of BASELINE.json's `configs`.
 
 Uses synthetic class-separable data (zero-egress environment)."""
 

@@ -102,7 +102,7 @@ def test_resnet_nhwc_matches_nchw():
     unchanged — one transpose at graph entry): losses agree to float
     tolerance over steps (reduce orders may differ per layout). On v5e
     the two compile to identical step times (XLA layout assignment
-    normalizes; PROFILE_r05.md §2)."""
+    normalizes)."""
     import numpy as np
 
     import paddle_tpu.fluid as fluid

@@ -427,7 +427,6 @@ def test_hier_psum_matches_flat_psum():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.jax_compat import shard_map
     from paddle_tpu.parallel import hier_psum, make_host_device_mesh
 
     mesh = make_host_device_mesh(2, 4)
@@ -441,8 +440,8 @@ def test_hier_psum_matches_flat_psum():
 
     kw = dict(mesh=mesh, in_specs=P(("host", "device")), out_specs=P(),
               check_vma=False)
-    got = shard_map(hier, **kw)(jnp.asarray(x))
-    want = shard_map(flat, **kw)(jnp.asarray(x))
+    got = jax.shard_map(hier, **kw)(jnp.asarray(x))
+    want = jax.shard_map(flat, **kw)(jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5)
 

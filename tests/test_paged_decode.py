@@ -242,10 +242,12 @@ def test_paged_kernel_matches_gather_oracle_at_odd_page_counts(
         pages = rng.permutation(np.arange(1, P))[:B * npages]
         table = pages.reshape(B, npages).astype(np.int32)
         lens = np.array([C - 3, (C // 2) + 1], np.int32)
-        c0 = monitor.counter("attn_paged_kernel_dispatch_total").value
+        c0 = monitor.counter("attn_kernel_dispatch_total",
+                             labels={"tier": "paged"}).value
         got = np.asarray(A.paged_attention_cache(
             q, k_pool, v_pool, table, lens))
-        c1 = monitor.counter("attn_paged_kernel_dispatch_total").value
+        c1 = monitor.counter("attn_kernel_dispatch_total",
+                             labels={"tier": "paged"}).value
         assert c1 > c0, "forced paged tier fell back (npages=%d)" % npages
         want = np.asarray(A._ref_attention_cache(
             q, A.gather_paged_cache(k_pool, table),

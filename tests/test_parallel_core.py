@@ -12,7 +12,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu import parallel as pl
-from paddle_tpu import jax_compat
 
 
 @pytest.fixture(scope="module")
@@ -86,7 +85,7 @@ def test_tp_column_then_row_linear(tp_mesh):
         h = jax.nn.relu(h)
         return pl.row_parallel_linear(h, w2, b2)
 
-    out = jax_compat.shard_map(
+    out = jax.shard_map(
         mlp, mesh=tp_mesh,
         in_specs=(P(), P(None, "tp"), P("tp"), P("tp", None), P()),
         out_specs=P(),
@@ -101,7 +100,7 @@ def test_vocab_parallel_embedding(tp_mesh):
     table = jnp.asarray(rng.randn(64, 8).astype(np.float32))
     ids = jnp.asarray(rng.randint(0, 64, (4, 7)))
     ref = jnp.take(table, ids, axis=0)
-    out = jax_compat.shard_map(
+    out = jax.shard_map(
         functools.partial(pl.vocab_parallel_embedding),
         mesh=tp_mesh,
         in_specs=(P(), P("tp", None)),
