@@ -47,6 +47,7 @@ import threading
 import time
 
 from . import monitor as _monitor
+from . import profiler as _profiler
 
 __all__ = [
     "ENV_DIR", "ENV_MAX_BYTES", "ENTRY_SUFFIX", "PRELOWERED_DIRNAME",
@@ -383,17 +384,19 @@ def wrap_jit(jfn, key, read_dirs=None, label=""):
             path = entry_path(d, key)
             if not os.path.exists(path):
                 continue
-            exe = _load_entry(path)
-            if exe is None:
-                continue
-            try:
-                out = exe(*args)
-            except Exception:
-                logger.warning("compile cache entry %s loaded but failed "
-                               "its first call; quarantining and "
-                               "compiling live", path, exc_info=True)
-                _quarantine(path)
-                continue
+            with _profiler.RecordEvent(_profiler.SPAN_CACHE_LOAD):
+                exe = _load_entry(path)
+                if exe is None:
+                    continue
+                try:
+                    out = exe(*args)
+                except Exception:
+                    logger.warning(
+                        "compile cache entry %s loaded but failed its "
+                        "first call; quarantining and compiling live",
+                        path, exc_info=True)
+                    _quarantine(path)
+                    continue
             _M_DISK_HIT.inc()
             _M_HIT_TIER_DISK.inc()
             return exe, out
@@ -401,9 +404,11 @@ def wrap_jit(jfn, key, read_dirs=None, label=""):
         _M_MISS_TIER_DISK.inc()
         # AOT lowering is the same trace a plain call does: a failure
         # here would have failed the undecorated jit the same way
-        compiled = jfn.lower(*args).compile()
+        with _profiler.RecordEvent(_profiler.SPAN_CACHE_COMPILE):
+            compiled = jfn.lower(*args).compile()
         if write_dir:
-            _save_entry(write_dir, key, compiled, label=label)
+            with _profiler.RecordEvent(_profiler.SPAN_CACHE_SAVE):
+                _save_entry(write_dir, key, compiled, label=label)
         return compiled, compiled(*args)
 
     def call(*args):

@@ -23,12 +23,16 @@ replaced by XLA partitioning + ICI collectives.
 """
 
 import os
+import time
+import types
+import weakref
 
 import numpy as np
 
 from . import compile_cache as _compile_cache
 from . import framework
 from . import monitor as _monitor
+from . import profiler as _prof
 from . import rng as _rng
 from .framework import Program, Variable, convert_dtype
 from .registry import LowerCtx, lower_block
@@ -350,7 +354,8 @@ class FetchHandle:
 
     def numpy(self):
         """Materialize on the host (blocking sync)."""
-        return _fetch_numpy(self._value)
+        with _prof.RecordEvent(_prof.SPAN_FETCH):
+            return _fetch_numpy(self._value)
 
     def __getitem__(self, idx):
         return self.numpy()[idx]
@@ -374,6 +379,44 @@ class _CompiledStep:
         self.fn = fn
         self.state_names = state_names
         self.fetch_names = fetch_names
+        self.arg_specs = None   # the first call's arguments, as shapes
+        _LIVE_STEPS.add(self)
+
+    def note_args(self, args):
+        """Keep the shapes of a call's arguments (taken before the call:
+        it donates the state), for ``hlo_text``."""
+        import jax
+
+        def spec(x):
+            # an uncommitted array's placement is jit's to choose, as
+            # the call's own lowering had it: naming it would make
+            # another program of the same step, compiled anew
+            committed = isinstance(x, jax.Array) and x.committed
+            return jax.ShapeDtypeStruct(
+                np.shape(x), x.dtype,
+                sharding=x.sharding if committed else None)
+
+        self.arg_specs = jax.tree_util.tree_map(spec, args)
+
+    def hlo_text(self):
+        """The compiled module as HLO text, each instruction with the
+        ``op_name`` it was traced under: what the profiler's region
+        table reads, since the device trace names an operation but does
+        not carry its metadata. Lowered again from the noted shapes
+        (JAX's caches make that seconds); None where the step cannot be
+        (never called, or a disk-tier or sharded wrapper)."""
+        if self.arg_specs is None or not hasattr(self.fn, "lower"):
+            return None
+        return self.fn.lower(*self.arg_specs).compile().as_text()
+
+
+_LIVE_STEPS = weakref.WeakSet()
+
+
+def compiled_steps():
+    """Every compiled step some Executor still holds
+    (``profiler.stop_profiler`` asks them for their HLO text)."""
+    return list(_LIVE_STEPS)
 
 
 class _WindowPrefetch:
@@ -617,7 +660,7 @@ class Executor:
         plain per-step shape (loop-invariant, reused every iteration);
         py_reader-fed programs instead drain exactly ``k`` batches up
         front. Each fetch returns the per-iteration trajectory, stacked
-        ``[k, ...]``. See ``_run_batched`` and README "Step-batched
+        ``[k, ...]``. See ``_prepare_batched`` and README "Step-batched
         execution".
 
         ``fetch_mode="async"``: return ``FetchHandle`` objects instead
@@ -657,15 +700,27 @@ class Executor:
                 "the NEXT step-batched window with this one's compute — "
                 "single steps already overlap via async dispatch "
                 "(fetch_mode='async')")
-        if iters > 1:
-            return self._run_batched(program, feed, fetch_list, scope,
-                                     return_numpy, iters, fetch_mode,
-                                     prefetch, checkpoint)
-        import time as _time
+        _prof.begin_run()
+        t_run0 = time.perf_counter()
+        with _prof.RecordEvent(_prof.SPAN_PREPARE):
+            if iters > 1:
+                prep = self._prepare_batched(program, feed, fetch_list,
+                                             scope, iters, prefetch,
+                                             checkpoint)
+            else:
+                prep = self._prepare(program, feed, fetch_list, scope,
+                                     checkpoint)
+        if prep is None:    # a server program: its serving loop has ended
+            return []
+        return self._run_prepared(prep, t_run0, return_numpy, iters,
+                                  fetch_mode, prefetch, checkpoint)
 
+    def _prepare(self, program, feed, fetch_list, scope, checkpoint):
+        """``executor.prepare`` of a single step: everything from entry to
+        the compile-cache lookup. Returns what ``_run_prepared`` takes, or
+        None after a server program's loop."""
         import jax
 
-        _t_run0 = _time.perf_counter()
         scope = scope or global_scope()
         feed = dict(feed or {})
         fetch_list = list(fetch_list or [])
@@ -721,7 +776,7 @@ class Executor:
                     build_server_from_attrs)
 
                 build_server_from_attrs(op.attrs).serve_forever()
-                return []
+                return None
             if op.type == "fl_listen_and_serv":
                 # federated variant (reference fl_listen_and_serv_op):
                 # initial params come from this scope's vars by name
@@ -752,7 +807,7 @@ class Executor:
                     srv.stop()
                     for key in {configured, srv.endpoint}:
                         _fl.SERVING.pop(key, None)
-                return []
+                return None
             if op.type == "host_embedding_init":
                 # host-side residency reset, synchronous with this run —
                 # the in-program op is a no-op (an io_callback there fires
@@ -861,106 +916,171 @@ class Executor:
             _flags.anomaly_policy() != "raise",
         )
 
-        step = self._cache.get(key)
-        cache_hit = step is not None
-        (_M_CACHE_HIT if cache_hit else _M_CACHE_MISS).inc()
-        (_M_CACHE_HIT_MEM if cache_hit else _M_CACHE_MISS_MEM).inc()
-        if step is None:
-            if _flags.check_program_enabled():
-                # debug mode (reference multi_devices_check_pass): validate
-                # well-formedness once per compiled signature
-                from .passes import apply_pass
+        state, rng = self._state_and_rng(program, scope, state_names)
+        return types.SimpleNamespace(
+            program=program, strategy=strategy, scope=scope,
+            fetch_names=fetch_names, save_ops=save_ops, key=key,
+            feed_names=list(feed), feeds=(feed,), state=state, rng=rng,
+            build=lambda: self._build(program, block, feed, fetch_names,
+                                      state_names, strategy))
 
-                apply_pass(program, "program_check",
-                           feed_names=list(feed))
-            step = self._build(program, block, feed, fetch_names, state_names, strategy)
-            self._cache[key] = step
-
-        # rng state: persists across runs in the scope
+    @staticmethod
+    def _state_and_rng(program, scope, state_names):
+        """The step's state arguments, out of the scope. The rng state
+        persists across runs there."""
         rng = scope.find_var(RNG_STATE_VAR)
         if rng is None:
             seed = program.random_seed or 0
             rng = _rng.key_data(_rng.root_key(seed))
             scope.set_var(RNG_STATE_VAR, rng)
+        return {n: scope.find_var(n) for n in state_names}, rng
 
-        state = {n: scope.find_var(n) for n in state_names}
-        from . import profiler as _prof
+    def _run_prepared(self, p, t_run0, return_numpy, iters, fetch_mode,
+                      prefetch, checkpoint):
+        """The rest of a run, single step or ``iters=k`` window alike:
+        the compile-cache lookup, ``executor.compile`` or
+        ``executor.call``, ``executor.commit``, ``executor.fetch``. ``p``
+        is what ``_prepare`` / ``_prepare_batched`` returned."""
+        import jax
 
+        from . import flags as _flags
         from .. import telemetry as _telemetry
+        from ..distributed import preemption as _preemption
 
+        program, scope, fetch_names = p.program, p.scope, p.fetch_names
+        batched = iters > 1
+        step = self._cache.get(p.key)
+        cache_hit = step is not None
+        (_M_CACHE_HIT if cache_hit else _M_CACHE_MISS).inc()
+        (_M_CACHE_HIT_MEM if cache_hit else _M_CACHE_MISS_MEM).inc()
         profiling = _prof.is_profiler_enabled()
-        t0 = _prof.now() if profiling else None
-        try:
-            if _telemetry.enabled() and _telemetry.current() is not None:
-                # traced request (serving batch ctx is ambient): the
-                # device-dispatch interval joins the request's trace
-                with _telemetry.span("executor.run",
-                                     attrs={"program": program._uid,
-                                            "cache_hit": cache_hit}):
-                    fetches, new_state, new_rng = step.fn(state, feed,
-                                                          rng)
-            else:
-                fetches, new_state, new_rng = step.fn(state, feed, rng)
-        except Exception:
-            # flight-recorder trigger: capture the ring (open spans show
-            # the in-flight request) before the failure unwinds
-            _telemetry.flight.dump(reason="executor_exception")
-            raise
+        with _prof.RecordEvent(_prof.SPAN_CALL if cache_hit
+                               else _prof.SPAN_COMPILE):
+            if step is None:
+                if _flags.check_program_enabled():
+                    # debug mode (reference multi_devices_check_pass):
+                    # validate well-formedness once per compiled signature
+                    from .passes import apply_pass
+
+                    apply_pass(program, "program_check",
+                               feed_names=p.feed_names)
+                step = self._cache[p.key] = p.build()
+                step.note_args((p.state, *p.feeds, p.rng))
+            t0 = _prof.now()
+            try:
+                if _telemetry.enabled() and \
+                        _telemetry.current() is not None:
+                    # traced request (serving batch ctx is ambient): the
+                    # device-dispatch interval joins the request's trace
+                    attrs = {"program": program._uid}
+                    if batched:
+                        attrs["iters"] = iters
+                    else:
+                        attrs["cache_hit"] = cache_hit
+                    with _telemetry.span(
+                            "executor.run_batched" if batched
+                            else "executor.run", attrs=attrs):
+                        fetches, new_state, new_rng = step.fn(
+                            p.state, *p.feeds, p.rng)
+                else:
+                    fetches, new_state, new_rng = step.fn(
+                        p.state, *p.feeds, p.rng)
+            except Exception:
+                # flight-recorder trigger: capture the ring (open spans
+                # show the in-flight request) before the failure unwinds
+                _telemetry.flight.dump(reason="executor_exception")
+                raise
         if profiling:
-            jax.block_until_ready(fetches)
-            # the #p<uid> suffix keeps distinct programs with the same
-            # leading fetches from colliding in the summary table
-            _prof._record("executor_run[%s#p%d]" % (
-                ",".join(fetch_names[:3]), program._uid),
-                _prof.now() - t0)
-        # nan/inf anomaly scan BEFORE commit (reference
-        # FLAGS_check_nan_inf / nan_inf_utils, grown into a policy): a
-        # non-finite step is handled per FLAGS_anomaly_policy — raise
-        # (legacy, default), skip_step (discard the update), or rollback
-        # (restore the last checkpoint). Discarded steps commit nothing.
-        anomaly = self._scan_anomaly(fetch_names, fetches, new_state)
-        discarded = False
-        if anomaly is not None:
-            discarded = self._handle_anomaly(anomaly, program, scope,
-                                             checkpoint, iters=1)
-        else:
-            self._anomaly_skips = 0
-        if not discarded:
-            scope.set_var(RNG_STATE_VAR, new_rng)
-            for n, v in new_state.items():
-                scope.set_var(n, v)
+            # the table's event is the step's time, so it waits for the
+            # device - unless the profiler is taking a device trace, which
+            # the wait would distort. The #p<uid> suffix keeps distinct
+            # programs with the same leading fetches apart in the table,
+            # and out of the monitor's label space.
+            if _prof.times_runs():
+                jax.block_until_ready(fetches)
+            event = "%s[%s#p%d%s]" % (
+                "executor_batched_run" if batched else "executor_run",
+                ",".join(fetch_names[:3]), program._uid,
+                ";k=%d" % iters if batched else "")
+            _prof._record(event, _prof.now() - t0, series=False)
+        if prefetch:
+            # dispatch is asynchronous — window i is still executing on
+            # device; start draining + staging window i+1 right now so
+            # the next run finds it ready (overlap hit). Pre-shard with
+            # the program's GSPMD feed sharding (iteration axis is 0,
+            # so the dp'd batch axis sits at 1).
+            sharding_fn = None
+            if p.strategy is not None and p.strategy.mesh is not None:
+                sharding_fn = (lambda name, v:
+                               p.strategy.feed_sharding(v, batch_dim=1))
+            self._window_prefetch[p.rkey] = _WindowPrefetch(
+                p.py_readers, iters, sharding_fn)
+        with _prof.RecordEvent(_prof.SPAN_COMMIT):
+            # nan/inf anomaly scan BEFORE commit (reference
+            # FLAGS_check_nan_inf / nan_inf_utils, grown into a policy): a
+            # non-finite step is handled per FLAGS_anomaly_policy — raise
+            # (legacy, default), skip_step (discard the update), or
+            # rollback (restore the last checkpoint). Discarded steps
+            # commit nothing. Under iters=k the granularity is the
+            # WINDOW: a non-finite value anywhere in the k-step
+            # trajectory (fetches are stacked [k, ...]) or the final
+            # state discards all k steps — the device-side loop cannot
+            # partially commit.
+            anomaly = self._scan_anomaly(fetch_names, fetches, new_state)
+            discarded = False
+            if anomaly is not None:
+                discarded = self._handle_anomaly(anomaly, program, scope,
+                                                 checkpoint, iters=iters)
+            else:
+                self._anomaly_skips = 0
+            if not discarded:
+                scope.set_var(RNG_STATE_VAR, new_rng)
+                for n, v in new_state.items():
+                    scope.set_var(n, v)
+            # the step's arguments were the last holders of the donated
+            # arrays: released here, inside the span and the run's wall
+            # time, not in the caller's frame as this one is torn down
+            p.state = p.rng = None
 
-        if save_ops and not discarded:
-            # TPU deviation from save_op.cc (which executes at its
-            # program-order position): the whole block runs as ONE
-            # compiled step, so saves always record the POST-step
-            # committed value, and only persistable (scope-held) vars
-            # are saveable. One PTC1 entry per file — exactly what
-            # layers.load reads back.
-            from .core import tensor_io
+            if p.save_ops and not discarded:
+                # TPU deviation from save_op.cc (which executes at its
+                # program-order position): the whole block runs as ONE
+                # compiled step, so saves always record the POST-step
+                # committed value (after step k of a window: ONE write
+                # per save op), and only persistable (scope-held) vars
+                # are saveable. One PTC1 entry per file — exactly what
+                # layers.load reads back.
+                from .core import tensor_io
 
-            for name, path in save_ops:
-                val = scope.find_var(name)
-                if val is None:
-                    raise RuntimeError(
-                        "save op: var %r is not in the scope — only "
-                        "PERSISTABLE vars can be saved (the step "
-                        "commits those; intermediates are fused away "
-                        "by XLA). fetch_list the value instead." % name)
-                os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-                tensor_io.save_combine(path, {name: _fetch_numpy(val)})
+                for name, path in p.save_ops:
+                    val = scope.find_var(name)
+                    if val is None:
+                        raise RuntimeError(
+                            "save op: var %r is not in the scope — only "
+                            "PERSISTABLE vars can be saved (the step "
+                            "commits those; intermediates are fused away "
+                            "by XLA). fetch_list the value instead."
+                            % name)
+                    os.makedirs(os.path.dirname(path) or ".",
+                                exist_ok=True)
+                    tensor_io.save_combine(path,
+                                           {name: _fetch_numpy(val)})
 
-        if checkpoint is not None and not discarded:
-            checkpoint[0].step_completed(program, scope, 1, checkpoint[1])
+            if checkpoint is not None and not discarded:
+                checkpoint[0].step_completed(program, scope, iters,
+                                             checkpoint[1])
 
-        # a preemption signal that landed DURING the step drains now,
-        # after the state committed — the step is never torn in half
-        _preemption.check_drain(checkpoint[0] if checkpoint else None,
-                                program, scope)
+            # a preemption signal that landed DURING the step (or window)
+            # drains now, after the state committed — never torn in half
+            _preemption.check_drain(checkpoint[0] if checkpoint else None,
+                                    program, scope)
 
-        wall = _time.perf_counter() - _t_run0
+        wall = time.perf_counter() - t_run0
         _M_RUN_SECONDS.observe(wall)
         _M_RUNS.inc()
+        if batched:
+            _M_BATCHED_RUNS.inc()
+            _M_BATCHED_ITERS.inc(iters)
         if _RUN_HOOKS:
             record = {
                 "program_id": program._uid,
@@ -969,9 +1089,11 @@ class Executor:
                 "cache_hit": cache_hit,
                 "profiler_enabled": profiling,
             }
+            # omit-when-default: a single-step, sync record keeps its
+            # exact key set (read record.get("iters", 1) / .get("async"))
+            if batched:
+                record["iters"] = iters
             if fetch_mode == "async":
-                # omit-when-default, like iters: legacy records keep
-                # their exact key set (read record.get("async", False))
                 record["async"] = True
             _fire_run_hooks(record)
 
@@ -979,7 +1101,8 @@ class Executor:
             return [FetchHandle(x, name=n)
                     for n, x in zip(fetch_names, fetches)]
         if return_numpy:
-            return [_fetch_numpy(x) for x in fetches]
+            with _prof.RecordEvent(_prof.SPAN_FETCH):
+                return [_fetch_numpy(x) for x in fetches]
         return list(fetches)
 
     # ------------------------------------------------------------------
@@ -1008,6 +1131,11 @@ class Executor:
                 if var.persistable and name in env and name not in state:
                     new_state[name] = env[name]
             return fetches, new_state, _rng.key_data(ctx.rng_key)
+
+        # what a trace calls the program: XLA's module (``jit_train_step``)
+        # and the ``jit(train_step)`` that leads every operation's op_name
+        if any(op.type == "autodiff" for op in block.ops):
+            step.__name__ = "train_step"
 
         from . import flags as _flags
 
@@ -1045,23 +1173,17 @@ class Executor:
         return _CompiledStep(jfn, state_names, fetch_names)
 
     # -- step-batched execution (iters=k) ------------------------------
-    def _run_batched(self, program, feed, fetch_list, scope, return_numpy,
-                     iters, fetch_mode=None, prefetch=False,
-                     checkpoint=None):
-        """``Executor.run(..., iters=k)`` for k >= 2: one compiled
-        executable drives k steps device-side. Kept separate from the
-        single-step ``run`` body so ``iters=1`` stays byte-for-byte the
-        legacy path (semantics, hook payloads, profiler events).
+    def _prepare_batched(self, program, feed, fetch_list, scope, iters,
+                         prefetch, checkpoint):
+        """``executor.prepare`` of ``Executor.run(..., iters=k)`` for
+        k >= 2: one compiled executable drives k steps device-side, so
+        the feeds are drained or stacked ``[k, ...]`` here.
         ``prefetch=True`` overlaps the NEXT window's py_reader
         drain+stack+stage with this window's device compute
-        (``_WindowPrefetch``); ``fetch_mode="async"`` returns
-        ``FetchHandle``s, so a prefetching loop issues no host sync at
-        all between windows."""
-        import time as _time
-
+        (``_WindowPrefetch``); with ``fetch_mode="async"`` a prefetching
+        loop issues no host sync at all between windows."""
         import jax
 
-        _t_run0 = _time.perf_counter()
         scope = scope or global_scope()
         feed = dict(feed or {})
         fetch_list = list(fetch_list or [])
@@ -1259,132 +1381,15 @@ class Executor:
             _flags.anomaly_policy() != "raise",
         )
 
-        step = self._cache.get(key)
-        cache_hit = step is not None
-        (_M_CACHE_HIT if cache_hit else _M_CACHE_MISS).inc()
-        (_M_CACHE_HIT_MEM if cache_hit else _M_CACHE_MISS_MEM).inc()
-        if step is None:
-            if _flags.check_program_enabled():
-                from .passes import apply_pass
-
-                apply_pass(program, "program_check",
-                           feed_names=list(feed))
-            step = self._build_batched(program, block, stacked, invariant,
-                                       fetch_names, state_names, strategy,
-                                       iters)
-            self._cache[key] = step
-
-        rng = scope.find_var(RNG_STATE_VAR)
-        if rng is None:
-            seed = program.random_seed or 0
-            rng = _rng.key_data(_rng.root_key(seed))
-            scope.set_var(RNG_STATE_VAR, rng)
-
-        state = {n: scope.find_var(n) for n in state_names}
-        from . import profiler as _prof
-
-        from .. import telemetry as _telemetry
-
-        profiling = _prof.is_profiler_enabled()
-        t0 = _prof.now() if profiling else None
-        try:
-            if _telemetry.enabled() and _telemetry.current() is not None:
-                with _telemetry.span("executor.run_batched",
-                                     attrs={"program": program._uid,
-                                            "iters": iters}):
-                    fetches, new_state, new_rng = step.fn(
-                        state, stacked, invariant, rng)
-            else:
-                fetches, new_state, new_rng = step.fn(state, stacked,
-                                                      invariant, rng)
-        except Exception:
-            _telemetry.flight.dump(reason="executor_exception")
-            raise
-        if profiling:
-            jax.block_until_ready(fetches)
-            _prof._record("executor_batched_run[%s#p%d;k=%d]" % (
-                ",".join(fetch_names[:3]), program._uid, iters),
-                _prof.now() - t0)
-        if prefetch:
-            # dispatch is asynchronous — window i is still executing on
-            # device; start draining + staging window i+1 right now so
-            # the next run finds it ready (overlap hit). Pre-shard with
-            # the program's GSPMD feed sharding (iteration axis is 0,
-            # so the dp'd batch axis sits at 1).
-            sharding_fn = None
-            if strategy is not None and strategy.mesh is not None:
-                sharding_fn = (lambda name, v:
-                               strategy.feed_sharding(v, batch_dim=1))
-            self._window_prefetch[rkey] = _WindowPrefetch(
-                py_readers, iters, sharding_fn)
-        # anomaly scan BEFORE commit, same policy as the single-step
-        # path. Granularity is the WINDOW: a non-finite value anywhere in
-        # the k-step trajectory (fetches are stacked [k, ...]) or the
-        # final state discards all k steps — the device-side loop cannot
-        # partially commit.
-        anomaly = self._scan_anomaly(fetch_names, fetches, new_state)
-        discarded = False
-        if anomaly is not None:
-            discarded = self._handle_anomaly(anomaly, program, scope,
-                                             checkpoint, iters=iters)
-        else:
-            self._anomaly_skips = 0
-        if not discarded:
-            scope.set_var(RNG_STATE_VAR, new_rng)
-            for n, v in new_state.items():
-                scope.set_var(n, v)
-
-        if save_ops and not discarded:
-            # same contract as the single-step path, applied to the whole
-            # window: ONE write per save op, recording the value committed
-            # after step k (running k single-step runs against the same
-            # file path leaves exactly this value too)
-            from .core import tensor_io
-
-            for name, path in save_ops:
-                val = scope.find_var(name)
-                if val is None:
-                    raise RuntimeError(
-                        "save op: var %r is not in the scope — only "
-                        "PERSISTABLE vars can be saved (the step "
-                        "commits those; intermediates are fused away "
-                        "by XLA). fetch_list the value instead." % name)
-                os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-                tensor_io.save_combine(path, {name: _fetch_numpy(val)})
-
-        if checkpoint is not None and not discarded:
-            checkpoint[0].step_completed(program, scope, iters,
-                                         checkpoint[1])
-
-        # drain between windows: a signal that landed mid-window exits
-        # here, after all k steps committed
-        _preemption.check_drain(checkpoint[0] if checkpoint else None,
-                                program, scope)
-
-        wall = _time.perf_counter() - _t_run0
-        _M_RUN_SECONDS.observe(wall)
-        _M_RUNS.inc()
-        _M_BATCHED_RUNS.inc()
-        _M_BATCHED_ITERS.inc(iters)
-        if _RUN_HOOKS:
-            record = {
-                "program_id": program._uid,
-                "fetch_names": list(fetch_names),
-                "wall_time": wall,
-                "cache_hit": cache_hit,
-                "profiler_enabled": profiling,
-                "iters": iters,
-            }
-            if fetch_mode == "async":
-                record["async"] = True
-            _fire_run_hooks(record)
-
-        if fetch_mode == "async":
-            return [FetchHandle(x, name=n)
-                    for n, x in zip(fetch_names, fetches)]
-        if return_numpy:
-            return [_fetch_numpy(x) for x in fetches]
-        return list(fetches)
+        state, rng = self._state_and_rng(program, scope, state_names)
+        return types.SimpleNamespace(
+            program=program, strategy=strategy, scope=scope,
+            fetch_names=fetch_names, save_ops=save_ops, key=key,
+            feed_names=list(feed), feeds=(stacked, invariant), state=state,
+            rng=rng, py_readers=py_readers, rkey=rkey,
+            build=lambda: self._build_batched(
+                program, block, stacked, invariant, fetch_names,
+                state_names, strategy, iters))
 
     def _build_batched(self, program, block, stacked, invariant,
                        fetch_names, state_names, strategy, iters):
@@ -1425,7 +1430,7 @@ class Executor:
                     "first so they exist in the scope" % (grown,))
             return fetches, new_state, _rng.key_data(ctx.rng_key)
 
-        def batched(state, stacked_feeds, invariant_feeds, rng_key):
+        def batched_step(state, stacked_feeds, invariant_feeds, rng_key):
             def body(carry, feed_i):
                 st, rk = carry
                 fv = dict(invariant_feeds)
@@ -1451,7 +1456,7 @@ class Executor:
 
         if strategy is not None and mesh is not None:
             return _CompiledStep(
-                strategy.wrap_batched_step(batched, block, stacked,
+                strategy.wrap_batched_step(batched_step, block, stacked,
                                            invariant, fetch_names,
                                            state_names,
                                            cache_key=cache_key,
@@ -1465,7 +1470,7 @@ class Executor:
         # discarded window's pre-step state stays valid) and for
         # inference-path executors; donate computed above joins the key
         jfn = _compile_cache.wrap_jit(
-            jax.jit(batched, donate_argnums=donate), cache_key,
+            jax.jit(batched_step, donate_argnums=donate), cache_key,
             read_dirs=self._cache_read_dirs,
             label="batched#k=%d" % iters)
         return _CompiledStep(jfn, state_names, fetch_names)

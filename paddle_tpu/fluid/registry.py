@@ -13,6 +13,8 @@ is the ``framework.Operator``. Rules read inputs with ``ctx.get`` and bind
 outputs with ``ctx.set``.
 """
 
+import re
+
 import numpy as np
 
 
@@ -199,13 +201,33 @@ def attribute_op_error(op, exc):
 EXECUTED_OP_TYPES = set()
 
 
+# the model's layer tag on a variable's name (models/bert.py:
+# ``layer_3_attn_q``)
+LAYER_TAG = re.compile(r"^layer_\d+_")
+
+
+def op_scope(op):
+    """The ``jax.named_scope`` an op is lowered under: its type, behind
+    the layer tag where its first output carries one (``layer_3_mul``).
+    Every HLO operation's ``op_name`` then says which program op it came
+    from, ``transpose(jvp(layer_3_mul))`` for its backward under the
+    ``autodiff`` op's replay; ``profiler.region_of`` reads it back.
+    Trace-time only."""
+    outs = op.output_arg_names()
+    tag = LAYER_TAG.match(outs[0]) if outs else None
+    return (tag.group(0) if tag else "") + op.type
+
+
 def lower_op(ctx, op):
     """Lower ONE op with error attribution + LoD propagation — the single
     entry every lowering loop (block, sub-block, replay, pipeline stage)
     must use so failures name the failing op and its creation site."""
+    import jax
+
     EXECUTED_OP_TYPES.add(op.type)
     try:
-        registry.get(op.type).lower(ctx, op)
+        with jax.named_scope(op_scope(op)):
+            registry.get(op.type).lower(ctx, op)
     except EnforceError:
         raise
     except Exception as e:  # noqa: B902 — attribute, then re-raise

@@ -123,6 +123,36 @@ KERNEL_TIERS = ("block", "block_bwd", "long", "long_bwd", "flash",
                 "flash_bwd", "decode", "paged")
 
 
+# The name of every ``pl.pallas_call`` here, one a site: the device
+# trace's instruction name of a kernel starts with it (``attn_block_fwd.3``),
+# the same for a forward kernel traced as the primal or under ``jvp``.
+KERNEL_NAMES = (
+    "attn_block_fwd", "attn_block_bwd", "attn_long_fwd", "attn_long_bwd",
+    "attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv",
+    "attn_packed_fwd", "attn_packed_bwd",
+    "attn_res_fwd", "attn_res_bwd_dq", "attn_res_bwd_dkv",
+    "attn_decode", "attn_paged")
+
+
+def _kernel_call(name, kernel, **kw):
+    """``pl.pallas_call`` under one of ``KERNEL_NAMES``, given twice:
+    as ``name=`` and as a ``named_scope`` round the call. XLA names the
+    custom call after the innermost scope it sits in, and under a
+    transform the outermost scope reads ``jvp(<name>)``; with two, the
+    inner one stays plain whatever the call was traced under. ``name=``
+    also goes into the Mosaic module, so a compilation cache keyed on
+    the program without its debug info cannot serve the unnamed kernel
+    in place of this one."""
+    assert name in KERNEL_NAMES, name
+    call = pl.pallas_call(kernel, name=name, interpret=_interpret(), **kw)
+
+    def named(*args):
+        with jax.named_scope(name):
+            return call(*args)
+
+    return named
+
+
 def _count_kernel(tier):
     """Trace-time record of which Pallas tier a dispatch took (one per
     traced ``pallas_call`` site, not per step) — how a caller proves the
@@ -516,7 +546,8 @@ def _pallas_attention_long(q, k, v, bias, scale, p_drop, seed):
     _count_kernel("long")
     B, H, S, d = q.shape
     grid, qspec, kvspec, bspec, nq, QB = _long_specs(q, bias)
-    return pl.pallas_call(
+    return _kernel_call(
+        "attn_long_fwd",
         functools.partial(_fwd_kernel_long, scale=scale, p_drop=p_drop,
                           n_heads=H, n_qtiles=nq),
         grid=grid,
@@ -524,7 +555,6 @@ def _pallas_attention_long(q, k, v, bias, scale, p_drop, seed):
                   qspec, kvspec, kvspec, bspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret(),
     )(seed, q, k, v, bias)
 
 
@@ -541,7 +571,8 @@ def _pallas_attention_long_bwd(q, k, v, bias, seed, do, scale, p_drop):
         lambda b, h, i, _ah=acc_heads, _rr=reduce_rows: (
             b, 0 if _ah else h, 0 if _rr else i, 0))
     f32 = jnp.float32
-    dq, dk, dv, dbias = pl.pallas_call(
+    dq, dk, dv, dbias = _kernel_call(
+        "attn_long_bwd",
         functools.partial(_bwd_kernel_long, scale=scale, p_drop=p_drop,
                           n_heads=H, n_qtiles=nq, acc_heads=acc_heads,
                           reduce_rows=reduce_rows),
@@ -553,7 +584,6 @@ def _pallas_attention_long_bwd(q, k, v, bias, seed, do, scale, p_drop):
                    jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct(dbias_shape, f32)],
-        interpret=_interpret(),
     )(seed, q, k, v, bias, do)
     return dq, dk.astype(q.dtype), dv.astype(q.dtype), dbias
 
@@ -772,7 +802,8 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed):
     B, H, S, d = q.shape
     TB, nt, qspec, kspec, bspec, rowspec = _flash_specs(q, bias)
     f32 = jnp.float32
-    return pl.pallas_call(
+    return _kernel_call(
+        "attn_flash_fwd",
         functools.partial(_flash_fwd_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H, nq=nt, nk=nt),
         grid=(B, H, nt, nt),
@@ -785,7 +816,6 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed):
                         pltpu.VMEM((TB, 1), f32),
                         pltpu.VMEM((TB, 1), f32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
-        interpret=_interpret(),
     )(seed, q, k, v, bias)
 
 
@@ -804,7 +834,8 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
     # reduced to the bias broadcast shape with plain XLA below.
     dbpspec = pl.BlockSpec(
         (1, 1, 1, TB), lambda b, h, i, j, _nt=nt: (b, h * _nt + i, 0, j))
-    dq, dbp = pl.pallas_call(
+    dq, dbp = _kernel_call(
+        "attn_flash_bwd_dq",
         functools.partial(_flash_dq_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H, nq=nt, nk=nt),
         grid=(B, H, nt, nt),
@@ -814,7 +845,6 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct((B, H * nt, 1, S), f32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
-        interpret=_interpret(),
     )(seed, q, k, v, bias, do, lse, dd)
     # transposed grid: k-tile is the SLOW tile dim so dk/dv accumulate
     # over consecutive q-tile steps
@@ -825,7 +855,8 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
         lambda b, h, j, i, _hb=bias.shape[1]: (b, h if _hb > 1 else 0,
                                                0, j))
     rowspec_t = pl.BlockSpec((1, 1, TB, 1), lambda b, h, j, i: (b, h, i, 0))
-    dk, dv = pl.pallas_call(
+    dk, dv = _kernel_call(
+        "attn_flash_bwd_dkv",
         functools.partial(_flash_dkdv_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H, nq=nt, nk=nt),
         grid=(B, H, nt, nt),
@@ -835,7 +866,6 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct(q.shape, f32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
-        interpret=_interpret(),
     )(seed, q, k, v, bias, do, lse, dd)
     dbias = jnp.sum(dbp.reshape(B, H, nt, S), axis=2,
                     keepdims=False)[:, :, None, :]         # [B, H, 1, S]
@@ -1038,7 +1068,8 @@ def _pallas_attention_packed(q3, k3, v3, bias, scale, p_drop, seed,
     Bb = _packed_bb(B, S, HD, n_heads)
     qspec, bspec = _packed_specs4(B, S, n_heads, d, bias, Bb)
     v4 = lambda t: t.reshape(B, S, n_heads, d)
-    o4 = pl.pallas_call(
+    o4 = _kernel_call(
+        "attn_packed_fwd",
         functools.partial(_packed_fwd_kernel, scale=scale, p_drop=p_drop,
                           n_heads=n_heads),
         grid=(B // Bb,),
@@ -1046,7 +1077,6 @@ def _pallas_attention_packed(q3, k3, v3, bias, scale, p_drop, seed,
                   qspec, qspec, qspec, bspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B, S, n_heads, d), q3.dtype),
-        interpret=_interpret(),
     )(seed, v4(q3), v4(k3), v4(v3), bias)
     return o4.reshape(B, S, HD)
 
@@ -1060,7 +1090,8 @@ def _pallas_attention_packed_bwd(q3, k3, v3, bias, seed, do, scale,
     dbias_shape = (B, bias.shape[1], 1, S)
     v4 = lambda t: t.reshape(B, S, n_heads, d)
     shape4 = jax.ShapeDtypeStruct((B, S, n_heads, d), q3.dtype)
-    dq, dk, dv, dbias = pl.pallas_call(
+    dq, dk, dv, dbias = _kernel_call(
+        "attn_packed_bwd",
         functools.partial(_packed_bwd_kernel, scale=scale, p_drop=p_drop,
                           n_heads=n_heads),
         grid=(B // Bb,),
@@ -1069,7 +1100,6 @@ def _pallas_attention_packed_bwd(q3, k3, v3, bias, seed, do, scale,
         out_specs=[qspec, qspec, qspec, bspec],
         out_shape=[shape4, shape4, shape4,
                    jax.ShapeDtypeStruct(dbias_shape, jnp.float32)],
-        interpret=_interpret(),
     )(seed, v4(q3), v4(k3), v4(v3), bias, v4(do))
     return (dq.reshape(B, S, HD), dk.reshape(B, S, HD),
             dv.reshape(B, S, HD), dbias)
@@ -1238,7 +1268,8 @@ def _res_specs(q3, n_heads, bias):
 
 def _pallas_attention_res(q3, k3, v3, bias, scale, p_drop, seed, n_heads):
     grid, qspec, bspec, d = _res_specs(q3, n_heads, bias)
-    return pl.pallas_call(
+    return _kernel_call(
+        "attn_res_fwd",
         functools.partial(_res_fwd_kernel, scale=scale, p_drop=p_drop,
                           n_heads=n_heads, d=d),
         grid=grid,
@@ -1246,7 +1277,6 @@ def _pallas_attention_res(q3, k3, v3, bias, scale, p_drop, seed, n_heads):
                   qspec, qspec, qspec, bspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-        interpret=_interpret(),
     )(seed, q3, k3, v3, bias)
 
 
@@ -1259,7 +1289,8 @@ def _pallas_attention_res_bwd(q3, k3, v3, bias, seed, do, scale, p_drop,
     ops = (seed, q3, k3, v3, bias, do)
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
                 qspec, qspec, qspec, bspec, qspec]
-    dq, dbias = pl.pallas_call(
+    dq, dbias = _kernel_call(
+        "attn_res_bwd_dq",
         functools.partial(_res_dq_kernel, scale=scale, p_drop=p_drop,
                           n_heads=n_heads, d=d, acc_heads=acc_heads),
         grid=grid,
@@ -1267,9 +1298,9 @@ def _pallas_attention_res_bwd(q3, k3, v3, bias, seed, do, scale, p_drop,
         out_specs=[qspec, bspec],
         out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
                    jax.ShapeDtypeStruct(dbias_shape, jnp.float32)],
-        interpret=_interpret(),
     )(*ops)
-    dk, dv = pl.pallas_call(
+    dk, dv = _kernel_call(
+        "attn_res_bwd_dkv",
         functools.partial(_res_dkdv_kernel, scale=scale, p_drop=p_drop,
                           n_heads=n_heads, d=d),
         grid=grid,
@@ -1277,7 +1308,6 @@ def _pallas_attention_res_bwd(q3, k3, v3, bias, seed, do, scale, p_drop,
         out_specs=[qspec, qspec],
         out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
                    jax.ShapeDtypeStruct(q3.shape, q3.dtype)],
-        interpret=_interpret(),
     )(*ops)
     return dq, dk, dv, dbias
 
@@ -1408,7 +1438,8 @@ def _pallas_attention(q, k, v, bias, scale, p_drop, seed):
     B, H, S, d = q.shape
     grid, qspec, _, bspec = _specs(q, bias,
                                    tile_budget=_fwd_budget(p_drop))
-    return pl.pallas_call(
+    return _kernel_call(
+        "attn_block_fwd",
         functools.partial(_fwd_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H),
         grid=grid,
@@ -1416,7 +1447,6 @@ def _pallas_attention(q, k, v, bias, scale, p_drop, seed):
                   qspec, qspec, qspec, bspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret(),
     )(seed, q, k, v, bias)
 
 
@@ -1428,7 +1458,8 @@ def _pallas_attention_bwd(q, k, v, bias, seed, do, scale, p_drop):
     reduce_rows = bias.shape[2] == 1
     dbias_shape = (B, bias.shape[1], bias.shape[2], S)
     f32 = jnp.float32
-    dq, dk, dv, dbias = pl.pallas_call(
+    dq, dk, dv, dbias = _kernel_call(
+        "attn_block_bwd",
         functools.partial(_bwd_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H, acc_heads=acc_heads,
                           reduce_rows=reduce_rows),
@@ -1440,7 +1471,6 @@ def _pallas_attention_bwd(q, k, v, bias, seed, do, scale, p_drop):
                    jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(dbias_shape, f32)],
-        interpret=_interpret(),
     )(seed, q, k, v, bias, do)
     return dq, dk, dv, dbias
 
@@ -1679,7 +1709,8 @@ def _pallas_attention_decode(q, k_cache, v_cache, cache_len, scale,
     qspec = pl.BlockSpec((1, 1, Q, d), lambda b, h, j: (b, h, 0, 0))
     kspec = pl.BlockSpec((1, 1, KB, d), lambda b, h, j: (b, h, j, 0))
     f32 = jnp.float32
-    return pl.pallas_call(
+    return _kernel_call(
+        "attn_decode",
         functools.partial(_decode_fwd_kernel, scale=scale, kb=KB, nk=nk,
                           causal_window=causal_window),
         grid=(B, H, nk),
@@ -1690,7 +1721,6 @@ def _pallas_attention_decode(q, k_cache, v_cache, cache_len, scale,
         scratch_shapes=[pltpu.VMEM((Q, d), f32),
                         pltpu.VMEM((Q, 1), f32),
                         pltpu.VMEM((Q, 1), f32)],
-        interpret=_interpret(),
     )(lens, q, k_cache, v_cache)
 
 
@@ -1853,12 +1883,12 @@ def _pallas_attention_paged(q, k_pool, v_pool, page_table, cache_len,
         scratch_shapes=[pltpu.VMEM((Q, d), f32),
                         pltpu.VMEM((Q, 1), f32),
                         pltpu.VMEM((Q, 1), f32)])
-    return pl.pallas_call(
+    return _kernel_call(
+        "attn_paged",
         functools.partial(_paged_decode_fwd_kernel, scale=scale,
                           ptok=ptok, npages=npages),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret(),
     )(table, lens, q, k_pool, v_pool)
 
 

@@ -100,8 +100,8 @@ def _build_image(reason):
         "reason": reason,
         "spans": _spans.snapshot(limit=_SPAN_TAIL),
         "profiler_spans": [
-            {"name": n, "t_end": t, "dur": d}
-            for n, t, d in list(_profiler._spans)[-_PROF_TAIL:]],
+            {"name": n, "run_id": r, "t_start": t, "dur": d}
+            for n, r, t, d in _profiler.recent_spans()[-_PROF_TAIL:]],
         "monitor_delta": deltas,
         "wire_ops": [
             {"ts": ts, "dir": dr, "op": op, "bytes": nb}

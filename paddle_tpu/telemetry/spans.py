@@ -4,9 +4,13 @@
 kept — the flight recorder wants the LAST seconds, matching the
 profiler's ring policy). Spans are appended at START, open (``dur``
 None) until the context exits, so a crash dump shows the in-flight
-request, not just completed ones. Timestamps reuse the profiler's
-perf_counter->unix anchor so host spans, executor profiler events, and
-device XPlane timelines all land on one clock.
+request, not just completed ones. Timestamps are unix time (a
+perf_counter reading anchored once a process), the one clock the
+processes of a fleet share for the merged export. Against the device,
+every span also enters the ``jax.profiler.TraceAnnotation`` that
+``fluid.profiler.RecordEvent`` enters: in any device trace being taken, a
+request's spans and the executor's sit in one ``/host:CPU`` plane on the
+device's clock.
 
 Export: ``export_trace(path)`` writes a chrome://tracing JSON where
 every distinct (pid, service) pair gets its own pid lane — in a real
@@ -22,8 +26,9 @@ import threading
 import time
 from collections import OrderedDict, deque
 
+from jax.profiler import TraceAnnotation
+
 from ..fluid import monitor as _monitor
-from ..fluid import profiler as _profiler
 from . import context as _context
 
 __all__ = ["span", "record_span", "snapshot", "clear", "set_max_spans",
@@ -37,6 +42,10 @@ _MAX = int(os.environ.get(ENV_MAX_SPANS, 65536) or 65536)
 _BUF = deque(maxlen=max(_MAX, 1))
 _DROPPED = [0]
 
+# perf_counter has an arbitrary epoch; anchored to unix time once, so the
+# spans of every process of a fleet merge on one wall clock
+_EPOCH_ANCHOR = (time.perf_counter(), time.time())
+
 _M_SPANS = _monitor.counter(
     "telemetry_spans_total", help="trace spans recorded in this process")
 _M_DROPPED = _monitor.counter(
@@ -45,7 +54,7 @@ _M_DROPPED = _monitor.counter(
 
 
 def _unix_now():
-    pc0, unix0 = _profiler._EPOCH_ANCHOR
+    pc0, unix0 = _EPOCH_ANCHOR
     return time.perf_counter() - pc0 + unix0
 
 
@@ -90,7 +99,8 @@ class _SpanScope:
     (optionally) the service ambient for everything nested."""
 
     __slots__ = ("_name", "_parent", "_service", "_links", "_attrs",
-                 "_ctx_token", "_svc_token", "_rec", "_t0", "ctx")
+                 "_ctx_token", "_svc_token", "_rec", "_t0", "_annotation",
+                 "ctx")
 
     def __init__(self, name, parent, service, links, attrs):
         self._name = name
@@ -109,6 +119,8 @@ class _SpanScope:
         if self._service is not None:
             self._svc_token = _context._SERVICE.set(self._service)
         service = self._service or _context.current_service()
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         if self.ctx.sampled:
             self._rec = _make_record(self._name, self.ctx, service,
@@ -118,6 +130,7 @@ class _SpanScope:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
         if self._rec is not None:
             self._rec["dur"] = time.perf_counter() - self._t0
             if exc_type is not None:
@@ -145,7 +158,7 @@ def record_span(name, t_start_perf, dur, ctx, service=None, links=None,
     reading; ``dur`` in seconds."""
     if ctx is None or not ctx.sampled:
         return None
-    pc0, unix0 = _profiler._EPOCH_ANCHOR
+    pc0, unix0 = _EPOCH_ANCHOR
     rec = _make_record(name, ctx, service or _context.current_service(),
                        t_start_perf - pc0 + unix0, dur=float(dur),
                        links=links, attrs=attrs)
