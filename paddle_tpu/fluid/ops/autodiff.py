@@ -5,13 +5,26 @@ TPU-first replacement for the reference's per-op grad machinery
 op-by-op grad program synthesis): instead of synthesizing hundreds of
 ``*_grad`` ops, ``append_backward`` inserts ONE ``autodiff`` op whose
 lowering replays the forward ops as a pure function and differentiates it
-with ``jax.grad``. XLA CSEs the replayed forward against the original
-computation, so no work is duplicated in the compiled executable.
+with ``jax.grad``.
 
-Random ops replay with recorded PRNG keys (``LowerCtx.replay_keys``) so the
-differentiated forward is bit-identical to the primal (the reference saves
-dropout masks for backward — same guarantee, zero memory cost here because
-XLA dedups).
+The compiled step holds ONE forward. ``lower_block`` has lowered the same
+ops once already (the primal), so the replay returns its forward values as
+the grad transform's auxiliary output and ``_autodiff`` rebinds those names
+in ``ctx.env``: every later reader (fetches, metric ops, optimizer ops, the
+state the executor commits) takes the replay's arrays, the primal chain is
+dead from the first forward op's output on, and ``jit`` removes it. Nothing
+is left for XLA's CSE to merge - it cannot merge two ``tpu_custom_call``s,
+nor anything downstream of them. In a trace the forward therefore carries
+the replay's scope, ``autodiff/jvp(<op>)``. What keeps its primal binding:
+the ``wrt`` vars, non-array entries (LoD lengths, tensor arrays) and what a
+``jax.checkpoint`` segment does not hand on. ``ctx.written`` stays as the
+primal lowering recorded it.
+
+Random ops replay with recorded PRNG keys (``LowerCtx.replay_keys``) and
+``stop_gradient`` is the identity going forward, so the differentiated
+forward is bit-identical to the primal (the reference saves dropout masks
+for backward - same guarantee, no memory cost) and the rebinding changes no
+number.
 
 ``stop_gradient`` var markers are honored by wrapping those vars in
 ``lax.stop_gradient`` during the replay.
@@ -170,6 +183,8 @@ def _autodiff(ctx, op):
     sparse_names = {s[0] for s in sparse_wrt}
     dense_idx = [i for i, n in enumerate(wrt_names) if n not in sparse_names]
     dense_names = [wrt_names[i] for i in dense_idx]
+    rebound = {n for o in prior_ops for n in o.output_arg_names()}
+    rebound.difference_update(wrt_names)
 
     def run_fwd(overrides, sparse_eps):
         if checkpoints:
@@ -186,7 +201,9 @@ def _autodiff(ctx, op):
             import jax.numpy as jnp
 
             loss = jnp.sum(loss)
-        return loss * loss_scale
+        forward_values = {n: renv[n] for n in rebound
+                          if isinstance(renv.get(n), jax.Array)}
+        return loss * loss_scale, forward_values
 
     if sparse_wrt or dist_push:
         import numpy as np
@@ -202,7 +219,8 @@ def _autodiff(ctx, op):
             eps_map = dict(zip(eps_outs, evals))
             return run_fwd(dict(zip(dense_names, dvals)), eps_map)
 
-        gdense, geps = jax.grad(fwd2, argnums=(0, 1))(dense_vals, eps0)
+        (gdense, geps), forward_values = jax.grad(
+            fwd2, argnums=(0, 1), has_aux=True)(dense_vals, eps0)
         for i, g in zip(dense_idx, gdense):
             ctx.set(grad_names[i], g)
         n_sparse = len(sparse_wrt)
@@ -229,10 +247,14 @@ def _autodiff(ctx, op):
             ctx.set(out_name + "@PS_GRAD", values)
             ctx.set(out_name + "@PS_ROWS", rows)
     else:
-        grads = jax.grad(lambda vals: run_fwd(dict(zip(wrt_names, vals)),
-                                              None))(wrt_vals)
+        grads, forward_values = jax.grad(
+            lambda vals: run_fwd(dict(zip(wrt_names, vals)), None),
+            has_aux=True)(wrt_vals)
         for gname, g in zip(grad_names, grads):
             ctx.set(gname, g)
+    # one forward in the step: later readers take the replay's values (not
+    # ctx.set - ctx.written stays what the primal lowering recorded)
+    ctx.env.update(forward_values)
 
 
 @register("calc_gradient")

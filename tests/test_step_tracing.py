@@ -226,7 +226,8 @@ def test_lowered_text_names_the_program_op_of_every_operation(trained):
     assert step.fn.__name__ == "train_step"
     _, args = exe.as_function(main, feed, [loss])
     text = step.fn.lower(*args).as_text(debug_info=True)
-    assert "jit(train_step)/layer_1_mul/" in text                # forward
+    assert "autodiff/jvp(layer_1_mul)/" in text     # forward: the replay's
+    assert "jit(train_step)/layer_1_mul/" not in text     # and only the one
     assert "autodiff/transpose(jvp(layer_1_mul))/" in text        # backward
     assert "jit(train_step)/layer_1_adam/" in text                # optimizer
 
