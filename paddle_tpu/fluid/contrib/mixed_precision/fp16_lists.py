@@ -25,6 +25,9 @@ black_list = {
     "reduce_sum", "reduce_mean", "cos_sim", "softmax_with_cross_entropy",
     "sigmoid_cross_entropy_with_logits", "cross_entropy",
     "group_norm", "instance_norm", "l2_normalize",
+    # the router: f32 logits and softmax over all experts (a bf16 pass
+    # flips near-tied choices); its weights go on in f32
+    "moe_route",
 }
 
 # everything else follows its inputs (elementwise, activations, shape ops)
@@ -42,6 +45,14 @@ gray_list = {
     # 24 layer_norms per BERT step). A caller that wants the reference
     # behavior passes custom_black_list=["batch_norm", "layer_norm"].
     "batch_norm", "layer_norm",
+    # rms_norm as layer_norm (f32 statistics inside, f32 weight). The
+    # rest follow their activations, which the projections made low:
+    # gated_delta_rule keeps A_log / dt_bias, its decays, its triangular
+    # system and its state in f32 and feeds the matmuls in the input
+    # dtype; moe_experts keeps the router's weights f32 and runs the
+    # grouped GEMMs in the input dtype
+    "rms_norm", "swiglu", "rotary_embedding", "causal_conv1d",
+    "gated_delta_rule", "moe_experts",
 }
 
 

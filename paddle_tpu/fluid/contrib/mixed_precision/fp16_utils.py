@@ -63,6 +63,9 @@ def _insert_cast(block, new_ops, cache, name, dest_dtype, suffix):
 _KEEP_FP32_SLOTS = {
     "batch_norm": ("Scale", "Bias", "Mean", "Variance"),
     "layer_norm": ("Scale", "Bias"),
+    "rms_norm": ("Scale",),
+    "gated_delta_rule": ("ALog", "DtBias"),
+    "moe_experts": ("TopkWeights",),
 }
 
 # gray ops where only SOME outputs become low-precision: batch_norm's
@@ -72,6 +75,7 @@ _KEEP_FP32_SLOTS = {
 _LOW_OUTPUT_SLOTS = {
     "batch_norm": ("Y",),
     "layer_norm": ("Y",),
+    "rms_norm": ("Y",),
 }
 
 

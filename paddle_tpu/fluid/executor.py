@@ -966,6 +966,7 @@ class Executor:
                                feed_names=p.feed_names)
                 step = self._cache[p.key] = p.build()
                 step.note_args((p.state, *p.feeds, p.rng))
+                _prof.note_compiled_step(step.fn, step.arg_specs)
             t0 = _prof.now()
             try:
                 if _telemetry.enabled() and \

@@ -37,7 +37,8 @@ __all__ = [
     "autoincreased_step_counter", "smooth_l1", "dice_loss", "py_func",
     "linear_chain_crf", "crf_decoding", "ctc_greedy_decoder",
     "shard_tensor", "fused_attention", "fused_attention_packed",
-    "einsum",
+    "einsum", "rms_norm", "swiglu", "rotary_embedding", "causal_conv1d",
+    "gated_delta_rule", "moe_route", "moe_experts",
 ]
 
 
@@ -1605,10 +1606,14 @@ def shard_tensor(x, spec, name=None):
 
 
 def fused_attention(q, k, v, attn_bias=None, scale=None, dropout_prob=0.0,
-                    is_test=False, name=None):
+                    is_test=False, name=None, causal=False,
+                    num_kv_heads=None):
     """Fused softmax(q·kᵀ·scale + bias)·v over [B, H, S, d] heads — a
     single Pallas TPU kernel per (batch, head) with in-kernel dropout;
-    falls back to the unfused jnp math off-TPU (kernels/attention.py)."""
+    falls back to the unfused jnp math off-TPU (kernels/attention.py).
+    ``causal`` masks column > row inside the kernel; ``num_kv_heads``
+    says K and V carry that many heads, each serving H / num_kv_heads
+    consecutive Q heads (grouped-query attention)."""
     helper = LayerHelper("fused_multihead_attention", **locals())
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
@@ -1617,6 +1622,10 @@ def fused_attention(q, k, v, attn_bias=None, scale=None, dropout_prob=0.0,
     attrs = {"dropout_prob": float(dropout_prob), "is_test": is_test}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if causal:
+        attrs["causal"] = True
+    if num_kv_heads:
+        attrs["num_kv_heads"] = int(num_kv_heads)
     helper.append_op(type="fused_multihead_attention", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out
@@ -1691,4 +1700,124 @@ def fused_attention_cache(q, k_cache, v_cache, cache_len, scale=None,
                      inputs={"Q": [q], "KCache": [k_cache],
                              "VCache": [v_cache], "CacheLen": [cache_len]},
                      outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def rms_norm(input, epsilon=1e-6, zero_centered=False, param_attr=None,
+             name=None):
+    """``x * rsqrt(mean(x^2) + eps) * w`` over the last axis. With
+    ``zero_centered`` the stored weight is read as ``1 + w`` (and starts
+    at 0); else it multiplies as it is (and starts at 1)."""
+    from ..initializer import Constant
+
+    helper = LayerHelper("rms_norm", **locals())
+    scale = helper.create_parameter(
+        param_attr, [int(input.shape[-1])], _data_type(input),
+        default_initializer=Constant(0.0 if zero_centered else 1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="rms_norm", inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [out]},
+                     attrs={"epsilon": float(epsilon),
+                            "zero_centered": bool(zero_centered)})
+    return out
+
+
+def swiglu(x, y, name=None):
+    """``silu(x) * y``."""
+    helper = LayerHelper("swiglu", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="swiglu", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def rotary_embedding(x, rotary_dim=None, theta=10000.0, name=None):
+    """Rotate-half rotary embedding on the first ``rotary_dim`` of the
+    head dim of ``x`` [B, H, S, d]; row ``s`` sits at position ``s``."""
+    helper = LayerHelper("rotary_embedding", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="rotary_embedding", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"rotary_dim": int(rotary_dim or x.shape[-1]),
+                            "theta": float(theta)})
+    return out
+
+
+def causal_conv1d(input, kernel_size, param_attr=None, name=None):
+    """Depthwise causal convolution along axis 1 of ``input`` [B, S, C]:
+    one ``kernel_size``-tap filter a channel, no bias."""
+    helper = LayerHelper("causal_conv1d", **locals())
+    w = helper.create_parameter(
+        param_attr, [int(input.shape[-1]), int(kernel_size)],
+        _data_type(input))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="causal_conv1d",
+                     inputs={"X": [input], "Filter": [w]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
+                     chunk_size=64, name=None):
+    """The gated delta rule of a Gated DeltaNet layer (ops/
+    linear_attention.py): q, k [B, S, Hk, dk], v [B, S, Hv, dv], ``a`` and
+    ``b`` [B, S, Hv] the pre-activations of the decay and of beta. Creates
+    the per-head ``A_log`` (0: decay rate 1) and ``dt_bias`` (1)."""
+    from ..initializer import Constant
+
+    helper = LayerHelper("gated_delta_rule", **locals())
+    heads, dtype = [int(v.shape[2])], "float32"
+    a_log = helper.create_parameter(a_log_attr, heads, dtype,
+                                    default_initializer=Constant(0.0))
+    dt_bias = helper.create_parameter(dt_bias_attr, heads, dtype,
+                                      default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op(
+        type="gated_delta_rule",
+        inputs={"Q": [q], "K": [k], "V": [v], "A": [a], "B": [b],
+                "ALog": [a_log], "DtBias": [dt_bias]},
+        outputs={"Out": [out]}, attrs={"chunk_size": int(chunk_size)})
+    return out
+
+
+def moe_route(input, num_experts, k, norm_topk_prob=True, param_attr=None,
+              name=None):
+    """Router over all ``num_experts`` experts: ``(ids, weights)`` of the
+    ``k`` largest of ``softmax(x W)`` (f32), renormalised to sum 1 where
+    ``norm_topk_prob``. ``ids`` is int32 [..., k] and carries no
+    gradient."""
+    helper = LayerHelper("moe_route", **locals())
+    w = helper.create_parameter(
+        param_attr, [int(input.shape[-1]), int(num_experts)], "float32")
+    ids = helper.create_variable_for_type_inference("int32",
+                                                    stop_gradient=True)
+    wts = helper.create_variable_for_type_inference("float32")
+    helper.append_op(type="moe_route", inputs={"X": [input], "Weight": [w]},
+                     outputs={"TopkIds": [ids], "TopkWeights": [wts]},
+                     attrs={"k": int(k),
+                            "norm_topk_prob": bool(norm_topk_prob)})
+    return ids, wts
+
+
+def moe_experts(input, topk_ids, topk_weights, experts_held, expert_width,
+                expert_offset=0, gate_attr=None, up_attr=None, down_attr=None,
+                name=None):
+    """The part of a sparse-expert layer's result that the
+    ``experts_held`` experts from ``expert_offset`` on give (SwiGLU
+    experts of width ``expert_width``, no biases), dropless (ops/
+    moe_ops.py). What absent experts would add is left out."""
+    helper = LayerHelper("moe_experts", **locals())
+    h, E, f = int(input.shape[-1]), int(experts_held), int(expert_width)
+    dtype = _data_type(input)
+    wg = helper.create_parameter(gate_attr, [E, h, f], dtype)
+    wu = helper.create_parameter(up_attr, [E, h, f], dtype)
+    wd = helper.create_parameter(down_attr, [E, f, h], dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="moe_experts",
+        inputs={"X": [input], "TopkIds": [topk_ids],
+                "TopkWeights": [topk_weights], "WGate": [wg], "WUp": [wu],
+                "WDown": [wd]},
+        outputs={"Out": [out]},
+        attrs={"expert_offset": int(expert_offset)})
     return out

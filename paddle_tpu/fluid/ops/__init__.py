@@ -14,10 +14,12 @@ from . import (  # noqa: F401
     distributed_ops,
     elementwise,
     embedding_ops,
+    linear_attention,
     loss,
     math,
     metrics,
     misc_ops,
+    moe_ops,
     nn,
     optimizer_ops,
     quant_ops,

@@ -1,0 +1,237 @@
+"""Linear-attention ops: the causal depthwise convolution and the gated
+delta rule of a Gated DeltaNet layer (Yang et al. 2024, "Gated Delta
+Networks"; the token mixer of three in four Qwen3-Next layers).
+
+Per head, with a ``[dk, dv]`` state ``S_0 = 0``::
+
+    S' = exp(g_t) S_{t-1};  d_t = beta_t (v_t - S'^T k_t)
+    S_t = S' + k_t d_t^T;   o_t = S_t^T q_t
+
+The lowering is the CHUNKED form (chunks of ``chunk_size`` positions):
+inside a chunk the rank-one updates are folded into one unit-lower
+triangular system, solved by block inversion; between chunks the state is
+carried by a ``lax.scan`` over the chunks, which holds only the two
+matmuls that touch the state. Everything else is batched over all chunks.
+Plain XLA: no Pallas kernel here yet. The ``autodiff`` op differentiates
+it like any lowering.
+"""
+
+import functools
+
+from ..registry import register
+
+_BASE = 16      # the diagonal blocks inverted by forward substitution
+
+
+def _count(impl):
+    """Trace-time record of which implementation a dispatch took (one per
+    traced site, not per step), beside ``attn_kernel_dispatch_total``."""
+    from .. import monitor
+
+    monitor.counter(
+        "gdn_dispatch_total",
+        "gated_delta_rule lowerings traced, by implementation (trace-time: "
+        "one per traced program, not per step)", labels={"impl": impl}).inc()
+
+
+def _taps(xp, w, S):
+    """``sum_j w[:, j] * xp[:, j:j + S]``: K shifted multiply-adds over one
+    padded array, in f32, which XLA fuses into one pass."""
+    import jax.numpy as jnp
+
+    xp, w = xp.astype(jnp.float32), w.astype(jnp.float32)
+    return sum(xp[:, j:j + S, :] * w[:, j] for j in range(w.shape[1]))
+
+
+@functools.lru_cache(maxsize=None)
+def _causal_conv():
+    """``conv(x [B, S, C], w [C, K])`` with a backward written out: the
+    input gradient is the same K taps run the other way over the padded
+    cotangent, so what stays live is x and w, not K f32 copies of x."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def conv(x, w):
+        K = w.shape[1]
+        return _taps(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))), w,
+                     x.shape[1]).astype(x.dtype)
+
+    def bwd(res, dy):
+        x, w = res
+        K, S = w.shape[1], x.shape[1]
+        dx = _taps(jnp.pad(dy, ((0, 0), (0, K - 1), (0, 0))), w[:, ::-1], S)
+        xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32)
+        dyf = dy.astype(jnp.float32)
+        dw = jnp.stack([jnp.sum(dyf * xp[:, j:j + S, :], axis=(0, 1))
+                        for j in range(K)], axis=1)
+        return dx.astype(x.dtype), dw.astype(w.dtype)
+
+    conv.defvjp(lambda x, w: (conv(x, w), (x, w)), bwd)
+    return conv
+
+
+@register("causal_conv1d")
+def _causal_conv1d(ctx, op):
+    """Depthwise causal convolution along the sequence: X [B, S, C],
+    Filter [C, K], ``Out[t] = sum_j Filter[:, j] * X[t - (K-1) + j]``
+    (zeros before the start), no bias. K shifted multiply-adds in f32:
+    for K = 4 that is cheaper on the chip than a grouped convolution."""
+    ctx.set_output(op, "Out", _causal_conv()(ctx.get_input(op, "X"),
+                                             ctx.get_input(op, "Filter")))
+
+
+def _inv_blocks(m):
+    """Inverse of unit lower triangular ``m`` [..., n, n], n <= _BASE, by
+    forward substitution, one row at a time (row i of the inverse is
+    ``e_i - m[i, :i] @ rows[:i]``)."""
+    import jax.numpy as jnp
+
+    n = m.shape[-1]
+    eye = jnp.eye(n, dtype=m.dtype)
+    rows = [jnp.broadcast_to(eye[0], m.shape[:-2] + (n,))]
+    for i in range(1, n):
+        prev = jnp.stack(rows, axis=-2)                 # [..., i, n]
+        rows.append(eye[i] - jnp.einsum(
+            "...j,...jk->...k", m[..., i, :i], prev, precision="highest"))
+    return jnp.stack(rows, axis=-2)
+
+
+def _inv_recursive(m):
+    """Block inversion: ``inv([[a, 0], [c, d]]) = [[ia, 0], [-id c ia,
+    id]]``, the two halves inverted in one batched call."""
+    import jax.numpy as jnp
+
+    n = m.shape[-1]
+    if n <= _BASE:
+        return _inv_blocks(m)
+    h = n // 2
+    both = _inv_recursive(jnp.stack([m[..., :h, :h], m[..., h:, h:]]))
+    ia, idd = both[0], both[1]
+    low = -jnp.einsum("...ij,...jk,...kl->...il", idd, m[..., h:, :h], ia,
+                      precision="highest")
+    top = jnp.concatenate([ia, jnp.zeros_like(ia)], axis=-1)
+    return jnp.concatenate([top, jnp.concatenate([low, idd], axis=-1)],
+                           axis=-2)
+
+
+@functools.lru_cache(maxsize=None)
+def _inv_unit_lower():
+    """``inv(m)`` for unit lower triangular ``m`` [..., n, n] (n a power
+    of two times at most ``_BASE``), with the two-matmul backward."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def inv(m):
+        return _inv_recursive(m)
+
+    def fwd(m):
+        t = _inv_recursive(m)
+        return t, t
+
+    def bwd(t, dt):
+        # d(inv) = -inv dM inv; only the strict lower part of M varies
+        dm = -jnp.einsum("...ji,...jk,...lk->...il", t, dt, t,
+                         precision="highest")
+        return (jnp.tril(dm, -1),)
+
+    inv.defvjp(fwd, bwd)
+    return inv
+
+
+def gated_delta_rule_chunked(q, k, v, g, beta, chunk_size=64):
+    """The chunked gated delta rule. q, k [B, S, H, dk] (normalised and
+    scaled by the caller), v [B, S, H, dv], g (log decay, <= 0) and beta
+    [B, S, H] in f32. Returns o [B, S, H, dv] in f32. Matmul operands go
+    in v's dtype (bf16 under AMP) with f32 accumulation; decays, the
+    triangular system and the state stay f32."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    cd = v.dtype
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    C = int(chunk_size)
+    pad = (-S) % C
+    if pad:     # beta = 0, g = 0: a padded position leaves the state alone
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+        g, beta = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (g, beta))
+    N = (S + pad) // C
+
+    def chunks(t):      # [B, S, H, ...] -> [B, H, N, C, ...]
+        t = jnp.moveaxis(t, 2, 1)
+        return t.reshape((B, H, N, C) + t.shape[3:])
+
+    q, k, v, g, beta = (chunks(t) for t in (q, k, v, g, beta))
+    gc = jnp.cumsum(g.astype(f32), axis=-1)             # [B, H, N, C]
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    # decay from position j to position i of a chunk, i >= j
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))
+    kf = k.astype(f32)
+    k_beta = kf * beta[..., None]
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
+    a = mm("bhnid,bhnjd->bhnij", k_beta.astype(cd), k) * decay
+    t = _inv_unit_lower()(jnp.eye(C, dtype=f32) + jnp.tril(a, -1))
+    rhs = jnp.concatenate([v.astype(f32) * beta[..., None],
+                           k_beta * jnp.exp(gc)[..., None]], axis=-1)
+    uw = mm("bhnij,bhnjd->bhnid", t.astype(cd), rhs.astype(cd))
+    u, w = uw[..., :dv], uw[..., dv:]
+    g_last = gc[..., -1]                                # [B, H, N]
+    k_dec = (kf * jnp.exp(g_last[..., None] - gc)[..., None]).astype(cd)
+
+    def step(state, xs):
+        u_n, w_n, k_n, gl_n = xs
+        v_new = u_n - mm("bhcd,bhde->bhce", w_n, state.astype(cd))
+        new = state * jnp.exp(gl_n)[..., None, None] + mm(
+            "bhcd,bhce->bhde", k_n, v_new.astype(cd))
+        return new, (state.astype(cd), v_new.astype(cd))
+
+    lead = lambda x: jnp.moveaxis(x, 2, 0)              # noqa: E731
+    _, (states, v_new) = jax.lax.scan(
+        step, jnp.zeros((B, H, dk, dv), f32),
+        (lead(u), lead(w.astype(cd)), lead(k_dec), lead(g_last)))
+    states, v_new = jnp.moveaxis(states, 0, 2), jnp.moveaxis(v_new, 0, 2)
+    qk = mm("bhnid,bhnjd->bhnij", q, k) * decay
+    o = mm("bhncd,bhnde->bhnce",
+           (q.astype(f32) * jnp.exp(gc)[..., None]).astype(cd), states) \
+        + mm("bhnij,bhnjd->bhnid", qk.astype(cd), v_new)
+    o = jnp.moveaxis(o.reshape(B, H, N * C, dv), 1, 2)
+    return o[:, :S]
+
+
+@register("gated_delta_rule")
+def _gated_delta_rule(ctx, op):
+    """Q, K [B, S, Hk, dk], V [B, S, Hv, dv] (Hv a multiple of Hk: each
+    key head serves Hv/Hk value heads), A and B [B, S, Hv] (the decay's
+    and beta's pre-activations), ALog, DtBias [Hv] -> Out [B, S, Hv, dv].
+    ``g = -exp(ALog) * softplus(A + DtBias)`` and ``beta = sigmoid(B)``
+    in f32; q and k are L2-normalised over the head dim and q is scaled
+    by ``dk ** -0.5``."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    q, k, v = (ctx.get_input(op, s) for s in ("Q", "K", "V"))
+    a = ctx.get_input(op, "A").astype(f32)
+    b = ctx.get_input(op, "B").astype(f32)
+    a_log = ctx.get_input(op, "ALog").astype(f32)
+    dt_bias = ctx.get_input(op, "DtBias").astype(f32)
+    g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+    beta = jax.nn.sigmoid(b)
+    qf, kf = q.astype(f32), k.astype(f32)
+    eps = 1e-6          # the family's l2norm: x * rsqrt(sum(x^2) + eps)
+    qf = qf * jax.lax.rsqrt(jnp.sum(qf * qf, -1, keepdims=True) + eps)
+    kf = kf * jax.lax.rsqrt(jnp.sum(kf * kf, -1, keepdims=True) + eps)
+    rep = v.shape[2] // q.shape[2]
+    assert rep * q.shape[2] == v.shape[2], (q.shape, v.shape)
+    qf, kf = (jnp.repeat(t, rep, axis=2) if rep > 1 else t
+              for t in (qf * q.shape[-1] ** -0.5, kf))
+    _count("chunked")
+    out = gated_delta_rule_chunked(
+        qf.astype(v.dtype), kf.astype(v.dtype), v, g, beta,
+        chunk_size=int(op.attr("chunk_size", 64)))
+    ctx.set_output(op, "Out", out.astype(v.dtype))

@@ -407,6 +407,59 @@ def _layer_norm(ctx, op):
                 ctx.var_dtype(names[0])))
 
 
+@register("rms_norm")
+def _rms_norm(ctx, op):
+    """``x * rsqrt(mean(x^2) + eps) * w`` over the last axis, ``w`` read as
+    ``1 + Scale`` where ``zero_centered`` (a weight stored round 0).
+    Statistics and the normalize run in f32 whatever X's dtype (gray
+    under AMP, like layer_norm); Y is cast back to X's dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    x = ctx.get_input(op, "X")
+    scale = ctx.get_input(op, "Scale")
+    xf = x.astype(jnp.float32)
+    out = xf * jax.lax.rsqrt(
+        jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        + op.attr("epsilon", 1e-6))
+    w = scale.astype(jnp.float32)
+    out = out * (1.0 + w if op.attr("zero_centered", False) else w)
+    ctx.set_output(op, "Y", out.astype(x.dtype))
+
+
+@register("swiglu")
+def _swiglu(ctx, op):
+    """``silu(X) * Y``: the gate of a gated FFN (and of a gated norm)."""
+    import jax
+
+    x = ctx.get_input(op, "X")
+    y = ctx.get_input(op, "Y")
+    ctx.set_output(op, "Out", jax.nn.silu(x) * y)
+
+
+@register("rotary_embedding")
+def _rotary_embedding(ctx, op):
+    """Rotate-half rotary position embedding on the first ``rotary_dim``
+    of X's last axis (partial rotary: the rest passes through). X is
+    [B, H, S, d]; the position of row ``s`` is ``s``. Angles in f32, Out
+    in X's dtype."""
+    import jax.numpy as jnp
+
+    x = ctx.get_input(op, "X")
+    S, d = x.shape[2], x.shape[3]
+    rd = int(op.attr("rotary_dim", d))
+    assert rd % 2 == 0 and rd <= d, (rd, d)
+    inv = 1.0 / (float(op.attr("theta", 10000.0))
+                 ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
+    ang = (jnp.arange(S, dtype=jnp.float32)[:, None] * inv)[None, None]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)           # [1, 1, S, rd/2]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :rd // 2], xf[..., rd // 2:rd]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                           xf[..., rd:]], axis=-1)
+    ctx.set_output(op, "Out", out.astype(x.dtype))
+
+
 @register("group_norm")
 def _group_norm(ctx, op):
     import jax.numpy as jnp
@@ -686,8 +739,20 @@ def _fused_multihead_attention(ctx, op):
     scale = op.attr("scale", None)
     drop = 0.0 if is_test else p
     key = ctx.next_rng() if drop > 0.0 else None
+    kv_heads = int(op.attr("num_kv_heads", 0) or 0)
+    if kv_heads and kv_heads != q.shape[1]:
+        # grouped-query attention: K/V arrive [B, Hkv, S, d] and each KV
+        # head serves H/Hkv consecutive Q heads; repeated to the Q head
+        # count before the kernel (autodiff sums the copies' gradients)
+        import jax.numpy as jnp
+
+        assert k.shape[1] == kv_heads and q.shape[1] % kv_heads == 0, (
+            q.shape, k.shape, kv_heads)
+        k = jnp.repeat(k, q.shape[1] // kv_heads, axis=1)
+        v = jnp.repeat(v, q.shape[1] // kv_heads, axis=1)
     ctx.set_output(op, "Out", fused_attention(
-        q, k, v, bias, scale=scale, dropout_prob=drop, rng_key=key))
+        q, k, v, bias, scale=scale, dropout_prob=drop, rng_key=key,
+        causal=bool(op.attr("causal", False))))
 
 
 @register("fused_multihead_attention_packed", has_state=True)

@@ -255,6 +255,7 @@ DEVICE_MODULE_LINE = "XLA Modules"     # ``jit_train_step(<fingerprint>)``
 _HLO_OP_NAME = re.compile(
     r'^\s*(?:ROOT )?%?([^\s=]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"',
     re.M)
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?%?([^\s=]+) = (.*)$", re.M)
 PHASES = ("forward", "backward", "optimizer", "unattributed")
 # ``transpose(jvp(layer_3_mul))`` -> ``layer_3_mul``
 _SCOPE = re.compile(r"^(?:[\w.]+\()*([^()]*)\)*$")
@@ -286,6 +287,21 @@ def _instruction(event_name):
     return event_name.split(" = ", 1)[0].lstrip("%")
 
 
+# An instruction that only holds other computations: the device line
+# gives it an event that spans its body's events, which are there too.
+# Counting both would count a ``lax.scan``'s or ``lax.cond``'s work twice.
+CONTAINER_OPCODES = frozenset({"while", "conditional", "call"})
+_OPCODE = re.compile(r"[\]\})]\s([a-z][a-z\-]*)\(")
+
+
+def _is_container(hlo_line):
+    """Whether an instruction's HLO text (``%while.3 = (...) while(...)``,
+    as the device line names an event, or a line of a module's text) is
+    of ``CONTAINER_OPCODES``."""
+    found = _OPCODE.search(hlo_line.split(" = ", 1)[-1])
+    return found is not None and found.group(1) in CONTAINER_OPCODES
+
+
 def _module_name(event_name):
     """``jit_train_step(11881051374078078384)`` -> ``jit_train_step``."""
     return re.sub(r"\(\d+\)$", "", event_name)
@@ -294,6 +310,41 @@ def _module_name(event_name):
 def op_names_of(hlo_text):
     """``{instruction name: op_name}`` of a compiled module's HLO text."""
     return dict(_HLO_OP_NAME.findall(hlo_text))
+
+
+# The newest compiled step, kept as what its HLO text can be made from
+# again (the jitted function and its arguments' shapes, no array), so
+# that a reader outside the program can file a device instruction under
+# its program op after the step object and its Executor are gone.
+_NEWEST_STEP = None
+_NEWEST_REGIONS = None
+
+
+def note_compiled_step(fn, arg_specs):
+    """``Executor.run`` calls this where it has compiled a step."""
+    global _NEWEST_STEP, _NEWEST_REGIONS
+    _NEWEST_STEP, _NEWEST_REGIONS = (fn, arg_specs), None
+
+
+def newest_step_regions():
+    """``{instruction name: (phase, program op type)}`` of the newest
+    compiled step's module (``region_of`` of each instruction's
+    ``op_name``; ``while`` / ``conditional`` / ``call`` instructions left
+    out, their bodies' being there), built on the first call: the step is lowered again
+    from the noted shapes (JAX's caches make that seconds). None where no
+    step was compiled or it cannot be lowered (a disk-tier or sharded
+    wrapper)."""
+    global _NEWEST_REGIONS
+    if _NEWEST_REGIONS is None and _NEWEST_STEP is not None:
+        fn, arg_specs = _NEWEST_STEP
+        if hasattr(fn, "lower"):
+            text = fn.lower(*arg_specs).compile().as_text()
+            containers = {name for name, rest in _HLO_LINE.findall(text)
+                          if _is_container(rest)}
+            _NEWEST_REGIONS = {name: region_of(op_name) for name, op_name
+                               in op_names_of(text).items()
+                               if name not in containers}
+    return _NEWEST_REGIONS
 
 
 def _live_hlo_texts(profile):
@@ -376,6 +427,8 @@ def device_time_by_region(profile, hlo_texts=None):
     phases = {p: [0, 0.0] for p in PHASES}
     ops, instructions, regions, intervals = {}, {}, {}, {}
     for plane, _, event in _device_events(profile):
+        if _is_container(event.name):
+            continue    # its body's operations are events of their own
         module = ""
         spans = runs.get(plane, ())
         i = bisect.bisect_right(spans, (event.start_ns, float("inf"))) - 1

@@ -37,6 +37,16 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       overflow scoped VMEM at S=4096; see _long_qb). Row-broadcast
       bias only (per-row bias falls through to blockwise).
   fallback — blockwise online-softmax scan (no [S, S] anywhere).
+  ``causal=True`` (every tier above and the fallback) — column > row is
+      masked INSIDE the kernel from the tile's row and column indices
+      (``_causal_mask``), so a causal call needs no [S, S] bias and keeps
+      the tier its S selects: the flash tier takes row-broadcast bias
+      only, and a ``[1, 1, S, S]`` causal bias would fall to the
+      blockwise scan. Every k-tile is still computed (one wholly above
+      the diagonal adds 0); skipping them is a later optimisation. A call
+      without ``causal`` traces to the jaxpr it always did. Fewer KV than
+      Q heads: the ``fused_multihead_attention`` op repeats K/V to the Q
+      head count before the kernel (``num_kv_heads``).
 
 Packed layout (``fused_attention_packed``, FORCE=packed): q/k/v stay in
 the fc-native [B, S, H*d] layout with heads handled inside the kernel;
@@ -194,12 +204,24 @@ def _uniform_from_bits(bits):
     return (bits >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24))
 
 
-def _ref_attention(q, k, v, bias, scale, p_drop, seed):
+def _causal_mask(s, row0=0, col0=0):
+    """``s`` with -1e30 wherever the column lies after the row, by index
+    over the last two axes; ``row0`` / ``col0`` are the tile's offsets in
+    the sequence. -1e30, not -inf (NaN discipline): column 0 is open to
+    every row, so a row's maximum is real by the first k-tile."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 2)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
+    return jnp.where(cols <= rows, s, -1e30)
+
+
+def _ref_attention(q, k, v, bias, scale, p_drop, seed, causal=False):
     """jnp reference (the fallback and the numerics oracle in tests)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
+    if causal:
+        s = _causal_mask(s)
     p = jax.nn.softmax(s, axis=-1)
     if p_drop > 0.0:
         key = jax.random.fold_in(jax.random.PRNGKey(0), seed[0])
@@ -209,7 +231,8 @@ def _ref_attention(q, k, v, bias, scale, p_drop, seed):
         q.dtype)
 
 
-def _blockwise_attention(q, k, v, bias, scale, p_drop, seed):
+def _blockwise_attention(q, k, v, bias, scale, p_drop, seed,
+                         causal=False):
     """Online-softmax attention over K/V blocks (the single-device form of
     the ring-attention fold, ``parallel/attention.py``): the [S, S] score
     matrix never exists — per block, scores are folded into (max, denom,
@@ -249,6 +272,8 @@ def _blockwise_attention(q, k, v, bias, scale, p_drop, seed):
         kblk, vblk, bblk, i = xs
         s = jnp.einsum("bhqd,bhkd->bhqk", qf,
                        kblk.astype(jnp.float32)) * scale + bblk
+        if causal:
+            s = _causal_mask(s, 0, i * block)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
@@ -268,15 +293,17 @@ def _blockwise_attention(q, k, v, bias, scale, p_drop, seed):
     return (o / l[..., None]).astype(q.dtype)
 
 
-def _fallback_attention(q, k, v, bias, scale, p_drop, seed):
+def _fallback_attention(q, k, v, bias, scale, p_drop, seed, causal=False):
     """Off-kernel path: one-pass reference below the VMEM bound, blockwise
     online softmax above it."""
     if q.shape[2] > _MAX_FUSED_SEQ:
-        return _blockwise_attention(q, k, v, bias, scale, p_drop, seed)
-    return _ref_attention(q, k, v, bias, scale, p_drop, seed)
+        return _blockwise_attention(q, k, v, bias, scale, p_drop, seed,
+                                    causal)
+    return _ref_attention(q, k, v, bias, scale, p_drop, seed, causal)
 
 
-def _attn_block_fwd(q, k, v, bias_b, seed_ref, scale, p_drop, stream):
+def _attn_block_fwd(q, k, v, bias_b, seed_ref, scale, p_drop, stream,
+                    causal=False):
     """Shared per-(batch-block, head) forward math: q/k/v [Bb, S, d],
     bias_b [Bb, Sq|1, S] additive. Returns o [Bb, S, d] f32."""
 
@@ -285,6 +312,8 @@ def _attn_block_fwd(q, k, v, bias_b, seed_ref, scale, p_drop, stream):
     s = jax.lax.dot_general(q, k, dn,
                             preferred_element_type=jnp.float32) * scale
     s = s + bias_b
+    if causal:
+        s = _causal_mask(s)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)
@@ -297,7 +326,8 @@ def _attn_block_fwd(q, k, v, bias_b, seed_ref, scale, p_drop, stream):
         preferred_element_type=jnp.float32)
 
 
-def _attn_block_bwd(q, k, v, do, bias_b, seed_ref, scale, p_drop, stream):
+def _attn_block_bwd(q, k, v, do, bias_b, seed_ref, scale, p_drop, stream,
+                    causal=False):
     """Shared per-(batch-block, head) backward math (probabilities
     recomputed flash-style, dropout mask regenerated from the forward's
     stream). Returns (dq, dk, dv, ds) — ds [Bb, S, S] f32 pre-reduction
@@ -307,6 +337,8 @@ def _attn_block_bwd(q, k, v, do, bias_b, seed_ref, scale, p_drop, stream):
     s = jax.lax.dot_general(q, k, dn_qk,
                             preferred_element_type=jnp.float32) * scale
     s = s + bias_b
+    if causal:
+        s = _causal_mask(s)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)   # pre-dropout probs
@@ -336,24 +368,24 @@ def _attn_block_bwd(q, k, v, do, bias_b, seed_ref, scale, p_drop, stream):
 
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
-                scale, p_drop, n_heads):
+                scale, p_drop, n_heads, causal=False):
     """One grid step = a BLOCK of batches for one head: batched matmuls
     keep the MXU busy (a single (b, h) pair at S=128 is DMA-bound)."""
 
     b, h = pl.program_id(0), pl.program_id(1)
     o = _attn_block_fwd(q_ref[:, 0], k_ref[:, 0], v_ref[:, 0],
                         bias_ref[:, 0], seed_ref, scale, p_drop,
-                        b * n_heads + h)
+                        b * n_heads + h, causal)
     o_ref[:, 0] = o.astype(o_ref.dtype)
 
 
 def _bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dbias_ref, *, scale, p_drop,
-                n_heads, acc_heads, reduce_rows):
+                n_heads, acc_heads, reduce_rows, causal=False):
     b, h = pl.program_id(0), pl.program_id(1)
     dq, dk, dv, ds = _attn_block_bwd(
         q_ref[:, 0], k_ref[:, 0], v_ref[:, 0], do_ref[:, 0],
-        bias_ref[:, 0], seed_ref, scale, p_drop, b * n_heads + h)
+        bias_ref[:, 0], seed_ref, scale, p_drop, b * n_heads + h, causal)
     dq_ref[:, 0] = dq.astype(dq_ref.dtype)
     dk_ref[:, 0] = dk.astype(dk_ref.dtype)
     dv_ref[:, 0] = dv.astype(dv_ref.dtype)
@@ -401,7 +433,7 @@ def _long_qb(S, d):
 
 
 def _fwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
-                     scale, p_drop, n_heads, n_qtiles):
+                     scale, p_drop, n_heads, n_qtiles, causal=False):
     """Long-sequence forward: grid (B, H, S/Qb). K/V for the whole
     (batch, head) sit in VMEM (S·d is small even when S² is not); each
     step computes one [Qb, S] score tile and its softmax in one pass —
@@ -413,6 +445,8 @@ def _fwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = s + bias_ref[0, 0]                        # [Qb|1, S]
+    if causal:
+        s = _causal_mask(s, pl.program_id(2) * q.shape[0])
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)
@@ -428,7 +462,8 @@ def _fwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
 
 def _bwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                      dq_ref, dk_ref, dv_ref, dbias_ref, *, scale, p_drop,
-                     n_heads, n_qtiles, acc_heads, reduce_rows):
+                     n_heads, n_qtiles, acc_heads, reduce_rows,
+                     causal=False):
     """Long-sequence backward: q-tile is the fastest grid dim, so the
     (b, h)-indexed dk/dv blocks are revisited across tiles and accumulate
     in VMEM (same revisit-accumulate idiom as dbias in _bwd_kernel)."""
@@ -440,6 +475,8 @@ def _bwd_kernel_long(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = s + bias_ref[0, 0]
+    if causal:
+        s = _causal_mask(s, pl.program_id(2) * q.shape[0])
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)
@@ -542,14 +579,15 @@ def _use_long_kernel(q, p_drop, bias):
     return not (_interpret() and p_drop > 0.0)
 
 
-def _pallas_attention_long(q, k, v, bias, scale, p_drop, seed):
+def _pallas_attention_long(q, k, v, bias, scale, p_drop, seed,
+                           causal=False):
     _count_kernel("long")
     B, H, S, d = q.shape
     grid, qspec, kvspec, bspec, nq, QB = _long_specs(q, bias)
     return _kernel_call(
         "attn_long_fwd",
         functools.partial(_fwd_kernel_long, scale=scale, p_drop=p_drop,
-                          n_heads=H, n_qtiles=nq),
+                          n_heads=H, n_qtiles=nq, causal=causal),
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   qspec, kvspec, kvspec, bspec],
@@ -558,7 +596,8 @@ def _pallas_attention_long(q, k, v, bias, scale, p_drop, seed):
     )(seed, q, k, v, bias)
 
 
-def _pallas_attention_long_bwd(q, k, v, bias, seed, do, scale, p_drop):
+def _pallas_attention_long_bwd(q, k, v, bias, seed, do, scale, p_drop,
+                               causal=False):
     _count_kernel("long_bwd")
     B, H, S, d = q.shape
     grid, qspec, kvspec, bspec, nq, QB = _long_specs(q, bias)
@@ -575,7 +614,7 @@ def _pallas_attention_long_bwd(q, k, v, bias, seed, do, scale, p_drop):
         "attn_long_bwd",
         functools.partial(_bwd_kernel_long, scale=scale, p_drop=p_drop,
                           n_heads=H, n_qtiles=nq, acc_heads=acc_heads,
-                          reduce_rows=reduce_rows),
+                          reduce_rows=reduce_rows, causal=causal),
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   qspec, kvspec, kvspec, bspec, qspec],
@@ -639,7 +678,7 @@ def _flash_seed(seed0, b, h, i, j, n_heads, nq, nk):
 
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                       lse_ref, acc_scr, m_scr, l_scr, *, scale, p_drop,
-                      n_heads, nq, nk):
+                      n_heads, nq, nk, causal=False):
     """Grid (B, H, nq, nk), k-tile fastest: classic online softmax. The
     (m, l, acc) carries live in VMEM scratch across the k-tile sweep; o
     and the row logsumexp L are written on the last k-tile. Dropout
@@ -653,6 +692,8 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = s + bias_ref[0, 0]                        # [1, Tb] row-broadcast
+    if causal:      # every k-tile is computed; one wholly masked adds 0
+        s = _causal_mask(s, pl.program_id(2) * q.shape[0], j * k.shape[0])
 
     @pl.when(j == 0)
     def _init():
@@ -685,7 +726,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 
 def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                      lse_ref, dd_ref, dq_ref, dbias_ref, *, scale, p_drop,
-                     n_heads, nq, nk):
+                     n_heads, nq, nk, causal=False):
     """Split backward, half 1 — grid (B, H, nq, nk), k-tile fastest: the
     dq block (keyed on the q-tile) accumulates over consecutive k-tile
     steps. Probabilities regenerate from the saved logsumexp: p =
@@ -702,6 +743,8 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = s + bias_ref[0, 0]
+    if causal:
+        s = _causal_mask(s, pl.program_id(2) * q.shape[0], j * k.shape[0])
     p = jnp.exp(s - lse)                          # undropped softmax rows
     if p_drop > 0.0:
         b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -730,7 +773,7 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
 
 def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                        lse_ref, dd_ref, dk_ref, dv_ref, *, scale, p_drop,
-                       n_heads, nq, nk):
+                       n_heads, nq, nk, causal=False):
     """Split backward, half 2 — grid (B, H, nk, nq), q-tile fastest: the
     dk/dv blocks (keyed on the k-tile) accumulate over consecutive
     q-tile steps. The PRNG seed uses the same (i, j) formula as the
@@ -747,6 +790,8 @@ def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = s + bias_ref[0, 0]
+    if causal:
+        s = _causal_mask(s, i * q.shape[0], j * k.shape[0])
     p = jnp.exp(s - lse)
     if p_drop > 0.0:
         b, h = pl.program_id(0), pl.program_id(1)
@@ -795,7 +840,8 @@ def _flash_specs(q, bias):
     return TB, nt, qspec, kspec, bspec, rowspec
 
 
-def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed):
+def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed,
+                            causal=False):
     """Returns (o, lse): lse [B, H, S] f32 feeds the split backward."""
 
     _count_kernel("flash")
@@ -805,7 +851,7 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed):
     return _kernel_call(
         "attn_flash_fwd",
         functools.partial(_flash_fwd_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=H, nq=nt, nk=nt),
+                          n_heads=H, nq=nt, nk=nt, causal=causal),
         grid=(B, H, nt, nt),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   qspec, kspec, kspec, bspec],
@@ -820,7 +866,7 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed):
 
 
 def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
-                                p_drop):
+                                p_drop, causal=False):
     _count_kernel("flash_bwd")
     B, H, S, d = q.shape
     TB, nt, qspec, kspec, bspec, rowspec = _flash_specs(q, bias)
@@ -837,7 +883,7 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
     dq, dbp = _kernel_call(
         "attn_flash_bwd_dq",
         functools.partial(_flash_dq_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=H, nq=nt, nk=nt),
+                          n_heads=H, nq=nt, nk=nt, causal=causal),
         grid=(B, H, nt, nt),
         in_specs=[smem, qspec, kspec, kspec, bspec, qspec, rowspec,
                   rowspec],
@@ -858,7 +904,7 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
     dk, dv = _kernel_call(
         "attn_flash_bwd_dkv",
         functools.partial(_flash_dkdv_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=H, nq=nt, nk=nt),
+                          n_heads=H, nq=nt, nk=nt, causal=causal),
         grid=(B, H, nt, nt),
         in_specs=[smem, qspec_t, kspec_t, kspec_t, bspec_t, qspec_t,
                   rowspec_t, rowspec_t],
@@ -1433,7 +1479,7 @@ def _fwd_budget(p_drop):
     return _BWD_BUDGET if p_drop > 0.0 else 2 * 1024 * 1024
 
 
-def _pallas_attention(q, k, v, bias, scale, p_drop, seed):
+def _pallas_attention(q, k, v, bias, scale, p_drop, seed, causal=False):
     _count_kernel("block")
     B, H, S, d = q.shape
     grid, qspec, _, bspec = _specs(q, bias,
@@ -1441,7 +1487,7 @@ def _pallas_attention(q, k, v, bias, scale, p_drop, seed):
     return _kernel_call(
         "attn_block_fwd",
         functools.partial(_fwd_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=H),
+                          n_heads=H, causal=causal),
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   qspec, qspec, qspec, bspec],
@@ -1450,7 +1496,8 @@ def _pallas_attention(q, k, v, bias, scale, p_drop, seed):
     )(seed, q, k, v, bias)
 
 
-def _pallas_attention_bwd(q, k, v, bias, seed, do, scale, p_drop):
+def _pallas_attention_bwd(q, k, v, bias, seed, do, scale, p_drop,
+                          causal=False):
     _count_kernel("block_bwd")
     B, H, S, d = q.shape
     grid, qspec, sspec, bspec = _specs(q, bias, tile_budget=_BWD_BUDGET)
@@ -1462,7 +1509,7 @@ def _pallas_attention_bwd(q, k, v, bias, seed, do, scale, p_drop):
         "attn_block_bwd",
         functools.partial(_bwd_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H, acc_heads=acc_heads,
-                          reduce_rows=reduce_rows),
+                          reduce_rows=reduce_rows, causal=causal),
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   qspec, qspec, qspec, bspec, qspec],
@@ -1484,51 +1531,52 @@ def _use_kernel(q, p_drop):
     return not (_interpret() and p_drop > 0.0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _fused(q, k, v, bias, scale, p_drop, seed):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 7))
+def _fused(q, k, v, bias, scale, p_drop, seed, causal=False):
     if _use_kernel(q, p_drop):
-        return _pallas_attention(q, k, v, bias, scale, p_drop, seed)
+        return _pallas_attention(q, k, v, bias, scale, p_drop, seed, causal)
     if _use_long_kernel(q, p_drop, bias):
-        return _pallas_attention_long(q, k, v, bias, scale, p_drop, seed)
+        return _pallas_attention_long(q, k, v, bias, scale, p_drop, seed,
+                                      causal)
     if _use_flash_kernel(q, p_drop, bias):
         return _pallas_attention_flash(q, k, v, bias, scale, p_drop,
-                                       seed)[0]
-    return _fallback_attention(q, k, v, bias, scale, p_drop, seed)
+                                       seed, causal)[0]
+    return _fallback_attention(q, k, v, bias, scale, p_drop, seed, causal)
 
 
-def _fused_fwd(q, k, v, bias, scale, p_drop, seed):
+def _fused_fwd(q, k, v, bias, scale, p_drop, seed, causal=False):
     if _use_flash_kernel(q, p_drop, bias):
         # the split backward regenerates probabilities from the row
         # logsumexp and needs rowsum(do*o), so o and lse join the
         # residuals (flash-attention-2 residual set: q, k, v, o, L)
         o, lse = _pallas_attention_flash(q, k, v, bias, scale, p_drop,
-                                         seed)
+                                         seed, causal)
         return o, (q, k, v, bias, seed, (o, lse))
-    out = _fused(q, k, v, bias, scale, p_drop, seed)
+    out = _fused(q, k, v, bias, scale, p_drop, seed, causal)
     return out, (q, k, v, bias, seed, None)
 
 
-def _fused_bwd(scale, p_drop, res, do):
+def _fused_bwd(scale, p_drop, causal, res, do):
     q, k, v, bias, seed, flash_res = res
     if flash_res is not None:
         o, lse = flash_res
         dq, dk, dv, dbias = _pallas_attention_flash_bwd(
-            q, k, v, bias, seed, do, o, lse, scale, p_drop)
+            q, k, v, bias, seed, do, o, lse, scale, p_drop, causal)
         return (dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype),
                 dbias.astype(bias.dtype), _seed_ct(seed))
     if _use_kernel(q, p_drop):
         dq, dk, dv, dbias = _pallas_attention_bwd(q, k, v, bias, seed, do,
-                                               scale, p_drop)
+                                               scale, p_drop, causal)
     elif _use_long_kernel(q, p_drop, bias):
         dq, dk, dv, dbias = _pallas_attention_long_bwd(
-            q, k, v, bias, seed, do, scale, p_drop)
+            q, k, v, bias, seed, do, scale, p_drop, causal)
     else:
         # recompute-based vjp through the fallback path (blockwise past
         # the VMEM bound: remat'd scan keeps bwd memory at the per-step
         # carries, O(nb*S*d) — see _blockwise_attention)
         def f(q_, k_, v_, bias_):
             return _fallback_attention(q_, k_, v_, bias_, scale, p_drop,
-                                       seed)
+                                       seed, causal)
 
         _, vjp = jax.vjp(f, q, k, v, bias)
         dq, dk, dv, dbias = vjp(do)
@@ -1546,16 +1594,19 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
-                    rng_key=None):
+                    rng_key=None, causal=False):
     """softmax(q·kᵀ·scale + bias)·v fused per (batch, head).
 
     q/k/v: [B, H, S, d]; bias broadcastable [B, 1|H, 1|S, S] additive
-    (0 keep / -1e4 mask); returns [B, H, S, d] in q's dtype.
+    (0 keep / -1e4 mask); returns [B, H, S, d] in q's dtype. ``causal``
+    masks column > row inside the kernels, by index: no [S, S] bias, so
+    the flash tier (row-broadcast bias only) stays open to it.
     """
     B, H, S, d = q.shape
     scale, bias, seed = _prep_bias_seed(B, S, d, bias, scale,
                                         dropout_prob, rng_key)
-    return _fused(q, k, v, bias, scale, float(dropout_prob), seed)
+    return _fused(q, k, v, bias, scale, float(dropout_prob), seed,
+                  bool(causal))
 
 
 # ---------------------------------------------------------------------------
