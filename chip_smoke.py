@@ -60,6 +60,8 @@ def _real_sizes():
         dropout_kernels=True,
         decode_case=(8, 16, 1024),                  # B, H, capacity
         paged_pages=(16, 128),
+        # B, S, Hk, Hv, d: one row of qwen3-next-80b-a3b.train-s8192
+        delta_rule_case=(1, 8192, 16, 32, 128),
         transformer=transformer.Transformer.big,
         vocab=32000,
         serve=dict(batch_size=8, src_len=128, prompt_len=64,
@@ -83,6 +85,7 @@ def _toy_sizes():
         dropout_kernels=False,
         decode_case=(2, 2, 1024),
         paged_pages=(128,),
+        delta_rule_case=(1, 256, 1, 2, 128),
         transformer=transformer.Transformer.tiny,
         vocab=512,
         serve=dict(batch_size=4, src_len=8, prompt_len=4,
@@ -347,12 +350,79 @@ def _check_paged(A, B, H, cap, ptok, dtype):
             "err": _sig(err)}
 
 
+def _check_delta_rule(B, S, Hk, Hv, d, chunk=64):
+    """The gated delta rule's kernels (kernels/delta_rule.py) in bf16,
+    forward and the five cotangents, against the f32 position-by-position
+    recurrence. The recurrence's backward keeps a state a position, so it
+    runs on the first key head and its value heads only; heads do not mix,
+    so those heads' cotangents are the whole comparison for them."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.kernels import delta_rule
+
+    def traced():
+        return {i: monitor.counter("gdn_dispatch_total",
+                                   labels={"impl": i}).value
+                for i in ("pallas", "pallas_bwd")}
+
+    ks = jax.random.split(jax.random.PRNGKey(29), 5)
+    q = jax.random.normal(ks[0], (B, S, Hk, d))
+    k = jax.random.normal(ks[1], (B, S, Hk, d))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, S, Hv, d))
+    g = -0.5 * jax.nn.softplus(jax.random.normal(ks[3], (B, S, Hv)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, Hv)))
+    q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    rep = Hv // Hk
+
+    def recurrence(q, k, v, g, beta):      # one key head, f32
+        def step(state, xs):
+            q_t, k_t, v_t, g_t, b_t = xs
+            state = state * jnp.exp(g_t)[..., None, None]
+            delta = b_t[..., None] * (
+                v_t - jnp.einsum("bhkv,bk->bhv", state, k_t))
+            state = state + k_t[:, None, :, None] * delta[..., None, :]
+            return state, jnp.einsum("bhkv,bk->bhv", state, q_t)
+        xs = tuple(jnp.moveaxis(t.astype(jnp.float32), 1, 0)
+                   for t in (q[:, :, 0], k[:, :, 0], v, g, beta))
+        _, o = jax.lax.scan(step, jnp.zeros((B, rep, d, d)), xs)
+        return jnp.moveaxis(o, 0, 1)
+
+    def run(fn, *args):
+        def loss(*a):
+            o = fn(*a).astype(jnp.float32)
+            return jnp.sum(jnp.sin(4.0 * o)), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        return (o,) + grads
+
+    before = traced()
+    got = run(lambda *a: delta_rule.gated_delta_rule_pallas(
+        *a, chunk_size=chunk), q, k, v, g, beta)
+    missing = [i for i, n in traced().items() if n <= before[i]]
+    assert not missing, "delta rule: never traced %r" % missing
+    with jax.default_matmul_precision("highest"):
+        want = run(recurrence, q[:, :, :1], k[:, :, :1], v[:, :, :rep],
+                   g[:, :, :rep], beta[:, :, :rep])
+    # o, dq, dk, dv, dg, dbeta: the first key head's share of each
+    heads = (rep, 1, 1, rep, rep, rep)
+    errs = [_rel_err(a[:, :, :h], b) for a, b, h in zip(got, want, heads)]
+    assert max(errs) < _TOL, (
+        "gated_delta_rule pallas S=%d chunk=%d: o dq dk dv dg dbeta %r"
+        % (S, chunk, errs))
+    return {"S": S, "B": B, "heads": [Hk, Hv], "dtype": "bfloat16",
+            "tier": "gdn_pallas", "err": _sig(max(errs))}
+
+
 def phase_kernels(sz):
     import jax.numpy as jnp
 
     from paddle_tpu.kernels import attention as A
 
-    cases = []
+    cases = [_check_delta_rule(*sz.delta_rule_case)]
     B, H, C = sz.decode_case
     for dtype in (jnp.float32, jnp.bfloat16):
         for S, b, h, tier in sz.fused_cases:

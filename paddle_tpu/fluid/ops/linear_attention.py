@@ -10,10 +10,14 @@ Per head, with a ``[dk, dv]`` state ``S_0 = 0``::
 The lowering is the CHUNKED form (chunks of ``chunk_size`` positions):
 inside a chunk the rank-one updates are folded into one unit-lower
 triangular system, solved by block inversion; between chunks the state is
-carried by a ``lax.scan`` over the chunks, which holds only the two
-matmuls that touch the state. Everything else is batched over all chunks.
-Plain XLA: no Pallas kernel here yet. The ``autodiff`` op differentiates
-it like any lowering.
+carried. Two implementations of the same equations and precisions: the
+Pallas kernels of ``kernels/delta_rule.py`` (forward and backward, a
+chunk's ``[C, C]`` work in VMEM) where head dims are multiples of 128, the
+chunk packs into a 128-row group and a TPU (or the interpreter) is there;
+else ``gated_delta_rule_chunked`` in plain XLA - a ``lax.scan`` over the
+chunks holds the two matmuls that touch the state, everything else is
+batched over all chunks - which is also the kernels' oracle. The
+``autodiff`` op differentiates either like any lowering.
 """
 
 import functools
@@ -214,6 +218,8 @@ def _gated_delta_rule(ctx, op):
     import jax
     import jax.numpy as jnp
 
+    from ...kernels import delta_rule
+
     f32 = jnp.float32
     q, k, v = (ctx.get_input(op, s) for s in ("Q", "K", "V"))
     a = ctx.get_input(op, "A").astype(f32)
@@ -222,16 +228,23 @@ def _gated_delta_rule(ctx, op):
     dt_bias = ctx.get_input(op, "DtBias").astype(f32)
     g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
     beta = jax.nn.sigmoid(b)
-    qf, kf = q.astype(f32), k.astype(f32)
-    eps = 1e-6          # the family's l2norm: x * rsqrt(sum(x^2) + eps)
-    qf = qf * jax.lax.rsqrt(jnp.sum(qf * qf, -1, keepdims=True) + eps)
-    kf = kf * jax.lax.rsqrt(jnp.sum(kf * kf, -1, keepdims=True) + eps)
     rep = v.shape[2] // q.shape[2]
     assert rep * q.shape[2] == v.shape[2], (q.shape, v.shape)
-    qf, kf = (jnp.repeat(t, rep, axis=2) if rep > 1 else t
-              for t in (qf * q.shape[-1] ** -0.5, kf))
-    _count("chunked")
-    out = gated_delta_rule_chunked(
-        qf.astype(v.dtype), kf.astype(v.dtype), v, g, beta,
-        chunk_size=int(op.attr("chunk_size", 64)))
+    chunk = int(op.attr("chunk_size", 64))
+    eps = 1e-6          # the family's l2norm: x * rsqrt(sum(x^2) + eps)
+    # by what the input shows: the kernels where the shapes fill their
+    # tiles (and a TPU or the interpreter is there), the XLA form elsewhere
+    if delta_rule.supported(q.shape[-1], v.shape[-1], chunk):
+        out = delta_rule.gated_delta_rule_pallas(       # counts "pallas"
+            q, k, v, g, beta, chunk_size=chunk, l2norm_eps=eps)
+    else:
+        _count("chunked")
+        qf, kf = q.astype(f32), k.astype(f32)
+        qf = qf * jax.lax.rsqrt(jnp.sum(qf * qf, -1, keepdims=True) + eps)
+        kf = kf * jax.lax.rsqrt(jnp.sum(kf * kf, -1, keepdims=True) + eps)
+        qn, kn = (qf * q.shape[-1] ** -0.5).astype(v.dtype), \
+            kf.astype(v.dtype)
+        if rep > 1:
+            qn, kn = (jnp.repeat(t, rep, axis=2) for t in (qn, kn))
+        out = gated_delta_rule_chunked(qn, kn, v, g, beta, chunk_size=chunk)
     ctx.set_output(op, "Out", out.astype(v.dtype))

@@ -113,20 +113,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .common import interpret as _interpret
+from .common import named_pallas_call
+from .common import supports_pallas as _supports_pallas
+
 _MAX_FUSED_SEQ = 1024
-
-
-def _interpret():
-    """PADDLE_TPU_PALLAS_INTERPRET=1 runs the kernels through the pallas
-    interpreter (CPU CI exercises the real kernel bodies). On a TPU it
-    is an error, not a mode: a stray setting would leave the chip idle
-    behind the interpreter and say nothing."""
-    on = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET", "") == "1"
-    if on and jax.devices()[0].platform == "tpu":
-        raise RuntimeError(
-            "PADDLE_TPU_PALLAS_INTERPRET=1 on a tpu platform: the Pallas "
-            "kernels would run interpreted instead of compiled; unset it")
-    return on
 
 
 KERNEL_TIERS = ("block", "block_bwd", "long", "long_bwd", "flash",
@@ -145,22 +136,9 @@ KERNEL_NAMES = (
 
 
 def _kernel_call(name, kernel, **kw):
-    """``pl.pallas_call`` under one of ``KERNEL_NAMES``, given twice:
-    as ``name=`` and as a ``named_scope`` round the call. XLA names the
-    custom call after the innermost scope it sits in, and under a
-    transform the outermost scope reads ``jvp(<name>)``; with two, the
-    inner one stays plain whatever the call was traced under. ``name=``
-    also goes into the Mosaic module, so a compilation cache keyed on
-    the program without its debug info cannot serve the unnamed kernel
-    in place of this one."""
+    """``common.named_pallas_call`` under one of ``KERNEL_NAMES``."""
     assert name in KERNEL_NAMES, name
-    call = pl.pallas_call(kernel, name=name, interpret=_interpret(), **kw)
-
-    def named(*args):
-        with jax.named_scope(name):
-            return call(*args)
-
-    return named
+    return named_pallas_call(name, kernel, **kw)
 
 
 def _count_kernel(tier):
@@ -193,10 +171,6 @@ def _attn_force():
             "PADDLE_TPU_ATTN_FORCE=%r not understood; expected one of "
             "%s (or unset)" % (v, ", ".join(_ATTN_FORCE_VALUES)))
     return v
-
-
-def _supports_pallas():
-    return _interpret() or jax.devices()[0].platform == "tpu"
 
 
 def _uniform_from_bits(bits):

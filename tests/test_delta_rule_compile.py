@@ -1,0 +1,69 @@
+"""The delta rule's kernels at the benchmark cell's shape, compiled for a
+DESCRIBED v5e (no chip attached): what the chip's compiler refuses - a
+slice off the tiling, too much VMEM, an op Mosaic cannot lower - it
+refuses here, at no chip time. Nothing runs, so nothing is said about
+results or speed. The topology is described inside a fixture, never at
+import: only the worker that is given this file loads the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# qwen3-next-80b-a3b.train-s8192: batch, sequence, key heads, value heads,
+# head dim, chunk
+B, S, HK, HV, D, C = 2, 8192, 16, 32, 128, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_text(one_chip, monkeypatch):
+    """``fn -> HLO text`` of ``fn`` over the cell's q, k, v, gc, beta,
+    compiled for the chip with the kernels NOT interpreted."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    # a compile for a described device cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((B, S, HK * D), jnp.bfloat16),
+            spec((B, S, HK * D), jnp.bfloat16),
+            spec((B, S, HV * D), jnp.bfloat16),
+            spec((B, HV, S // 128, 128), jnp.float32),
+            spec((B, HV, S // 128, 128), jnp.float32))
+    yield lambda fn: jax.jit(fn).lower(*args).compile().as_text()
+    jax.config.update("jax_enable_compilation_cache", True)
+
+
+def _core(*args):
+    from paddle_tpu.kernels import delta_rule
+
+    return delta_rule._core(*args, D, D, C, 1e-6)
+
+
+def test_forward_kernel_compiles_for_v5e_at_the_cells_shape(compiled_text):
+    text = compiled_text(_core)
+    assert "tpu_custom_call" in text and "gdn_chunk_fwd" in text
+
+
+def test_backward_kernel_compiles_for_v5e_at_the_cells_shape(compiled_text):
+    text = compiled_text(jax.grad(
+        lambda *a: jnp.sum(_core(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4)))
+    assert "tpu_custom_call" in text
+    assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text

@@ -323,3 +323,45 @@ def test_newest_step_regions_files_instructions_under_program_ops(
     for op_type in ("gated_delta_rule", "moe_experts", "rms_norm"):
         assert ("forward", op_type) in found, op_type
         assert ("backward", op_type) in found, op_type
+
+
+def test_kernel_path_files_its_kernels_under_the_program_op(monkeypatch):
+    """A step whose ``gated_delta_rule`` took the Pallas kernels (head dim
+    128, the interpreter standing in for the chip): the forward kernel's
+    instructions are filed under (forward, gated_delta_rule), the backward
+    kernel's under (backward, gated_delta_rule) - where the ``gdn_*``
+    metrics read them."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor, profiler
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    S, Hk, Hv, d = 128, 1, 2, 128
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        ins = [fluid.layers.data(n, shape) for n, shape in (
+            ("q", [S, Hk, d]), ("k", [S, Hk, d]), ("v", [S, Hv, d]),
+            ("a", [S, Hv]), ("b", [S, Hv]))]
+        w = fluid.layers.create_parameter([d], "float32", name="v_scale")
+        q, k, v, a, b = ins
+        loss = fluid.layers.mean(fluid.layers.gated_delta_rule(
+            q, k, v * w, a, b, chunk_size=64))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    rng = np.random.RandomState(0)
+    feed = {t.name: rng.randn(2, *t.shape[1:]).astype("float32")
+            for t in ins}
+    before = monitor.counter("gdn_dispatch_total",
+                             labels={"impl": "pallas_bwd"}).value
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor()
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])
+        regions = profiler.newest_step_regions()
+        fn, specs = profiler._NEWEST_STEP
+    assert monitor.counter("gdn_dispatch_total",
+                           labels={"impl": "pallas_bwd"}).value > before
+    op_names = profiler.op_names_of(fn.lower(*specs).compile().as_text())
+    for kernel, phase in (("gdn_chunk_fwd", "forward"),
+                          ("gdn_chunk_bwd", "backward")):
+        filed = {regions[i] for i, name in op_names.items()
+                 if "/%s/" % kernel in name and i in regions}
+        assert filed == {(phase, "gated_delta_rule")}, (kernel, filed)

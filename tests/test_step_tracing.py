@@ -195,20 +195,31 @@ def test_a_tracing_profiler_does_not_block_and_run_events_are_no_series(
     assert event in report and "Device time by region" in report
 
 
-def test_every_pallas_call_carries_a_name_of_the_table():
-    tree = ast.parse(open(attention.__file__).read())
-    raw, named = [], []
-    for node in ast.walk(tree):
+def _calls(module, func_name):
+    """The ``ast.Call`` nodes of ``module``'s source that call
+    ``func_name`` (a bare name or an attribute)."""
+    found = []
+    for node in ast.walk(ast.parse(open(module.__file__).read())):
         if isinstance(node, ast.Call):
             f = node.func
-            if isinstance(f, ast.Attribute) and f.attr == "pallas_call":
-                raw.append(node)
-            if isinstance(f, ast.Name) and f.id == "_kernel_call":
-                named.append(node.args[0])
-    # the one raw call is the helper's, and it passes the name on
-    (call,) = raw
+            if getattr(f, "attr", getattr(f, "id", None)) == func_name:
+                found.append(node)
+    return found
+
+
+def test_every_pallas_call_carries_a_name_of_the_table():
+    from paddle_tpu.kernels import common, delta_rule
+
+    # the package's one raw call is the shared helper's, and it passes the
+    # name on; neither kernel file makes one of its own
+    (call,) = _calls(common, "pallas_call")
     assert any(kw.arg == "name" and isinstance(kw.value, ast.Name)
                and kw.value.id == "name" for kw in call.keywords)
+    assert not _calls(attention, "pallas_call")
+    assert not _calls(delta_rule, "pallas_call")
+    (lifted,) = _calls(attention, "named_pallas_call")  # in _kernel_call
+    assert isinstance(lifted.args[0], ast.Name)
+    named = [c.args[0] for c in _calls(attention, "_kernel_call")]
     assert all(isinstance(a, ast.Constant) for a in named)
     names = [a.value for a in named]
     assert len(names) == 14 and len(set(names)) == len(names)
@@ -216,6 +227,13 @@ def test_every_pallas_call_carries_a_name_of_the_table():
     for tier in attention.KERNEL_TIERS:     # a name for every counted tier
         stem = "attn_" + tier.replace("_bwd", "")
         assert any(n.startswith(stem) for n in names), tier
+    # the delta rule's kernels: named, and never under the attention
+    # metrics' prefix
+    gdn = [c.args[0] for c in _calls(delta_rule, "named_pallas_call")]
+    assert all(isinstance(a, ast.Constant) for a in gdn)
+    assert sorted(a.value for a in gdn) == sorted(delta_rule.KERNEL_NAMES)
+    assert all(n.startswith("gdn_") for n in delta_rule.KERNEL_NAMES)
+    assert not set(delta_rule.KERNEL_NAMES) & set(attention.KERNEL_NAMES)
 
 
 def test_lowered_text_names_the_program_op_of_every_operation(trained):
