@@ -468,7 +468,7 @@ def disk_hit_count():
 # -- JAX's own persistent compilation cache -----------------------------------
 def use_jax_cache():
     """Give JAX's persistent compilation cache a home, for entry-point
-    scripts (``chip_smoke.py``, ``bench.py``) — never called on import,
+    scripts (``chip_smoke.py``, ``benchmark/run.py``) — never called on import,
     so a test run writes no executables into the checkout.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it

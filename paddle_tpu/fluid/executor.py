@@ -284,6 +284,179 @@ def _split_batched_feed(feed, block, iters, batch_factor=1):
     return stacked, invariant
 
 
+_HOST_OPS = frozenset(("listen_and_serv", "fl_listen_and_serv",
+                       "host_embedding_init", "py_reader_dequeue", "save"))
+
+
+def _host_plan(program):
+    """What a Program asks of the host around its compiled step, from ONE
+    walk of its ops - the one place the executor knows the op types of
+    the layers above it: ``server`` (a pserver program does not compile:
+    its op is a host serving loop, like the reference's
+    listen_and_serv_op.cc RunSyncLoop), ``tables`` (host embedding
+    tables whose residency resets with this run), ``reader_ids`` (the
+    py_reader queues that feed it) and ``saves`` (name, path)."""
+    block = program.global_block()
+    plan = types.SimpleNamespace(server=None, tables=[], reader_ids=[],
+                                 saves=[])
+    for blk in program.blocks:
+        for op in blk.ops:
+            if op.type not in _HOST_OPS:
+                continue
+            if op.type == "save":
+                # save ops write once per run, after commit - which is
+                # only truthful at the top level. Inside control flow (a
+                # cond branch that may not run, a While body that may run
+                # 0 or N times) a host file write cannot follow the
+                # predicate from within one compiled step, so refuse
+                # rather than silently firing.
+                if blk is not block:
+                    raise RuntimeError(
+                        "a save op inside a control-flow sub-block is not "
+                        "supported: the compiled step cannot conditionally "
+                        "write host files — move the save op to the global "
+                        "block or checkpoint from the host loop "
+                        "(fluid.io.save)")
+                plan.saves.append((op.input("X")[0], op.attr("file_path")))
+            elif blk is not block:
+                continue
+            elif op.type == "host_embedding_init":
+                # host-side residency reset, synchronous with the run -
+                # the in-program op is a no-op (an io_callback there fires
+                # on a runtime thread after the async dispatch returns,
+                # racing the next step's residency prepare and wiping the
+                # LUT it just admitted)
+                plan.tables.append(op.attr("table_name"))
+            elif op.type == "py_reader_dequeue":
+                plan.reader_ids.append(int(op.attr("reader_id")))
+            elif plan.server is None:
+                plan.server = op
+    return plan
+
+
+def _serve(op, scope):
+    """Run a server program's host loop until it ends."""
+    if op.type == "listen_and_serv":
+        from .transpiler.distribute_transpiler import build_server_from_attrs
+
+        build_server_from_attrs(op.attrs).serve_forever()
+        return
+    # federated variant (reference fl_listen_and_serv_op): initial params
+    # come from this scope's vars by name
+    from ..distributed import fl_server as _fl
+
+    params = {}
+    for name in op.attr("param_names"):
+        val = scope.find_var(name)
+        if val is None:
+            raise RuntimeError(
+                "fl_listen_and_serv param %r not in scope — "
+                "run the startup program first" % name)
+        params[name] = np.asarray(val)
+    configured = op.attr("endpoint")
+    host, port = configured.rsplit(":", 1)
+    srv = _fl.FLServer(params, op.attr("n_trainers"),
+                       host=host, port=int(port))
+    # register under BOTH the endpoint the program named and the socket's
+    # resolved one (getsockname may differ, e.g. localhost vs 127.0.0.1)
+    for key in {configured, srv.endpoint}:
+        _fl.SERVING[key] = srv
+    try:
+        srv.serve_forever()
+    finally:
+        srv.stop()
+        for key in {configured, srv.endpoint}:
+            _fl.SERVING.pop(key, None)
+
+
+def _drain_window(readers, iters):
+    """Pull exactly ``iters`` batches from every reader and stack them
+    ``[k, ...]``: ``("ok", feed)``, or ``("eof", n_pulled, partial)``
+    when a queue ran out first (``partial``: batches were pulled and are
+    lost, so size the pass to a multiple of k to lose nothing)."""
+    pulled = {r: [] for r in readers}
+    for i in range(iters):
+        step_vals = [(r, r._next()) for r in readers]
+        if any(v is None for _, v in step_vals):
+            return ("eof", i,
+                    bool(i) or any(v is not None for _, v in step_vals))
+        for r, vals in step_vals:
+            pulled[r].append(vals)
+    return ("ok", {name: np.stack([vals[j] for vals in items])
+                   for r, items in pulled.items()
+                   for j, name in enumerate(r.names)})
+
+
+def _trace_step(block, fetch_names, strategy=None, fixed_state=False):
+    """The block as the pure function ``step(state, feed_vals, rng_key) ->
+    (fetches, new_state, new_key)`` every compiled program is made of."""
+    mesh = strategy.mesh if strategy is not None else None
+
+    def step(state, feed_vals, rng_key):
+        env = {}
+        env.update(state)
+        env.update(feed_vals)
+        ctx = LowerCtx(block, env, _rng.wrap_key_data(rng_key), mesh=mesh)
+        if strategy is not None:
+            strategy._on_trace_begin(ctx)
+        lower_block(ctx, block)
+        fetches = [ctx.get(n) for n in fetch_names]
+        # Return ALL state (unchanged entries pass through as aliased
+        # buffers under donation — returning them keeps the donated
+        # buffers alive for the scope), plus vars that became
+        # persistable during this program (startup init).
+        new_state = {n: env[n] for n in state if n in env}
+        grown = {n: env[n] for n in ctx.written
+                 if n in env and n not in new_state}
+        grown.update((name, env[name]) for name, var in block.vars.items()
+                     if var.persistable and name in env
+                     and name not in state)
+        if grown and fixed_state:
+            # a scan carry has a FIXED structure: a program that creates
+            # new persistables mid-step (startup-style init) cannot be
+            # step-batched — fail with the remedy, not a tracer error
+            raise RuntimeError(
+                "iters>1 needs loop-invariant state, but this "
+                "program creates new persistable vars %s during "
+                "the step — run the startup program (iters=1) "
+                "first so they exist in the scope" % (sorted(grown),))
+        new_state.update(grown)
+        return fetches, new_state, _rng.key_data(ctx.rng_key)
+
+    # what a trace calls the program: XLA's module (``jit_train_step``)
+    # and the ``jit(train_step)`` that leads every operation's op_name
+    if any(op.type == "autodiff" for op in block.ops):
+        step.__name__ = "train_step"
+    return step
+
+
+def _scan_steps(step, iters):
+    """``step`` wrapped in a ``lax.scan`` over the iteration axis: stacked
+    feeds are sliced per step, invariant feeds close over the loop and
+    ``(state, rng)`` is the carry."""
+    import jax
+
+    def batched_step(state, stacked_feeds, invariant_feeds, rng_key):
+        def body(carry, feed_i):
+            st, rk = carry
+            fv = dict(invariant_feeds)
+            fv.update(feed_i)
+            fetches, new_st, new_rk = step(st, fv, rk)
+            return (new_st, new_rk), fetches
+
+        (final_state, final_rng), traj = jax.lax.scan(
+            body, (state, rng_key), stacked_feeds, length=iters)
+        return traj, final_state, final_rng
+
+    return batched_step
+
+
+def _state_names(program, scope):
+    """The persistable state visible to ``program`` in ``scope``."""
+    return sorted(v.name for v in program.list_vars()
+                  if v.persistable and scope.has_var(v.name))
+
+
 def _local_view(x):
     """Host-readable numpy view of a possibly multi-process array: a
     non-fully-addressable array (replicated or sharded across processes)
@@ -458,25 +631,16 @@ class _WindowPrefetch:
 
         try:
             with _M_PREFETCH_INFLIGHT.track():
-                pulled = {r: [] for r in self.readers}
-                for i in range(self.iters):
-                    step_vals = [(r, r._next()) for r in self.readers]
-                    if any(v is None for _, v in step_vals):
-                        partial = bool(i) or any(v is not None
-                                                 for _, v in step_vals)
-                        self._result = ("eof", i, partial)
-                        return
-                    for r, vals in step_vals:
-                        pulled[r].append(vals)
-                feed = {}
-                for r, items in pulled.items():
-                    for j, name in enumerate(r.names):
-                        arr = np.stack([vals[j] for vals in items])
+                result = _drain_window(self.readers, self.iters)
+                if result[0] == "ok":
+                    feed = {}
+                    for name, arr in result[1].items():
                         s = self._sharding_fn(name, arr) \
                             if self._sharding_fn is not None else None
                         feed[name] = jax.device_put(arr, s) \
                             if s is not None else jax.device_put(arr)
-                self._result = ("ok", feed)
+                    result = ("ok", feed)
+                self._result = result
         except BaseException as e:  # background thread: stored and re-raised on the consuming run
             self._result = ("error", e)
 
@@ -648,7 +812,7 @@ class Executor:
         prefetch=False,
         checkpoint=None,
     ):
-        """``iters=1`` (default): one feed/fetch step, the legacy path.
+        """``iters=1`` (default): one feed/fetch step.
 
         ``iters=k`` (k >= 2): step-batched execution — the program's step
         function is compiled ONCE and ``k`` steps run inside a single
@@ -660,8 +824,7 @@ class Executor:
         plain per-step shape (loop-invariant, reused every iteration);
         py_reader-fed programs instead drain exactly ``k`` batches up
         front. Each fetch returns the per-iteration trajectory, stacked
-        ``[k, ...]``. See ``_prepare_batched`` and README "Step-batched
-        execution".
+        ``[k, ...]``.
 
         ``fetch_mode="async"``: return ``FetchHandle`` objects instead
         of numpy — the step is dispatched but run() never blocks on a
@@ -703,28 +866,26 @@ class Executor:
         _prof.begin_run()
         t_run0 = time.perf_counter()
         with _prof.RecordEvent(_prof.SPAN_PREPARE):
-            if iters > 1:
-                prep = self._prepare_batched(program, feed, fetch_list,
-                                             scope, iters, prefetch,
-                                             checkpoint)
-            else:
-                prep = self._prepare(program, feed, fetch_list, scope,
-                                     checkpoint)
+            prep = self._prepare(program, feed, fetch_list, scope, iters,
+                                 prefetch, checkpoint)
         if prep is None:    # a server program: its serving loop has ended
             return []
         return self._run_prepared(prep, t_run0, return_numpy, iters,
                                   fetch_mode, prefetch, checkpoint)
 
-    def _prepare(self, program, feed, fetch_list, scope, checkpoint):
-        """``executor.prepare`` of a single step: everything from entry to
-        the compile-cache lookup. Returns what ``_run_prepared`` takes, or
-        None after a server program's loop."""
+    def _prepare(self, program, feed, fetch_list, scope, iters, prefetch,
+                 checkpoint):
+        """``executor.prepare``: everything from entry to the
+        compile-cache lookup, for one step (``iters == 1``) or a window of
+        k steps that one compiled executable drives device-side, its
+        feeds drained or stacked ``[k, ...]`` here. Returns what
+        ``_run_prepared`` takes, or None after a server program's loop."""
         import jax
 
         scope = scope or global_scope()
         feed = dict(feed or {})
-        fetch_list = list(fetch_list or [])
-        fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
+        fetch_names = [v.name if isinstance(v, Variable) else str(v)
+                       for v in fetch_list or []]
 
         # CompiledProgram carries sharding strategy; plain Program runs single-device.
         from . import compiler
@@ -735,120 +896,83 @@ class Executor:
             program = strategy._program
         if program is None:
             program = framework.default_main_program()
-
         block = program.global_block()
 
         # graceful preemption (distributed.preemption): launched workers
         # have PADDLE_PREEMPT_DRAIN=1, so the first run() installs the
         # SIGTERM drain handlers; a signal that already arrived drains
-        # HERE — before the step — through the active CheckpointManager
-        # and exits 0 (drain_exit does not return).
+        # HERE — before the step or window, never inside one (the k-step
+        # device loop is the commit unit) — through the active
+        # CheckpointManager and exits 0 (drain_exit does not return).
         from ..distributed import preemption as _preemption
 
         _preemption.maybe_install_from_env()
         _preemption.check_drain(checkpoint[0] if checkpoint else None,
                                 program, scope)
 
-        # pserver programs don't compile — their listen_and_serv op is a
-        # host serving loop; running one blocks, like the reference's
-        # pserver Executor (listen_and_serv_op.cc RunSyncLoop). The same
-        # scan collects py_reader queues so EOF can surface after the step.
-        py_readers = []
-        # save ops write once per run, after commit — which is only
-        # truthful at the top level. Inside control flow (a cond branch
-        # that may not run, a While body that may run 0 or N times) a
-        # host file write cannot follow the predicate from within one
-        # compiled step, so refuse rather than silently firing.
-        save_ops = [(op.input("X")[0], op.attr("file_path"))
-                    for op in block.ops if op.type == "save"]
-        for blk in program.blocks:
-            if blk is not block and any(op.type == "save"
-                                        for op in blk.ops):
+        plan = _host_plan(program)
+        if plan.server is not None:
+            if iters > 1:
                 raise RuntimeError(
-                    "a save op inside a control-flow sub-block is not "
-                    "supported: the compiled step cannot conditionally "
-                    "write host files — move the save op to the global "
-                    "block or checkpoint from the host loop "
-                    "(fluid.io.save)")
-        for op in block.ops:
-            if op.type == "listen_and_serv":
-                from .transpiler.distribute_transpiler import (
-                    build_server_from_attrs)
+                    "iters>1 cannot drive a server program (%s op): the "
+                    "serving loop runs on the host — call exe.run "
+                    "without iters" % plan.server.type)
+            _serve(plan.server, scope)
+            return None
+        if plan.tables:
+            from .. import embedding as _embedding
 
-                build_server_from_attrs(op.attrs).serve_forever()
-                return None
-            if op.type == "fl_listen_and_serv":
-                # federated variant (reference fl_listen_and_serv_op):
-                # initial params come from this scope's vars by name
-                from ..distributed.fl_server import FLServer
+            for name in plan.tables:
+                _embedding.get_host_table(name).reset_residency()
+        py_readers = []
+        if plan.reader_ids:
+            from .layers.py_reader import _READERS
 
-                params = {}
-                for name in op.attr("param_names"):
-                    val = scope.find_var(name)
-                    if val is None:
-                        raise RuntimeError(
-                            "fl_listen_and_serv param %r not in scope — "
-                            "run the startup program first" % name)
-                    params[name] = np.asarray(val)
-                from ..distributed import fl_server as _fl
+            py_readers = [_READERS.get(rid) for rid in plan.reader_ids]
+            if None in py_readers:
+                raise RuntimeError(
+                    "the py_reader feeding this program was "
+                    "garbage-collected — keep the object returned "
+                    "by layers.py_reader() alive and start() it")
+        if prefetch and not py_readers:
+            raise ValueError(
+                "prefetch=True needs a py_reader-fed program — explicit "
+                "feeds are the caller's to stage ahead of time "
+                "(DataLoader use_double_buffer / fluid.reader.stage_feed)")
 
-                configured = op.attr("endpoint")
-                host, port = configured.rsplit(":", 1)
-                srv = FLServer(params, op.attr("n_trainers"),
-                               host=host, port=int(port))
-                # register under BOTH the endpoint the program named and
-                # the socket's resolved one (getsockname may differ,
-                # e.g. localhost vs 127.0.0.1)
-                for key in {configured, srv.endpoint}:
-                    _fl.SERVING[key] = srv
-                try:
-                    srv.serve_forever()
-                finally:
-                    srv.stop()
-                    for key in {configured, srv.endpoint}:
-                        _fl.SERVING.pop(key, None)
-                return None
-            if op.type == "host_embedding_init":
-                # host-side residency reset, synchronous with this run —
-                # the in-program op is a no-op (an io_callback there fires
-                # on a runtime thread after the async dispatch returns,
-                # racing the next step's residency prepare and wiping the
-                # LUT it just admitted)
-                from .. import embedding as _embedding
+        # a window prefetched on these readers is this run's to consume
+        # only if it was drained for the same readers at the same iters
+        rkey = (tuple(id(r) for r in py_readers), iters)
+        for k, pf in self._window_prefetch.items():
+            if k == rkey or not set(k[0]) & set(rkey[0]):
+                continue
+            if iters == 1:
+                raise RuntimeError(
+                    "a prefetched iters=%d window is pending on "
+                    "this program's py_reader(s) — a single-step "
+                    "run would race it for batches. Finish the "
+                    "batched loop (run with iters=%d until EOF) or "
+                    "exe.close() first." % (pf.iters, pf.iters))
+            raise RuntimeError(
+                "a prefetched window (iters=%d) is pending on "
+                "py_reader(s) this run (iters=%d) also reads — the "
+                "prefetched batches would be mis-windowed. Keep a "
+                "prefetching batched loop's iters uniform, or "
+                "exe.close() between loops." % (pf.iters, iters))
+        pending = self._window_prefetch.pop(rkey, None)
 
-                _embedding.get_host_table(
-                    op.attr("table_name")).reset_residency()
-            if op.type == "py_reader_dequeue":
-                from .layers.py_reader import _READERS
-
-                r = _READERS.get(int(op.attr("reader_id")))
-                if r is None:
-                    raise RuntimeError(
-                        "the py_reader feeding this program was "
-                        "garbage-collected — keep the object returned "
-                        "by layers.py_reader() alive and start() it")
-                py_readers.append(r)
-        if py_readers:
-            rids = {id(r) for r in py_readers}
-            for pf in self._window_prefetch.values():
-                if set(pf.key[0]) & rids:
-                    raise RuntimeError(
-                        "a prefetched iters=%d window is pending on "
-                        "this program's py_reader(s) — a single-step "
-                        "run would race it for batches. Finish the "
-                        "batched loop (run with iters=%d until EOF) or "
-                        "exe.close() first." % (pf.iters, pf.iters))
-        if py_readers:
-            # pull every reader's batch on the host BEFORE dispatch and
-            # ride the normal feed path (works under any sharding
-            # strategy); any empty queue raises EOF with no step run —
-            # nothing to discard, donation stays on. All batches are
-            # pulled before deciding, so uneven readers lose at most
-            # the final ragged step (logged), exactly one epoch ends.
+        # pull every reader's batches on the host BEFORE dispatch and ride
+        # the normal feed path (works under any sharding strategy); any
+        # empty queue raises EOF with no step run — nothing to discard,
+        # donation stays on. All batches of a step are pulled before
+        # deciding, so uneven readers lose at most the final ragged step
+        # (logged), exactly one epoch ends.
+        eof = None
+        if py_readers and iters == 1:
             pulled = [(r, r._next()) for r in py_readers]
             if any(v is None for _, v in pulled):
-                from . import core as _core
-
+                eof = ("py_reader queue exhausted — reader.reset() and "
+                       "re-start() for the next pass")
                 dropped = [r.names[0] for r, v in pulled if v is not None]
                 if dropped:
                     import logging
@@ -857,22 +981,56 @@ class Executor:
                         "py_reader EOF: discarding the already-pulled "
                         "batch of %s (readers have unequal lengths)",
                         dropped)
-                for r in py_readers:
-                    r.reset()
-                raise _core.EOFException(
-                    "py_reader queue exhausted — reader.reset() and "
-                    "re-start() for the next pass")
-            for r, vals in pulled:
-                feed.update(zip(r.names, vals))
+            else:
+                for r, vals in pulled:
+                    feed.update(zip(r.names, vals))
+        elif py_readers:
+            if pending is not None:
+                # overlap hit: the window was drained+stacked+staged in
+                # the background while the previous window computed
+                status = pending.consume()
+                if status[0] == "error":
+                    raise status[1]
+            else:
+                if prefetch:
+                    # first window of a pass (or the pass just restarted
+                    # after EOF): nothing staged yet, drain inline
+                    _M_OVERLAP_MISS.inc()
+                status = _drain_window(py_readers, iters)
+            if status[0] == "eof":
+                eof = ("py_reader queue exhausted before %d batches — "
+                       "reader.reset() and re-start() for the next pass"
+                       % iters)
+                if status[2]:
+                    import logging
 
-        # host-tier embedding tables: translate this batch's raw ids into
-        # resident-cache slots (admitting missing rows) and inject the
+                    logging.getLogger(__name__).warning(
+                        "py_reader EOF during a %sbatched run: "
+                        "discarding %d already-pulled batch(es) of a "
+                        "requested window of %d",
+                        "prefetched " if pending is not None else "",
+                        status[1], iters)
+            else:
+                if pending is not None:
+                    _M_OVERLAP_HIT.inc()
+                feed.update(status[1])
+        if eof is not None:
+            from . import core as _core
+
+            for r in py_readers:
+                r.reset()
+            raise _core.EOFException(eof)
+
+        # host-tier embedding tables: translate the raw ids of this batch
+        # (or of the whole [k, ...] window, in one residency transaction,
+        # so the scanned body only ever gathers resident slots) into
+        # resident-cache slots, admitting missing rows, and inject the
         # <table>@SLOTS feed — BEFORE normalization so the slots array is
         # part of the feed signature like any other input
         if getattr(program, "_embedding_bindings", None):
             from .. import embedding as _embedding
 
-            _embedding.prepare_feed(program, feed, scope)
+            _embedding.prepare_feed(program, feed, scope, iters=iters)
 
         # normalize feeds to declared dtype; device-resident jax Arrays pass
         # through untouched (the DataLoader/buffered-reader path pre-stages
@@ -881,6 +1039,11 @@ class Executor:
 
         for name in list(feed):
             if isinstance(feed[name], LoDTensor):
+                if iters > 1:
+                    raise ValueError(
+                        "iters>1 does not take LoDTensor feeds — feed "
+                        "dense arrays (plus explicit length arrays) "
+                        "stacked [k, ...], or loop exe.run from the host")
                 # decompose: data under the name, int32 lengths under @LOD
                 # (the bounded-LoD device encoding, see fluid/lod.py)
                 feed[lod_name(name)] = feed[name].lengths()
@@ -893,36 +1056,49 @@ class Executor:
                 arr = arr.astype(var.dtype)
             feed[name] = arr
 
-        # persistable state visible to this program
-        state_names = sorted(
-            v.name
-            for v in program.list_vars()
-            if v.persistable and scope.has_var(v.name)
-        )
+        feeds = (feed,)
+        if iters > 1:
+            batch_factor = 1
+            if strategy is not None and \
+                    getattr(strategy, "_mode", "") == "pipeline":
+                batch_factor = int(strategy._num_microbatches)
+                mesh = strategy.mesh
+                for ax in ("host", "data"):
+                    if mesh is not None and ax in mesh.shape:
+                        batch_factor *= int(mesh.shape[ax])
+            feeds = _split_batched_feed(feed, block, iters, batch_factor)
+
+        state_names = _state_names(program, scope)
+        feed_sig = _feed_signature(feed, block)
 
         from . import flags as _flags
 
         # program._uid (a monotonic token) rather than id(program): a GC'd
-        # Program's id can be reused, which would serve a stale compiled step.
-        # The anomaly-policy bit joins the key because it flips buffer
-        # donation (skip_step/rollback must keep pre-step buffers alive).
+        # Program's id can be reused, which would serve a stale compiled
+        # step. A k-step executable is another program than a single
+        # step, so iters is a member. The anomaly-policy bit joins the key
+        # because it flips buffer donation (skip_step/rollback must keep
+        # pre-step buffers alive).
         key = (
             program._uid,
             program._mutation,
-            _feed_signature(feed, block),
+            feed_sig,
             tuple(fetch_names),
             tuple(state_names),
             strategy._uid if strategy is not None else 0,
+            iters,
             _flags.anomaly_policy() != "raise",
         )
 
         state, rng = self._state_and_rng(program, scope, state_names)
         return types.SimpleNamespace(
             program=program, strategy=strategy, scope=scope,
-            fetch_names=fetch_names, save_ops=save_ops, key=key,
-            feed_names=list(feed), feeds=(feed,), state=state, rng=rng,
-            build=lambda: self._build(program, block, feed, fetch_names,
-                                      state_names, strategy))
+            fetch_names=fetch_names, save_ops=plan.saves, key=key,
+            feed_names=list(feed), feeds=feeds, state=state, rng=rng,
+            py_readers=py_readers,
+            build=lambda: self._build(program, block, feeds, feed_sig,
+                                      fetch_names, state_names, strategy,
+                                      iters))
 
     @staticmethod
     def _state_and_rng(program, scope, state_names):
@@ -940,7 +1116,7 @@ class Executor:
         """The rest of a run, single step or ``iters=k`` window alike:
         the compile-cache lookup, ``executor.compile`` or
         ``executor.call``, ``executor.commit``, ``executor.fetch``. ``p``
-        is what ``_prepare`` / ``_prepare_batched`` returned."""
+        is what ``_prepare`` returned."""
         import jax
 
         from . import flags as _flags
@@ -1014,8 +1190,8 @@ class Executor:
             if p.strategy is not None and p.strategy.mesh is not None:
                 sharding_fn = (lambda name, v:
                                p.strategy.feed_sharding(v, batch_dim=1))
-            self._window_prefetch[p.rkey] = _WindowPrefetch(
-                p.py_readers, iters, sharding_fn)
+            pf = _WindowPrefetch(p.py_readers, iters, sharding_fn)
+            self._window_prefetch[pf.key] = pf
         with _prof.RecordEvent(_prof.SPAN_COMMIT):
             # nan/inf anomaly scan BEFORE commit (reference
             # FLAGS_check_nan_inf / nan_inf_utils, grown into a policy): a
@@ -1107,373 +1283,55 @@ class Executor:
         return list(fetches)
 
     # ------------------------------------------------------------------
-    def _build(self, program, block, feed, fetch_names, state_names, strategy):
+    def _build(self, program, block, feeds, feed_sig, fetch_names,
+               state_names, strategy, iters):
+        """Compile the block's step: itself at ``iters == 1``, else a
+        scan of it. The initial state is donated, so a step or a whole
+        k-step window is allocation-free on device."""
         import jax
-
-        mesh = strategy.mesh if strategy is not None else None
-
-        def step(state, feed_vals, rng_key):
-            env = {}
-            env.update(state)
-            env.update(feed_vals)
-            ctx = LowerCtx(block, env, _rng.wrap_key_data(rng_key),
-                           mesh=mesh)
-            if strategy is not None:
-                strategy._on_trace_begin(ctx)
-            lower_block(ctx, block)
-            fetches = [ctx.get(n) for n in fetch_names]
-            # Return ALL state (unchanged entries pass through as aliased
-            # buffers under donation — returning them keeps the donated
-            # buffers alive for the scope), plus vars that became
-            # persistable during this program (startup init).
-            new_state = {n: env[n] for n in state if n in env}
-            new_state.update({n: env[n] for n in ctx.written if n in env})
-            for name, var in block.vars.items():
-                if var.persistable and name in env and name not in state:
-                    new_state[name] = env[name]
-            return fetches, new_state, _rng.key_data(ctx.rng_key)
-
-        # what a trace calls the program: XLA's module (``jit_train_step``)
-        # and the ``jit(train_step)`` that leads every operation's op_name
-        if any(op.type == "autodiff" for op in block.ops):
-            step.__name__ = "train_step"
 
         from . import flags as _flags
 
+        fn = _trace_step(block, fetch_names, strategy,
+                         fixed_state=iters > 1)
+        label = "step#%s" % ",".join(fetch_names[:3])
+        if iters > 1:
+            fn, label = _scan_steps(fn, iters), "batched#k=%d" % iters
+
+        # skip_step/rollback re-commit the PRE-step scope arrays after a
+        # discarded step or window; donation would have handed those
+        # buffers to XLA (a no-op on CPU but fatal on TPU), so those
+        # policies compile undonated, as inference-path executors do. The
+        # policy sits in the compile-cache key, so flipping
+        # FLAGS_anomaly_policy recompiles rather than reusing a
+        # mismatched executable; the bit joins the disk key too.
         donate = ((0,) if self._donate_state
                   and _flags.anomaly_policy() == "raise" else ())
         cache_key = None
         if _compile_cache.active(self._cache_read_dirs):
             cache_key = _compile_cache.step_key(
-                program, _feed_signature(feed, block), fetch_names,
-                state_names, strategy, 1, bool(donate))
+                program, feed_sig, fetch_names, state_names, strategy,
+                iters, bool(donate))
 
         # Startup-style programs create new persistables -> output structure
         # depends on trace; jit handles that fine since structure is fixed
         # per cache entry.
-        if strategy is not None and mesh is not None:
-            return _CompiledStep(
-                strategy.wrap_step(step, program, block, feed, fetch_names,
-                                   state_names, cache_key=cache_key,
-                                   cache_read_dirs=self._cache_read_dirs),
-                state_names,
-                fetch_names,
-            )
-
-        # skip_step/rollback re-commit the PRE-step scope arrays after a
-        # discarded step; donation would have handed those buffers to XLA
-        # (a no-op on CPU but fatal on TPU), so those policies compile
-        # undonated (donate computed above joins the disk key). The
-        # policy sits in the compile-cache key, so flipping
-        # FLAGS_anomaly_policy recompiles rather than reusing a
-        # mismatched executable.
-        jfn = _compile_cache.wrap_jit(
-            jax.jit(step, donate_argnums=donate), cache_key,
-            read_dirs=self._cache_read_dirs,
-            label="step#%s" % ",".join(fetch_names[:3]))
-        return _CompiledStep(jfn, state_names, fetch_names)
-
-    # -- step-batched execution (iters=k) ------------------------------
-    def _prepare_batched(self, program, feed, fetch_list, scope, iters,
-                         prefetch, checkpoint):
-        """``executor.prepare`` of ``Executor.run(..., iters=k)`` for
-        k >= 2: one compiled executable drives k steps device-side, so
-        the feeds are drained or stacked ``[k, ...]`` here.
-        ``prefetch=True`` overlaps the NEXT window's py_reader
-        drain+stack+stage with this window's device compute
-        (``_WindowPrefetch``); with ``fetch_mode="async"`` a prefetching
-        loop issues no host sync at all between windows."""
-        import jax
-
-        scope = scope or global_scope()
-        feed = dict(feed or {})
-        fetch_list = list(fetch_list or [])
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
-
-        from . import compiler
-
-        strategy = None
-        if isinstance(program, compiler.CompiledProgram):
-            strategy = program
-            program = strategy._program
-        if program is None:
-            program = framework.default_main_program()
-        block = program.global_block()
-
-        # same drain hook as the single-step path: check between
-        # windows, never inside one (the k-step device loop is the
-        # commit unit)
-        from ..distributed import preemption as _preemption
-
-        _preemption.maybe_install_from_env()
-        _preemption.check_drain(checkpoint[0] if checkpoint else None,
-                                program, scope)
-
-        py_readers = []
-        for op in block.ops:
-            if op.type in ("listen_and_serv", "fl_listen_and_serv"):
-                raise RuntimeError(
-                    "iters>1 cannot drive a server program (%s op): the "
-                    "serving loop runs on the host — call exe.run "
-                    "without iters" % op.type)
-            if op.type == "host_embedding_init":
-                from .. import embedding as _embedding
-
-                _embedding.get_host_table(
-                    op.attr("table_name")).reset_residency()
-            if op.type == "py_reader_dequeue":
-                from .layers.py_reader import _READERS
-
-                r = _READERS.get(int(op.attr("reader_id")))
-                if r is None:
-                    raise RuntimeError(
-                        "the py_reader feeding this program was "
-                        "garbage-collected — keep the object returned "
-                        "by layers.py_reader() alive and start() it")
-                py_readers.append(r)
-        save_ops = [(op.input("X")[0], op.attr("file_path"))
-                    for op in block.ops if op.type == "save"]
-        for blk in program.blocks:
-            if blk is not block and any(op.type == "save"
-                                        for op in blk.ops):
-                raise RuntimeError(
-                    "a save op inside a control-flow sub-block is not "
-                    "supported: the compiled step cannot conditionally "
-                    "write host files — move the save op to the global "
-                    "block or checkpoint from the host loop "
-                    "(fluid.io.save)")
-
-        if prefetch and not py_readers:
-            raise ValueError(
-                "prefetch=True needs a py_reader-fed program — explicit "
-                "feeds are the caller's to stage ahead of time "
-                "(DataLoader use_double_buffer / fluid.reader.stage_feed)")
-
-        rkey = (tuple(id(r) for r in py_readers), iters)
-        pending = self._window_prefetch.get(rkey) if py_readers else None
-        for k in list(self._window_prefetch):
-            if k != rkey and set(k[0]) & set(rkey[0]):
-                pf = self._window_prefetch[k]
-                raise RuntimeError(
-                    "a prefetched window (iters=%d) is pending on "
-                    "py_reader(s) this run (iters=%d) also reads — the "
-                    "prefetched batches would be mis-windowed. Keep a "
-                    "prefetching batched loop's iters uniform, or "
-                    "exe.close() between loops." % (pf.iters, iters))
-        if pending is not None:
-            # overlap hit: the window was drained+stacked+staged in the
-            # background while the previous window computed
-            del self._window_prefetch[rkey]
-            status = pending.consume()
-            if status[0] == "error":
-                raise status[1]
-            if status[0] == "eof":
-                # EOF-before-step, exactly like the inline drain: reset,
-                # raise, no step ran, partial pulls discarded (logged)
-                from . import core as _core
-
-                if status[2]:
-                    import logging
-
-                    logging.getLogger(__name__).warning(
-                        "py_reader EOF during a prefetched batched run: "
-                        "discarding %d already-pulled batch(es) of a "
-                        "requested window of %d", status[1], iters)
-                for r in py_readers:
-                    r.reset()
-                raise _core.EOFException(
-                    "py_reader queue exhausted before %d batches — "
-                    "reader.reset() and re-start() for the next pass"
-                    % iters)
-            _M_OVERLAP_HIT.inc()
-            feed.update(status[1])
-        elif py_readers:
-            if prefetch:
-                # first window of a pass (or the pass just restarted
-                # after EOF): nothing staged yet, drain inline
-                _M_OVERLAP_MISS.inc()
-            # drain exactly `iters` batches per reader up front and stack
-            # them [k, ...]; EOF before k batches ends the pass like the
-            # single-step path (readers reset, EOFException, no step ran —
-            # already-pulled batches of this window are discarded, so size
-            # the pass to a multiple of k to lose nothing)
-            pulled = {r: [] for r in py_readers}
-            for i in range(iters):
-                step_vals = [(r, r._next()) for r in py_readers]
-                if any(v is None for _, v in step_vals):
-                    from . import core as _core
-
-                    if i or any(v is not None for _, v in step_vals):
-                        import logging
-
-                        logging.getLogger(__name__).warning(
-                            "py_reader EOF during a batched run: "
-                            "discarding %d already-pulled batch(es) of a "
-                            "requested window of %d", i, iters)
-                    for r in py_readers:
-                        r.reset()
-                    raise _core.EOFException(
-                        "py_reader queue exhausted before %d batches — "
-                        "reader.reset() and re-start() for the next pass"
-                        % iters)
-                for r, vals in step_vals:
-                    pulled[r].append(vals)
-            for r, items in pulled.items():
-                for j, name in enumerate(r.names):
-                    feed[name] = np.stack([vals[j] for vals in items])
-
-        # host-tier embeddings: one residency transaction covers the whole
-        # [k, ...] window — ids across all k steps are admitted together so
-        # the scanned body only ever gathers resident slots
-        if getattr(program, "_embedding_bindings", None):
-            from .. import embedding as _embedding
-
-            _embedding.prepare_feed(program, feed, scope, iters=iters)
-
-        from .lod import LoDTensor
-
-        for name in list(feed):
-            if isinstance(feed[name], LoDTensor):
-                raise ValueError(
-                    "iters>1 does not take LoDTensor feeds — feed dense "
-                    "arrays (plus explicit length arrays) stacked "
-                    "[k, ...], or loop exe.run from the host")
-            if isinstance(feed[name], jax.Array):
-                continue
-            var = block._find_var_recursive(name)
-            arr = np.asarray(feed[name])
-            if var is not None and arr.dtype != var.dtype:
-                arr = arr.astype(var.dtype)
-            feed[name] = arr
-
-        batch_factor = 1
-        if strategy is not None and \
-                getattr(strategy, "_mode", "") == "pipeline":
-            batch_factor = int(strategy._num_microbatches)
-            mesh = strategy.mesh
-            for ax in ("host", "data"):
-                if mesh is not None and ax in mesh.shape:
-                    batch_factor *= int(mesh.shape[ax])
-        stacked, invariant = _split_batched_feed(feed, block, iters,
-                                                 batch_factor)
-
-        state_names = sorted(
-            v.name
-            for v in program.list_vars()
-            if v.persistable and scope.has_var(v.name)
-        )
-
-        from . import flags as _flags
-
-        # iters joins the key: a k-step executable is a different
-        # program than a single step (8-tuple — never collides with the
-        # single-step path's 7-tuple keys in the same cache); the
-        # anomaly-policy bit flips buffer donation, like the single-step
-        # path
-        key = (
-            program._uid,
-            program._mutation,
-            _feed_signature(feed, block),
-            tuple(fetch_names),
-            tuple(state_names),
-            strategy._uid if strategy is not None else 0,
-            iters,
-            _flags.anomaly_policy() != "raise",
-        )
-
-        state, rng = self._state_and_rng(program, scope, state_names)
-        return types.SimpleNamespace(
-            program=program, strategy=strategy, scope=scope,
-            fetch_names=fetch_names, save_ops=save_ops, key=key,
-            feed_names=list(feed), feeds=(stacked, invariant), state=state,
-            rng=rng, py_readers=py_readers, rkey=rkey,
-            build=lambda: self._build_batched(
-                program, block, stacked, invariant, fetch_names,
-                state_names, strategy, iters))
-
-    def _build_batched(self, program, block, stacked, invariant,
-                       fetch_names, state_names, strategy, iters):
-        """Trace the block once into ``step`` and wrap it in a
-        ``lax.scan`` over the iteration axis: stacked feeds are sliced
-        per step, invariant feeds close over the loop, ``(state, rng)``
-        is the carry, and the initial state is donated — the whole
-        k-step window is allocation-free on device."""
-        import jax
-
-        mesh = strategy.mesh if strategy is not None else None
-
-        def step(state, feed_vals, rng_key):
-            env = {}
-            env.update(state)
-            env.update(feed_vals)
-            ctx = LowerCtx(block, env, _rng.wrap_key_data(rng_key),
-                           mesh=mesh)
-            if strategy is not None:
-                strategy._on_trace_begin(ctx)
-            lower_block(ctx, block)
-            fetches = [ctx.get(n) for n in fetch_names]
-            new_state = {n: env[n] for n in state if n in env}
-            # a scan carry has a FIXED structure: a program that creates
-            # new persistables mid-step (startup-style init) cannot be
-            # step-batched — fail with the remedy, not a tracer error
-            grown = sorted(
-                set(n for n in ctx.written
-                    if n in env and n not in new_state) |
-                set(name for name, var in block.vars.items()
-                    if var.persistable and name in env
-                    and name not in state))
-            if grown:
-                raise RuntimeError(
-                    "iters>1 needs loop-invariant state, but this "
-                    "program creates new persistable vars %s during "
-                    "the step — run the startup program (iters=1) "
-                    "first so they exist in the scope" % (grown,))
-            return fetches, new_state, _rng.key_data(ctx.rng_key)
-
-        def batched_step(state, stacked_feeds, invariant_feeds, rng_key):
-            def body(carry, feed_i):
-                st, rk = carry
-                fv = dict(invariant_feeds)
-                fv.update(feed_i)
-                fetches, new_st, new_rk = step(st, fv, rk)
-                return (new_st, new_rk), fetches
-
-            (final_state, final_rng), traj = jax.lax.scan(
-                body, (state, rng_key), stacked_feeds, length=iters)
-            return traj, final_state, final_rng
-
-        from . import flags as _flags
-
-        donate = ((0,) if self._donate_state
-                  and _flags.anomaly_policy() == "raise" else ())
-        cache_key = None
-        if _compile_cache.active(self._cache_read_dirs):
-            merged = dict(stacked)
-            merged.update(invariant)
-            cache_key = _compile_cache.step_key(
-                program, _feed_signature(merged, block), fetch_names,
-                state_names, strategy, iters, bool(donate))
-
-        if strategy is not None and mesh is not None:
-            return _CompiledStep(
-                strategy.wrap_batched_step(batched_step, block, stacked,
-                                           invariant, fetch_names,
-                                           state_names,
-                                           cache_key=cache_key,
-                                           cache_read_dirs=self._cache_read_dirs,
-                                           program=program, iters=iters),
-                state_names,
-                fetch_names,
-            )
-
-        # see _build: donation off under skip_step/rollback (so a
-        # discarded window's pre-step state stays valid) and for
-        # inference-path executors; donate computed above joins the key
-        jfn = _compile_cache.wrap_jit(
-            jax.jit(batched_step, donate_argnums=donate), cache_key,
-            read_dirs=self._cache_read_dirs,
-            label="batched#k=%d" % iters)
+        if strategy is not None and strategy.mesh is not None:
+            if iters == 1:
+                jfn = strategy.wrap_step(
+                    fn, program, block, feeds[0], fetch_names,
+                    state_names, cache_key=cache_key,
+                    cache_read_dirs=self._cache_read_dirs)
+            else:
+                jfn = strategy.wrap_batched_step(
+                    fn, block, *feeds, fetch_names, state_names,
+                    cache_key=cache_key,
+                    cache_read_dirs=self._cache_read_dirs,
+                    program=program, iters=iters)
+        else:
+            jfn = _compile_cache.wrap_jit(
+                jax.jit(fn, donate_argnums=donate), cache_key,
+                read_dirs=self._cache_read_dirs, label=label)
         return _CompiledStep(jfn, state_names, fetch_names)
 
     # convenience ------------------------------------------------------
@@ -1545,28 +1403,10 @@ class Executor:
         """Exposes a Program block as a pure jittable function
         ``fn(state_dict, feed_dict, rng_key) -> (fetches, new_state, key)``
         plus example args. ``feed_specs``: {name: example ndarray}."""
-        import jax
-
         scope = scope or global_scope()
-        block = program.global_block()
         fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
-        state_names = sorted(
-            v.name
-            for v in program.list_vars()
-            if v.persistable and scope.has_var(v.name)
-        )
-
-        def step(state, feed_vals, rng_key):
-            env = {}
-            env.update(state)
-            env.update(feed_vals)
-            ctx = LowerCtx(block, env, _rng.wrap_key_data(rng_key))
-            lower_block(ctx, block)
-            fetches = [ctx.get(n) for n in fetch_names]
-            new_state = {n: env[n] for n in state if n in env}
-            new_state.update({n: env[n] for n in ctx.written if n in env})
-            return fetches, new_state, _rng.key_data(ctx.rng_key)
-
+        state_names = _state_names(program, scope)
+        step = _trace_step(program.global_block(), fetch_names)
         state = {n: scope.find_var(n) for n in state_names}
         rng = scope.find_var(RNG_STATE_VAR)
         if rng is None:

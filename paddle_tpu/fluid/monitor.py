@@ -11,7 +11,7 @@ enable/disable cycles. Everything is lock-protected, label-aware, and
 ``reset()``-able so tests can assert exact deltas.
 
 Exposition:
-  * ``dump_json()``           -> plain dict (bench.py embeds this)
+  * ``dump_json()``           -> plain dict
   * ``dump_prometheus(dst)``  -> Prometheus text format 0.0.4
   * ``PADDLE_MONITOR_DUMP=/path`` dumps at interpreter exit
     (``*.json`` -> JSON, anything else -> Prometheus text).
@@ -340,8 +340,7 @@ def reset():
 # -- exposition ---------------------------------------------------------------
 
 def dump_json():
-    """{name: [{"labels": {...}, <metric fields>}, ...]} — the bench.py
-    embedding format."""
+    """{name: [{"labels": {...}, <metric fields>}, ...]}."""
     out = OrderedDict()
     for m in all_metrics():
         d = m.to_dict()

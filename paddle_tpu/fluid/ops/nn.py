@@ -11,7 +11,6 @@ MXU for convs, so no manual layout transform is needed. Convs and matmuls
 stay whole — XLA tiles them; elementwise epilogues (bias, act) fuse.
 """
 
-import os
 
 import numpy as np
 
@@ -38,20 +37,6 @@ def _conv2d(ctx, op):
     fmt = op.attr("data_format", "NCHW")
     if op.type == "depthwise_conv2d":
         groups = x.shape[-1] if fmt == "NHWC" else x.shape[1]
-    if (os.environ.get("PADDLE_TPU_CONV1X1_GEMM") == "1"
-            and tuple(w.shape[2:]) == (1, 1) and strides == (1, 1)
-            and pads == (0, 0) and groups == 1):
-        # Measured NEGATIVE (r5, v5e, ResNet-50 B=256 AMP): pointwise
-        # convs as explicit contractions — so autodiff emits dots, not
-        # transposed convs, for dx/dw — run at 1566 img/s vs 2424 for
-        # the conv lowering (-35%). XLA's conv path fuses the NCHW
-        # layouts/epilogues better than its dot path at these shapes;
-        # kept env-gated for re-measurement on future toolchains.
-        import jax.numpy as jnp
-
-        eq = ("nchw,oc->nohw" if fmt == "NCHW" else "nhwc,oc->nhwo")
-        ctx.set_output(op, "Output", jnp.einsum(eq, x, w[:, :, 0, 0]))
-        return
     out = jax.lax.conv_general_dilated(
         x,
         w,
@@ -293,7 +278,6 @@ def _batch_norm(ctx, op):
     """Training mode computes batch stats and updates running stats
     (persistable writes, committed by the executor); test mode uses running
     stats. Reference ``operators/batch_norm_op.cc``."""
-    import jax
     import jax.numpy as jnp
 
     x = ctx.get_input(op, "X")
@@ -314,8 +298,7 @@ def _batch_norm(ctx, op):
         use_mean, use_var = mean, var
     else:
         # SINGLE-pass stats (jnp.var re-derives the mean — a second
-        # full-activation sweep; BN dominates ResNet's step, measured
-        # 1478 -> 1946 img/s from this change): E[x-a] and E[(x-a)^2]
+        # full-activation sweep): E[x-a] and E[(x-a)^2]
         # reduce over the same input in one fused sweep, f32
         # accumulation, SHIFTED by the running mean as anchor — exact
         # algebraically (var = E[(x-a)^2] - E[x-a]^2), and the
@@ -329,22 +312,8 @@ def _batch_norm(ctx, op):
         # rsqrt bound the fallout if it ever triggers; the off-anchor
         # regime is pinned by test_batch_norm_far_anchor_stats.
         anchor = mean.astype(jnp.float32).reshape(bshape)
-
-        # PADDLE_TPU_BN_REMAT=1 wraps the stats sweep in jax.checkpoint
-        # so autodiff recomputes the centered f32 activations instead of
-        # storing them. Measured on v5e ResNet-50: remat LOSES with
-        # bf16 BN I/O (B=128: 55.6 vs 53.9 ms; B=256: 107.5 vs 105.2)
-        # AND with f32 I/O (86.7 vs 67.6 ms) — XLA already folds the
-        # convert+subtract into the backward reduce fusions, so the
-        # checkpoint only adds a redundant recompute. Default off; knob
-        # kept for measurement.
-        def _stats(xin):
-            xc = xin.astype(jnp.float32) - anchor
-            return jnp.mean(xc, axis=axes), jnp.mean(xc * xc, axis=axes)
-
-        if os.environ.get("PADDLE_TPU_BN_REMAT", "0") == "1":
-            _stats = jax.checkpoint(_stats)
-        mc, m2 = _stats(x)
+        xc = x.astype(jnp.float32) - anchor
+        mc, m2 = jnp.mean(xc, axis=axes), jnp.mean(xc * xc, axis=axes)
         use_var = jnp.maximum(m2 - mc * mc, 0.0)
         use_mean = mc + anchor.reshape(-1)
         new_mean = momentum * mean + (1.0 - momentum) * use_mean

@@ -6,7 +6,7 @@ C++ ``LoDTensorBlockingQueue`` + ``buffered_reader`` (pre-H2D transfer on a
 CUDA stream). TPU-native: a background ``DeviceStager`` thread assembles
 numpy batches and stages them on device with ``jax.device_put`` ahead of
 consumption — the double-buffer H2D overlap matters even more here because
-the chip can sit behind a high-latency host link (see bench.py); the
+the chip can sit behind a high-latency host link; the
 executor accepts the staged ``jax.Array`` feeds untouched.
 
 Staging is SHARDING-AWARE: pass ``sharding=`` (a ``CompiledProgram``, a

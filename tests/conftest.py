@@ -114,21 +114,55 @@ def _no_leaked_nondaemon_threads():
             "DeviceStager/Executor/loader" % [t.name for t in leaked])
 
 
-def pytest_sessionfinish(session, exitstatus):
-    """Dump the executed-op-type set so the execution-coverage gate's
-    EXEMPT list can be audited: tests/.executed_op_types.txt. Only
-    full-suite sessions write it (partial runs would clobber the
-    meaningful dump with a tiny one)."""
-    try:
-        if len(getattr(session, "items", [])) < 400:
-            return
-        from paddle_tpu.fluid.registry import EXECUTED_OP_TYPES, registry
+_WORKER_OPS = pytest.StashKey()
 
-        here = os.path.dirname(os.path.abspath(__file__))
-        with open(os.path.join(here, ".executed_op_types.txt"), "w") as f:
-            f.write("\n".join(sorted(EXECUTED_OP_TYPES)) + "\n")
-            f.write("# missing:\n")
-            for t in sorted(set(registry.types()) - EXECUTED_OP_TYPES):
-                f.write("# %s\n" % t)
-    except Exception:
-        pass
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_testnodedown(node, error):
+    """xdist controller: keep what the worker's ``pytest_sessionfinish``
+    handed over (a crashed worker hands over nothing)."""
+    out = getattr(node, "workeroutput", None) or {}
+    if "executed_op_types" in out:
+        node.config.stash.setdefault(_WORKER_OPS, []).append(
+            (out["n_items"], out["executed_op_types"]))
+
+
+def pytest_sessionfinish(session, exitstatus):
+    """The execution-coverage gate across processes. ``EXECUTED_OP_TYPES``
+    is per process, and under xdist each worker runs a part of the suite:
+    a worker hands its set to the controller, and the controller judges
+    the union (``test_zz_coverage_gate.unexecuted``), failing the session
+    where the test itself cannot. Full-suite sessions also dump the set,
+    so the gate's EXEMPT list can be audited:
+    tests/.executed_op_types.txt (partial runs would clobber the
+    meaningful dump with a tiny one)."""
+    from paddle_tpu.fluid.registry import EXECUTED_OP_TYPES, registry
+
+    config = session.config
+    n_items = len(getattr(session, "items", []))
+    if hasattr(config, "workerinput"):
+        config.workeroutput["n_items"] = n_items
+        config.workeroutput["executed_op_types"] = sorted(EXECUTED_OP_TYPES)
+        return
+    executed = set(EXECUTED_OP_TYPES)
+    workers = config.stash.get(_WORKER_OPS, None)
+    if workers is not None:
+        n_items = max(n for n, _ in workers)
+        executed.update(*(ops for _, ops in workers))
+    if n_items < 400:
+        return
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, ".executed_op_types.txt"), "w") as f:
+        f.write("\n".join(sorted(executed)) + "\n")
+        f.write("# missing:\n")
+        for t in sorted(set(registry.types()) - executed):
+            f.write("# %s\n" % t)
+    if workers is not None:
+        from test_zz_coverage_gate import unexecuted
+
+        message = unexecuted(executed)
+        if message is not None:
+            reporter = config.pluginmanager.get_plugin("terminalreporter")
+            reporter.write_sep("=", "test_zz_coverage_gate", red=True)
+            reporter.write_line(message, red=True)
+            session.exitstatus = pytest.ExitCode.TESTS_FAILED

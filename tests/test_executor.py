@@ -337,6 +337,132 @@ def test_iters_py_reader_drains_exactly_k_batches():
         np.testing.assert_allclose(np.asarray(t3).ravel(), [0.0, 1.0])
 
 
+# -- the host plan: what run() does before any step, single step or window ---
+
+def _reader_program(n_batches, B=4, D=3):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        reader = layers.py_reader(capacity=8, shapes=[[B, D]],
+                                  dtypes=["float32"])
+        x = layers.read_file(reader)
+        w = layers.create_parameter([D], "float32", name="hp_w")
+        m = layers.reduce_mean(x * w)
+        optimizer.SGD(learning_rate=0.1).minimize(m)
+    batches = [(np.full((B, D), i + 1, np.float32),)
+               for i in range(n_batches)]
+    reader.decorate_tensor_provider(lambda: iter(batches))
+    return main, startup, reader, m
+
+
+@pytest.mark.parametrize("iters", [1, 2])
+def test_server_program_serves_or_is_refused(iters, monkeypatch):
+    """A server op is a host loop: a single-step run serves it and
+    returns [] with nothing compiled; iters=k refuses it."""
+    from paddle_tpu.fluid.transpiler import distribute_transpiler
+
+    served = []
+
+    class _Server:
+        def serve_forever(self):
+            served.append(True)
+
+    monkeypatch.setattr(distribute_transpiler, "build_server_from_attrs",
+                        lambda attrs: _Server())
+    prog = fluid.Program()
+    prog.global_block().append_op("listen_and_serv", inputs={},
+                                  outputs={}, attrs={})
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        if iters == 1:
+            assert exe.run(prog) == []
+            assert served == [True] and not exe._cache
+        else:
+            with pytest.raises(RuntimeError,
+                               match="cannot drive a server program "
+                                     "\\(listen_and_serv op\\)"):
+                exe.run(prog, iters=iters)
+            assert not served
+
+
+@pytest.mark.parametrize("iters", [1, 2])
+def test_save_op_in_a_sub_block_is_refused(iters, tmp_path):
+    main, startup, loss = _sgd_program()
+    w = main.all_parameters()[0]
+    sub = main._create_block()
+    sub.append_op("save", inputs={"X": [w]}, outputs={},
+                  attrs={"file_path": str(tmp_path / "w")})
+    main._rollback()
+    exe = fluid.Executor()
+    feed = {"x": np.zeros((8, 4), np.float32),
+            "label": np.zeros((8, 1), np.float32)}
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        with pytest.raises(RuntimeError, match="control-flow sub-block"):
+            exe.run(main, feed=feed, fetch_list=[loss], iters=iters)
+    assert not (tmp_path / "w").exists()
+
+
+@pytest.mark.parametrize("iters", [1, 2])
+def test_garbage_collected_py_reader_is_named(iters):
+    import gc
+
+    main, startup, reader, m = _reader_program(2)
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        del reader
+        gc.collect()
+        with pytest.raises(RuntimeError, match="garbage-collected"):
+            exe.run(main, fetch_list=[m], iters=iters)
+
+
+@pytest.mark.parametrize("iters", [1, 2])
+def test_py_reader_eof_comes_before_the_step(iters):
+    """A queue that cannot fill the step (or the window of k) raises EOF
+    with the state untouched and the readers reset for the next pass."""
+    main, startup, reader, m = _reader_program(2 * iters - 1)
+    exe = fluid.Executor()
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        reader.start()
+        exe.run(main, fetch_list=[m], iters=iters)
+        w_before = np.asarray(scope.find_var("hp_w")).copy()
+        rng_before = np.asarray(scope.find_var("@rng_state@")).copy()
+        # iters=1: the queue is empty; iters=2: one batch is left of two
+        with pytest.raises(fluid.core.EOFException):
+            exe.run(main, fetch_list=[m], iters=iters)
+        np.testing.assert_array_equal(
+            np.asarray(scope.find_var("hp_w")), w_before)
+        np.testing.assert_array_equal(
+            np.asarray(scope.find_var("@rng_state@")), rng_before)
+        reader.start()
+        (t,) = exe.run(main, fetch_list=[m], iters=iters)
+        assert np.asarray(t).size == iters
+
+
+def test_iters_is_a_member_of_the_one_cache_key():
+    """One program at iters=1 and at iters=2: two entries of the one
+    cache, each compiled once and hit once."""
+    from paddle_tpu.fluid import monitor
+
+    main, startup, loss = _sgd_program()
+    exe = fluid.Executor()
+    rng = np.random.RandomState(0)
+    feed = {"x": rng.rand(8, 4).astype(np.float32),
+            "label": rng.rand(8, 1).astype(np.float32)}
+    hits = monitor.counter("executor_compile_cache_hit_total")
+    misses = monitor.counter("executor_compile_cache_miss_total")
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        n, m0, h0 = len(exe._cache), misses.value, hits.value
+        for k in (1, 2, 1, 2):
+            exe.run(main, feed=feed, fetch_list=[loss], iters=k)
+        assert (misses.value - m0, hits.value - h0) == (2, 2)
+        assert len(exe._cache) == n + 2
+        assert len({len(key) for key in exe._cache}) == 1
+
+
 def test_iters_gspmd_matches_sequential():
     """iters=k composes with with_data_parallel (GSPMD): trajectory
     matches the sequential CompiledProgram runs."""

@@ -181,8 +181,7 @@ def test_speculative_identity_compiles_and_acceptance_ceiling():
     """One dense baseline, two draft configurations: a shallow draft
     must emit bit-identical tokens for exactly two extra compiles and
     never retrace on reuse; a full-depth draft (draft == target) must
-    hit the acceptance ceiling — every round accepts all k tokens (the
-    histogram mean BENCH_DECODE asserts >= 1.5)."""
+    hit the acceptance ceiling — every round accepts all k tokens."""
     B, S, P, C = 2, 6, 4, 32
     rng = np.random.RandomState(4)
     src = rng.randint(2, 512, (B, S)).astype(np.int64)

@@ -2,7 +2,9 @@
 lowering must actually RUN during the suite — ``registry.lower_op`` (and
 the dygraph tracer) record executed types into ``EXECUTED_OP_TYPES``, and
 this file (alphabetically last, so it runs after every other module)
-asserts registry ⊆ executed ∪ EXEMPT. Unlike the old textual-mention
+asserts registry ⊆ executed ∪ EXEMPT. The set is per process: under xdist
+the test skips in the workers and ``tests/conftest.py`` judges the union
+of their sets on the controller at session end. Unlike the old textual-mention
 check (an op named in a comment passed), a lowering that silently stops
 being exercised now fails the build. Reference analogue: the op-test
 discipline of ``unittests/op_test.py:135``."""
@@ -22,14 +24,27 @@ EXEMPT = {
 }
 
 
-def test_every_registered_lowering_executed(request):
-    from paddle_tpu.fluid.registry import EXECUTED_OP_TYPES, registry
+def unexecuted(executed):
+    """The gate's verdict on a set of executed op types: what to fail
+    with, or None."""
+    from paddle_tpu.fluid.registry import registry
 
+    missing = sorted(t for t in registry.types()
+                     if t not in executed and t not in EXEMPT)
+    if missing:
+        return ("registered op lowerings never executed by the suite "
+                "(add a real execution test or an EXEMPT entry with a "
+                "reason): %s" % missing)
+
+
+def test_every_registered_lowering_executed(request):
+    from paddle_tpu.fluid.registry import EXECUTED_OP_TYPES
+
+    if hasattr(request.config, "workerinput"):
+        pytest.skip("an xdist worker ran a part of the suite: the "
+                    "controller judges the union of the workers' sets at "
+                    "session end (tests/conftest.py pytest_sessionfinish)")
     if len(request.session.items) < 400:
         pytest.skip("partial run: the execution gate needs the full suite")
-    missing = sorted(t for t in registry.types()
-                     if t not in EXECUTED_OP_TYPES and t not in EXEMPT)
-    assert not missing, (
-        "registered op lowerings never executed by the suite "
-        "(add a real execution test or an EXEMPT entry with a reason): %s"
-        % missing)
+    message = unexecuted(EXECUTED_OP_TYPES)
+    assert message is None, message

@@ -14,12 +14,12 @@ beyond a bounded stall:
            key — the WAL-persisted wall deadline plus the keeper's
            post-reconnect replay keep the member live well past the
            TTL it held when the server died.
-  fleet    delegate to ``bench.bench_coord_recovery(smoke=True)``:
-           coordinator crash + recovery under closed-loop serving
-           traffic (every request accounted, stale-routing window
-           observed, zero lost).
 
-Usage: python tools/chaos.py [barrier|lease|fleet|all]
+(The fleet's story - coordinator crash under closed-loop serving
+traffic, zero lost - is ``tests/test_chaos.py::
+test_fleet_rides_out_coordinator_crash``, in-process.)
+
+Usage: python tools/chaos.py [barrier|lease|all]
 Exit code 0 = every scenario held its invariant; one JSON line per
 scenario on stdout.
 """
@@ -149,18 +149,8 @@ def scenario_lease():
         _kill9(proc)
 
 
-def scenario_fleet():
-    """Coordinator crash + recovery under closed-loop fleet traffic."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import bench
-
-    out = bench.bench_coord_recovery(smoke=True)
-    return dict({"scenario": "fleet", "ok": True}, **out)
-
-
 _SCENARIOS = {"barrier": scenario_barrier,
-              "lease": scenario_lease,
-              "fleet": scenario_fleet}
+              "lease": scenario_lease}
 
 
 def main(argv=None):

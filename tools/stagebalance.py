@@ -132,8 +132,7 @@ def stage_report(program, feed):
 
 
 def _build_demo(n_layers, n_stages, mb_rows, seq_len, vocab):
-    """Tiny EncoderTower LM with uniform layer cuts — the same model
-    ``bench.py``'s BENCH_PIPELINE leg times."""
+    """Tiny EncoderTower LM with uniform layer cuts."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import dygraph, layers, optimizer
     from paddle_tpu.models import transformer
