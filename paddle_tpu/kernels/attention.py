@@ -42,11 +42,21 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       (``_causal_mask``), so a causal call needs no [S, S] bias and keeps
       the tier its S selects: the flash tier takes row-broadcast bias
       only, and a ``[1, 1, S, S]`` causal bias would fall to the
-      blockwise scan. Every k-tile is still computed (one wholly above
-      the diagonal adds 0); skipping them is a later optimisation. A call
-      without ``causal`` traces to the jaxpr it always did. Fewer KV than
-      Q heads: the ``fused_multihead_attention`` op repeats K/V to the Q
-      head count before the kernel (``num_kv_heads``).
+      blockwise scan. The flash tier does only the causal work: a score
+      tile wholly above the diagonal (k-tile j > q-tile i; nt(nt-1)/2 of
+      nt² a (batch, head)) is skipped in all three kernels. Its steps
+      come FIRST in their sweep (``_flash_ktile``; in dk/dv they do by
+      the grid's order): the body sits under ``pl.when``, and their block
+      index is that of the sweep's first tile (``_flash_specs``), so the
+      pipeline fetches that block under the row before and issues no DMA
+      for them. Such a tile's probabilities were exp(-1e30 - m) = 0, and
+      the tiles computed keep their order: every output is what it was,
+      to the bit. ``attn_flash_tiles_total{kind}`` counts a site's tiles
+      computed and skipped. The block and long tiers hold all of K a step
+      and have no sweep to shorten. A call without ``causal`` traces to
+      the jaxpr it always did. Fewer KV than Q heads: the
+      ``fused_multihead_attention`` op repeats K/V to the Q head count
+      before the kernel (``num_kv_heads``).
 
 Packed layout (``fused_attention_packed``, FORCE=packed): q/k/v stay in
 the fc-native [B, S, H*d] layout with heads handled inside the kernel;
@@ -154,6 +164,23 @@ def _count_kernel(tier):
         "per traced program, not per step)", labels={"tier": tier}).inc()
 
 
+def _count_flash_tiles(nt, causal, sites=1):
+    """Trace-time record, once a traced flash ``pallas_call`` site (of
+    which the caller builds ``sites``), of the score tiles a (batch, head)
+    that the kernel computes and of those it skips (under ``causal``, the
+    ones wholly above the diagonal)."""
+    from ..fluid import monitor as _monitor
+
+    computed = nt * (nt + 1) // 2 if causal else nt * nt
+    for kind, n in (("computed", computed), ("skipped", nt * nt - computed)):
+        _monitor.counter(
+            "attn_flash_tiles_total",
+            "flash attention score tiles a (batch, head), computed or "
+            "skipped as wholly above the causal diagonal (trace-time: "
+            "once a traced kernel site, not per step)",
+            labels={"kind": kind}).inc(n * sites)
+
+
 _ATTN_FORCE_VALUES = ("flash", "packed", "decode", "paged", "ring",
                       "ulysses")
 
@@ -182,7 +209,8 @@ def _causal_mask(s, row0=0, col0=0):
     """``s`` with -1e30 wherever the column lies after the row, by index
     over the last two axes; ``row0`` / ``col0`` are the tile's offsets in
     the sequence. -1e30, not -inf (NaN discipline): column 0 is open to
-    every row, so a row's maximum is real by the first k-tile."""
+    every row, so a row's maximum is real by the first k-tile (tile
+    ``(i, 0)``, which no causal skip ever drops)."""
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 2)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
     return jnp.where(cols <= rows, s, -1e30)
@@ -650,6 +678,25 @@ def _flash_seed(seed0, b, h, i, j, n_heads, nq, nk):
     return seed0 + (((b * n_heads + h) * nq + i) * nk + j)
 
 
+def _flash_ktile(nk, causal):
+    """``(j, i)``: the k-tile that this step of a q-tile's sweep computes
+    (grid ``(B, H, nq, nk)``, k-tile fastest) and, under ``causal``, the
+    q-tile (else None). Read at the kernel's top level: the interpreter
+    resolves program ids there only. Under ``causal`` q-tile i has the
+    i + 1 tiles 0..i to compute (q and k share the tile edge: a tile with
+    j > i lies wholly above the diagonal, its probabilities were
+    exp(-1e30 - m) = 0 and it added 0.0 everywhere) and takes them on its
+    LAST i + 1 steps, in the same order, so the sweep ends on the diagonal.
+    The steps before them have no tile (j < 0) and hold the block of tile
+    0, which the pipeline fetched under the row before (``_flash_specs``):
+    they move no data."""
+    j = pl.program_id(3)
+    if not causal:
+        return j, None
+    i = pl.program_id(2)
+    return j - (nk - 1 - i), i
+
+
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                       lse_ref, acc_scr, m_scr, l_scr, *, scale, p_drop,
                       n_heads, nq, nk, causal=False):
@@ -659,39 +706,47 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     masks only the value accumulation — the denominator uses undropped
     weights (same semantics as _blockwise_attention)."""
 
-    j = pl.program_id(3)
-    q = q_ref[0, 0]                               # [Tb, d]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = s + bias_ref[0, 0]                        # [1, Tb] row-broadcast
-    if causal:      # every k-tile is computed; one wholly masked adds 0
-        s = _causal_mask(s, pl.program_id(2) * q.shape[0], j * k.shape[0])
+    j, qi = _flash_ktile(nk, causal)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, -1e30, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    def _tile():
+        q = q_ref[0, 0]                               # [Tb, d]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = s + bias_ref[0, 0]                    # [1, Tb] row-broadcast
+        if causal:
+            s = _causal_mask(s, qi * q.shape[0], j * k.shape[0])
 
-    m_prev = m_scr[...]                           # [Tb, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                        # [Tb, Tb]
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    if p_drop > 0.0:
-        b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
-                                    n_heads, nq, nk))
-        u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-        p = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+        @pl.when(j == 0)
+        def _init():
+            m_scr[...] = jnp.full(m_scr.shape, -1e30, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(j == nk - 1)
+        m_prev = m_scr[...]                           # [Tb, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                        # [Tb, Tb]
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if p_drop > 0.0:
+            b, h, i = (pl.program_id(0), pl.program_id(1),
+                       pl.program_id(2))
+            pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
+                                        n_heads, nq, nk))
+            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
+            p = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    if causal:      # j < 0: a step of the sweep that has no tile
+        pl.when(j >= 0)(_tile)
+    else:
+        _tile()
+
+    @pl.when(j == (qi if causal else nk - 1))
     def _emit():
         l = l_scr[...]
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -707,42 +762,50 @@ def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     exp(s - L) is exactly softmax without a second online pass. Also
     emits per-(q-tile) dbias partials, reduced outside the kernel."""
 
-    j = pl.program_id(3)
-    q = q_ref[0, 0]                               # [Tb, d]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0]                           # [Tb, 1]
-    dd = dd_ref[0, 0]                             # rowsum(do*o) [Tb, 1]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = s + bias_ref[0, 0]
+    j, qi = _flash_ktile(nk, causal)
+
+    def _tile():
+        q = q_ref[0, 0]                               # [Tb, d]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = lse_ref[0, 0]                           # [Tb, 1]
+        dd = dd_ref[0, 0]                             # rowsum(do*o) [Tb, 1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = s + bias_ref[0, 0]
+        if causal:
+            s = _causal_mask(s, qi * q.shape[0], j * k.shape[0])
+        p = jnp.exp(s - lse)                      # undropped softmax rows
+        if p_drop > 0.0:
+            b, h, i = (pl.program_id(0), pl.program_id(1),
+                       pl.program_id(2))
+            pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
+                                        n_heads, nq, nk))
+            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
+            pd = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
+        else:
+            pd = p
+        dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        ds = pd * dpd - p * dd                        # [Tb, Tb]
+        dbias_ref[0, 0] = jnp.sum(ds, axis=0, keepdims=True)
+        contrib = jax.lax.dot_general(
+            ds.astype(q.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+
+        @pl.when(j == 0)
+        def _init():
+            dq_ref[0, 0] = contrib
+
+        @pl.when(j != 0)
+        def _acc():
+            dq_ref[0, 0] += contrib
+
     if causal:
-        s = _causal_mask(s, pl.program_id(2) * q.shape[0], j * k.shape[0])
-    p = jnp.exp(s - lse)                          # undropped softmax rows
-    if p_drop > 0.0:
-        b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
-                                    n_heads, nq, nk))
-        u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-        pd = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
+        pl.when(j >= 0)(_tile)
     else:
-        pd = p
-    dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    ds = pd * dpd - p * dd                        # [Tb, Tb]
-    dbias_ref[0, 0] = jnp.sum(ds, axis=0, keepdims=True)
-    contrib = jax.lax.dot_general(ds.astype(q.dtype), k,
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32) * scale
-
-    @pl.when(j == 0)
-    def _init():
-        dq_ref[0, 0] = contrib
-
-    @pl.when(j != 0)
-    def _acc():
-        dq_ref[0, 0] += contrib
+        _tile()
 
 
 def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
@@ -752,66 +815,98 @@ def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     dk/dv blocks (keyed on the k-tile) accumulate over consecutive
     q-tile steps. The PRNG seed uses the same (i, j) formula as the
     forward, so the regenerated mask is bit-exact despite the
-    transposed grid order."""
+    transposed grid order. Under ``causal`` the sweep's first j steps
+    (q-tiles wholly before the k-tile) are skipped, so the first tile
+    computed, which initialises dk and dv, is the diagonal one."""
 
     j, i = pl.program_id(2), pl.program_id(3)
-    q = q_ref[0, 0]                               # [Tb, d]
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0]
-    dd = dd_ref[0, 0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = s + bias_ref[0, 0]
+    first = j if causal else 0
+
+    def _tile():
+        q = q_ref[0, 0]                               # [Tb, d]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = lse_ref[0, 0]
+        dd = dd_ref[0, 0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = s + bias_ref[0, 0]
+        if causal:
+            s = _causal_mask(s, i * q.shape[0], j * k.shape[0])
+        p = jnp.exp(s - lse)
+        if p_drop > 0.0:
+            b, h = pl.program_id(0), pl.program_id(1)
+            pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
+                                        n_heads, nq, nk))
+            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
+            pd = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
+        else:
+            pd = p
+        lp = q.dtype
+        dv = jax.lax.dot_general(pd.astype(lp), do,
+                                 (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        ds = pd * dpd - p * dd
+        dk = jax.lax.dot_general(ds.astype(lp), q,
+                                 (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+
+        @pl.when(i == first)
+        def _init():
+            dk_ref[0, 0] = dk
+            dv_ref[0, 0] = dv
+
+        @pl.when(i != first)
+        def _acc():
+            dk_ref[0, 0] += dk
+            dv_ref[0, 0] += dv
+
     if causal:
-        s = _causal_mask(s, i * q.shape[0], j * k.shape[0])
-    p = jnp.exp(s - lse)
-    if p_drop > 0.0:
-        b, h = pl.program_id(0), pl.program_id(1)
-        pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
-                                    n_heads, nq, nk))
-        u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-        pd = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
+        pl.when(i >= j)(_tile)
     else:
-        pd = p
-    lp = q.dtype
-    dv = jax.lax.dot_general(pd.astype(lp), do,
-                             (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [Tb, d]
-    dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    ds = pd * dpd - p * dd
-    dk = jax.lax.dot_general(ds.astype(lp), q,
-                             (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32) * scale
-
-    @pl.when(i == 0)
-    def _init():
-        dk_ref[0, 0] = dk
-        dv_ref[0, 0] = dv
-
-    @pl.when(i != 0)
-    def _acc():
-        dk_ref[0, 0] += dk
-        dv_ref[0, 0] += dv
+        _tile()
 
 
-def _flash_specs(q, bias):
+def _flash_specs(q, bias, causal, kfast=True):
+    """Block specs of the flash kernels' grid ``(B, H, slow, fast)``:
+    ``kfast`` has the k-tile fastest (forward, dq), else the q-tile (dk/dv).
+    Under ``causal`` the steps of a sweep that have no tile come first
+    (``_flash_ktile``; in dk/dv the q-tiles before the k-tile) and name the
+    block of the sweep's first tile, so the pipeline fetches it once, under
+    the row before, and issues no DMA for them."""
     B, H, S, d = q.shape
     TB = _flash_block(S)
     nt = S // TB
     hb = bias.shape[1]
-    qspec = pl.BlockSpec((1, 1, TB, d), lambda b, h, i, j: (b, h, i, 0))
-    kspec = pl.BlockSpec((1, 1, TB, d), lambda b, h, i, j: (b, h, j, 0))
-    bspec = pl.BlockSpec((1, 1, 1, TB),
-                         lambda b, h, i, j, _hb=hb: (b, h if _hb > 1 else 0,
-                                                     0, j))
+
+    def tiles(*g):      # grid indices -> (b, h, q-tile, k-tile) to fetch
+        b, h, i, j = g if kfast else (g[0], g[1], g[3], g[2])
+        if causal and kfast:
+            j = jnp.maximum(j - (nt - 1 - i), 0)
+        elif causal:
+            i = jnp.maximum(i, j)
+        return b, h, i, j
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda *g: index(*tiles(*g)))
+
+    qspec = spec((1, 1, TB, d), lambda b, h, i, j: (b, h, i, 0))
+    kspec = spec((1, 1, TB, d), lambda b, h, i, j: (b, h, j, 0))
+    bspec = spec((1, 1, 1, TB),
+                 lambda b, h, i, j: (b, h if hb > 1 else 0, 0, j))
     # per-row stats (lse, rowsum(do*o)) ride as [B, H, S, 1]: trailing
     # dim 1 satisfies the TPU block-shape rule (equal to the array dim)
     # and [Tb, 1] blocks line up with the kernels' column-vector math
-    rowspec = pl.BlockSpec((1, 1, TB, 1), lambda b, h, i, j: (b, h, i, 0))
-    return TB, nt, qspec, kspec, bspec, rowspec
+    rowspec = spec((1, 1, TB, 1), lambda b, h, i, j: (b, h, i, 0))
+    # dbias partials: one [1, TB] row-sum per (q-tile, k-tile), each
+    # block written at most once (no cross-grid-dim revisit hazards);
+    # laid out [B, H*nt, 1, S] to satisfy the TPU block-shape rule
+    dbpspec = spec((1, 1, 1, TB),
+                   lambda b, h, i, j: (b, h * nt + i, 0, j))
+    return TB, nt, qspec, kspec, bspec, rowspec, dbpspec
 
 
 def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed,
@@ -820,7 +915,8 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed,
 
     _count_kernel("flash")
     B, H, S, d = q.shape
-    TB, nt, qspec, kspec, bspec, rowspec = _flash_specs(q, bias)
+    TB, nt, qspec, kspec, bspec, rowspec, _ = _flash_specs(q, bias, causal)
+    _count_flash_tiles(nt, causal)
     f32 = jnp.float32
     return _kernel_call(
         "attn_flash_fwd",
@@ -843,17 +939,13 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
                                 p_drop, causal=False):
     _count_kernel("flash_bwd")
     B, H, S, d = q.shape
-    TB, nt, qspec, kspec, bspec, rowspec = _flash_specs(q, bias)
+    TB, nt, qspec, kspec, bspec, rowspec, dbpspec = _flash_specs(
+        q, bias, causal)
+    _count_flash_tiles(nt, causal, sites=2)     # dq, dk/dv
     f32 = jnp.float32
     dd = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1,
                  keepdims=True)                            # [B, H, S, 1]
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    # dbias partials: one [1, TB] row-sum per (q-tile, k-tile), each
-    # block written exactly once (no cross-grid-dim revisit hazards);
-    # laid out [B, H*nt, 1, S] to satisfy the TPU block-shape rule, and
-    # reduced to the bias broadcast shape with plain XLA below.
-    dbpspec = pl.BlockSpec(
-        (1, 1, 1, TB), lambda b, h, i, j, _nt=nt: (b, h * _nt + i, 0, j))
     dq, dbp = _kernel_call(
         "attn_flash_bwd_dq",
         functools.partial(_flash_dq_kernel, scale=scale, p_drop=p_drop,
@@ -868,13 +960,8 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
     )(seed, q, k, v, bias, do, lse, dd)
     # transposed grid: k-tile is the SLOW tile dim so dk/dv accumulate
     # over consecutive q-tile steps
-    qspec_t = pl.BlockSpec((1, 1, TB, d), lambda b, h, j, i: (b, h, i, 0))
-    kspec_t = pl.BlockSpec((1, 1, TB, d), lambda b, h, j, i: (b, h, j, 0))
-    bspec_t = pl.BlockSpec(
-        (1, 1, 1, TB),
-        lambda b, h, j, i, _hb=bias.shape[1]: (b, h if _hb > 1 else 0,
-                                               0, j))
-    rowspec_t = pl.BlockSpec((1, 1, TB, 1), lambda b, h, j, i: (b, h, i, 0))
+    _, _, qspec_t, kspec_t, bspec_t, rowspec_t, _ = _flash_specs(
+        q, bias, causal, kfast=False)
     dk, dv = _kernel_call(
         "attn_flash_bwd_dkv",
         functools.partial(_flash_dkdv_kernel, scale=scale, p_drop=p_drop,
@@ -887,8 +974,11 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
                    jax.ShapeDtypeStruct(q.shape, f32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
     )(seed, q, k, v, bias, do, lse, dd)
-    dbias = jnp.sum(dbp.reshape(B, H, nt, S), axis=2,
-                    keepdims=False)[:, :, None, :]         # [B, H, 1, S]
+    dbp = dbp.reshape(B, H, nt, S)
+    if causal:      # the partials of the tiles skipped were never written
+        dbp = jnp.where(jnp.arange(S)[None, :] // TB
+                        <= jnp.arange(nt)[:, None], dbp, 0.0)
+    dbias = jnp.sum(dbp, axis=2)[:, :, None, :]            # [B, H, 1, S]
     if bias.shape[1] == 1:
         dbias = jnp.sum(dbias, axis=1, keepdims=True)
     return dq, dk, dv, dbias

@@ -3,7 +3,9 @@ mask is made inside the kernels from row and column indices, in every
 training tier (block, long, flash - under the Pallas interpreter - and the
 blockwise fallback), against ``_ref_attention`` with an explicit causal
 bias; K/V with fewer heads than Q through the ``fused_multihead_attention``
-op; and a call without ``causal`` traces to the jaxpr it always did."""
+op; a call without ``causal`` traces to the jaxpr it always did; and the
+flash tier skips the k-tiles wholly above the diagonal (neither fetched
+nor computed) and counts them in ``attn_flash_tiles_total``."""
 
 import os
 
@@ -100,6 +102,80 @@ def test_causal_gqa_gradients_match_reference(monkeypatch, tier):
     for a, b, name in zip(got, want, "qkv"):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-5, err_msg=name)
+
+
+def test_flash_skips_the_tiles_above_the_diagonal(monkeypatch):
+    """V's last k-tile is NaN. Q-tiles 0..2 lie wholly before it, so a
+    kernel that skips their last k-tile never reads it and returns what
+    the clean run returns (one that computes it multiplies NaN by p = 0
+    and returns NaN there); the last q-tile sees it and is NaN."""
+    S, taken = _tier(monkeypatch, "flash")
+    q, k, v, pad = _qkv(S, Hkv=4)
+    assert taken(q, pad)
+    tb = A._flash_block(S)
+    clean = A.fused_attention(q, k, v, pad, causal=True)
+    got = A.fused_attention(q, k, v.at[:, :, -tb:].set(jnp.nan), pad,
+                            causal=True)
+    np.testing.assert_array_equal(got[:, :, :-tb], clean[:, :, :-tb])
+    assert np.isfinite(clean).all()
+    assert np.isnan(got[:, :, -tb:]).all()
+
+
+@pytest.mark.parametrize("tiles", [4, 1])
+def test_flash_causal_gradients_with_dbias_match_reference(monkeypatch,
+                                                           tiles):
+    """dq, dk, dv and the gradient of the padded-tail bias with the
+    skipped tiles (4 x 4: their dbias partials are written as zeros) and
+    with one tile (S = Tb: nothing to skip)."""
+    S, taken = _tier(monkeypatch, "flash")
+    monkeypatch.setattr(A, "_FLASH_BLOCK_CANDIDATES", (S // tiles,))
+    q, k, v, pad = _qkv(S, Hkv=4)
+    assert taken(q, pad) and S // A._flash_block(S) == tiles
+    scale = 1.0 / np.sqrt(q.shape[-1])
+
+    def fused(q_, k_, v_, pad_):
+        return jnp.sum(jnp.sin(A.fused_attention(q_, k_, v_, pad_,
+                                                 causal=True)))
+
+    def ref(q_, k_, v_, pad_):
+        return jnp.sum(jnp.sin(_reference(q_, k_, v_, pad_, scale)))
+
+    np.testing.assert_allclose(
+        A.fused_attention(q, k, v, pad, causal=True),
+        _reference(q, k, v, pad, scale), rtol=2e-5, atol=2e-6)
+    got = jax.grad(fused, argnums=(0, 1, 2, 3))(q, k, v, pad)
+    want = jax.grad(ref, argnums=(0, 1, 2, 3))(q, k, v, pad)
+    for a, b, name in zip(got, want, ("q", "k", "v", "bias")):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_tiles_counter(monkeypatch, causal):
+    """``attn_flash_tiles_total``: once a traced kernel site, the 4 x 4
+    tiles a (batch, head) it computes and skips - the forward alone is
+    one site, a forward and backward three (forward, dq, dk/dv)."""
+    from paddle_tpu.fluid import monitor
+
+    S, _ = _tier(monkeypatch, "flash")
+    q, k, v, pad = _qkv(S, Hkv=4)
+
+    def read():
+        return tuple(monitor.counter("attn_flash_tiles_total",
+                                     labels={"kind": kind}).value
+                     for kind in ("computed", "skipped"))
+
+    def loss(q_, k_, v_):
+        return jnp.sum(A.fused_attention(q_, k_, v_, pad, causal=causal))
+
+    site = (10, 6) if causal else (16, 0)
+    c0 = read()
+    jax.make_jaxpr(loss)(q, k, v)
+    c1 = read()
+    assert tuple(b - a for a, b in zip(c0, c1)) == site
+    jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v)
+    assert tuple(b - a for a, b in zip(c1, read())) == tuple(
+        3 * n for n in site)
 
 
 def test_op_takes_causal_and_num_kv_heads():

@@ -1,17 +1,19 @@
-"""The delta rule's kernels at the benchmark cell's shape, compiled for a
-DESCRIBED v5e (no chip attached): what the chip's compiler refuses - a
-slice off the tiling, too much VMEM, an op Mosaic cannot lower - it
-refuses here, at no chip time. Nothing runs, so nothing is said about
-results or speed. The topology is described inside a fixture, never at
-import: only the worker that is given this file loads the TPU's library."""
+"""The delta rule's kernels and the causal flash attention's at the
+benchmark cell's shape, compiled for a DESCRIBED v5e (no chip attached):
+what the chip's compiler refuses - a slice off the tiling, too much
+VMEM, an op Mosaic cannot lower - it refuses here, at no chip time.
+Nothing runs, so nothing is said about results or speed. The topology is
+described inside a fixture, never at import: only the worker that is
+given this file loads the TPU's library."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 # qwen3-next-80b-a3b.train-s8192: batch, sequence, key heads, value heads,
-# head dim, chunk
+# head dim, chunk; the attention layer's heads and head dim
 B, S, HK, HV, D, C = 2, 8192, 16, 32, 128, 64
+HA, DA = 16, 256
 
 
 @pytest.fixture(scope="module")
@@ -29,8 +31,9 @@ def one_chip():
 
 @pytest.fixture
 def compiled_text(one_chip, monkeypatch):
-    """``fn -> HLO text`` of ``fn`` over the cell's q, k, v, gc, beta,
-    compiled for the chip with the kernels NOT interpreted."""
+    """``fn -> HLO text`` of ``fn`` over the cell's q, k, v, gc, beta (or
+    over ``shapes``, bfloat16), compiled for the chip with the kernels NOT
+    interpreted."""
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
@@ -46,7 +49,9 @@ def compiled_text(one_chip, monkeypatch):
             spec((B, S, HV * D), jnp.bfloat16),
             spec((B, HV, S // 128, 128), jnp.float32),
             spec((B, HV, S // 128, 128), jnp.float32))
-    yield lambda fn: jax.jit(fn).lower(*args).compile().as_text()
+    yield lambda fn, shapes=None: jax.jit(fn).lower(*(
+        args if shapes is None else
+        [spec(s, jnp.bfloat16) for s in shapes])).compile().as_text()
     jax.config.update("jax_enable_compilation_cache", True)
 
 
@@ -67,3 +72,18 @@ def test_backward_kernel_compiles_for_v5e_at_the_cells_shape(compiled_text):
         argnums=(0, 1, 2, 3, 4)))
     assert "tpu_custom_call" in text
     assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
+
+
+def test_causal_flash_kernels_compile_for_v5e_at_the_cells_shape(
+        compiled_text, monkeypatch):
+    """Forward, dq and dk/dv with the tiles above the diagonal skipped:
+    the clamped block indices and the ``pl.when`` bodies pass Mosaic."""
+    from paddle_tpu.kernels import attention as A
+
+    monkeypatch.setattr(A, "_supports_pallas", lambda: True)
+    text = compiled_text(
+        jax.grad(lambda q, k, v: jnp.sum(A.fused_attention(
+            q, k, v, scale=DA ** -0.5, causal=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)), [(B, HA, S, DA)] * 3)
+    for name in ("attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv"):
+        assert name in text
