@@ -50,9 +50,10 @@ gray_list = {
     # gated_delta_rule keeps A_log / dt_bias, its decays, its triangular
     # system and its state in f32 and feeds the matmuls in the input
     # dtype; moe_experts keeps the router's weights f32 and runs the
-    # grouped GEMMs in the input dtype
+    # grouped GEMMs in the input dtype; sparse_index feeds its score
+    # matmuls in the input dtype, accumulates, weighs and compares in f32
     "rms_norm", "swiglu", "rotary_embedding", "causal_conv1d",
-    "gated_delta_rule", "moe_experts",
+    "gated_delta_rule", "moe_experts", "sparse_index",
 }
 
 

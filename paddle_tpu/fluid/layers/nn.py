@@ -37,7 +37,8 @@ __all__ = [
     "autoincreased_step_counter", "smooth_l1", "dice_loss", "py_func",
     "linear_chain_crf", "crf_decoding", "ctc_greedy_decoder",
     "shard_tensor", "fused_attention", "fused_attention_packed",
-    "einsum", "rms_norm", "swiglu", "rotary_embedding", "causal_conv1d",
+    "einsum", "rms_norm", "swiglu", "rotary_embedding", "sparse_index",
+    "causal_conv1d",
     "gated_delta_rule", "moe_route", "moe_experts",
 ]
 
@@ -1607,18 +1608,26 @@ def shard_tensor(x, spec, name=None):
 
 def fused_attention(q, k, v, attn_bias=None, scale=None, dropout_prob=0.0,
                     is_test=False, name=None, causal=False,
-                    num_kv_heads=None):
+                    num_kv_heads=None, select=None):
     """Fused softmax(q·kᵀ·scale + bias)·v over [B, H, S, d] heads — a
     single Pallas TPU kernel per (batch, head) with in-kernel dropout;
     falls back to the unfused jnp math off-TPU (kernels/attention.py).
     ``causal`` masks column > row inside the kernel; ``num_kv_heads``
     says K and V carry that many heads, each serving H / num_kv_heads
-    consecutive Q heads (grouped-query attention)."""
+    consecutive Q heads (grouped-query attention). ``select`` [B, S, S]
+    (integer, what ``sparse_index`` gives: nonzero = query t may see key
+    s) restricts every head of a batch row to one learned set of keys a
+    query; it carries no gradient, goes with neither ``attn_bias`` nor
+    dropout, and takes the kernels' select tier (with ``causal``, any S
+    that a 128-row tile divides and at most 8 Q heads a K/V head; else
+    the masked jnp form)."""
     helper = LayerHelper("fused_multihead_attention", **locals())
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if attn_bias is not None:
         inputs["Bias"] = [attn_bias]
+    if select is not None:
+        inputs["Select"] = [select]
     attrs = {"dropout_prob": float(dropout_prob), "is_test": is_test}
     if scale is not None:
         attrs["scale"] = float(scale)
@@ -1628,6 +1637,24 @@ def fused_attention(q, k, v, attn_bias=None, scale=None, dropout_prob=0.0,
         attrs["num_kv_heads"] = int(num_kv_heads)
     helper.append_op(type="fused_multihead_attention", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def sparse_index(q, k, w, topk, chunk_size=512, name=None):
+    """A learned sparse attention's selection (ops/sparse_attention.py):
+    from the indexer's queries ``q`` [B, Hi, S, di], its one shared key
+    head ``k`` [B, S, di] and its head weights ``w`` [B, S, Hi], the mask
+    [B, S, S] int8 of the ``min(t + 1, topk)`` keys ``s <= t`` of largest
+    ``sum_j w[t, j] relu(q[t, j] . k[s])`` a query ``t`` (every key tied
+    with the ``topk``-th too). No gradient flows through it."""
+    helper = LayerHelper("sparse_index", **locals())
+    out = helper.create_variable_for_type_inference("int8",
+                                                    stop_gradient=True)
+    out.shape = (int(q.shape[0]), int(q.shape[2]), int(q.shape[2]))
+    helper.append_op(type="sparse_index",
+                     inputs={"Q": [q], "K": [k], "W": [w]},
+                     outputs={"Select": [out]},
+                     attrs={"topk": int(topk), "chunk_size": int(chunk_size)})
     return out
 
 
@@ -1731,15 +1758,29 @@ def swiglu(x, y, name=None):
     return out
 
 
-def rotary_embedding(x, rotary_dim=None, theta=10000.0, name=None):
+def rotary_embedding(x, rotary_dim=None, theta=10000.0, positions=None,
+                     mrope_section=None, name=None):
     """Rotate-half rotary embedding on the first ``rotary_dim`` of the
-    head dim of ``x`` [B, H, S, d]; row ``s`` sits at position ``s``."""
+    head dim of ``x`` [B, H, S, d]. Without ``positions`` row ``s`` sits at
+    position ``s``. ``positions`` [B, S] (integer) gives every row its own
+    position. ``positions`` [3, B, S] with ``mrope_section`` = three counts
+    of frequency pairs adding up to ``rotary_dim / 2`` is the rotary
+    embedding in sections of a vision-language model: the first count of
+    pairs turns by the first row's positions (time), the next by the
+    second's (height), the rest by the third's (width); for text the three
+    rows are equal and the result is the plain embedding's, bit for bit."""
     helper = LayerHelper("rotary_embedding", **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
-    helper.append_op(type="rotary_embedding", inputs={"X": [x]},
-                     outputs={"Out": [out]},
-                     attrs={"rotary_dim": int(rotary_dim or x.shape[-1]),
-                            "theta": float(theta)})
+    inputs = {"X": [x]}
+    attrs = {"rotary_dim": int(rotary_dim or x.shape[-1]),
+             "theta": float(theta)}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    if mrope_section is not None:
+        assert positions is not None, "mrope_section needs positions"
+        attrs["mrope_section"] = [int(n) for n in mrope_section]
+    helper.append_op(type="rotary_embedding", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -1801,11 +1842,13 @@ def moe_route(input, num_experts, k, norm_topk_prob=True, param_attr=None,
 
 def moe_experts(input, topk_ids, topk_weights, experts_held, expert_width,
                 expert_offset=0, gate_attr=None, up_attr=None, down_attr=None,
-                name=None):
+                name=None, experts_total=None):
     """The part of a sparse-expert layer's result that the
     ``experts_held`` experts from ``expert_offset`` on give (SwiGLU
     experts of width ``expert_width``, no biases), dropless (ops/
-    moe_ops.py). What absent experts would add is left out."""
+    moe_ops.py). What absent experts would add is left out.
+    ``experts_total`` (the router's width) lets the op size its walk's
+    chunk as the whole number of token counts next above an even load."""
     helper = LayerHelper("moe_experts", **locals())
     h, E, f = int(input.shape[-1]), int(experts_held), int(expert_width)
     dtype = _data_type(input)
@@ -1819,5 +1862,6 @@ def moe_experts(input, topk_ids, topk_weights, experts_held, expert_width,
                 "TopkWeights": [topk_weights], "WGate": [wg], "WUp": [wu],
                 "WDown": [wd]},
         outputs={"Out": [out]},
-        attrs={"expert_offset": int(expert_offset)})
+        attrs={"expert_offset": int(expert_offset),
+               "experts_total": int(experts_total or 0)})
     return out

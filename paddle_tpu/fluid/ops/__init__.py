@@ -25,6 +25,7 @@ from . import (  # noqa: F401
     quant_ops,
     rnn_ops,
     sequence_ops,
+    sparse_attention,
     structured_loss_ops,
     tensor_ops,
 )

@@ -182,8 +182,15 @@ def _moe_experts(ctx, op):
     WGate, WUp [E_held, h, f], WDown [E_held, f, h] -> Out [..., h]: the
     held experts' part of the layer's result. ``expert_offset``: the
     first held expert's index among all. The sorted (token, choice) pairs
-    are walked a token count's rows at a time: ``k`` chunks at most, and
-    one where the load is even."""
+    are walked a chunk at a time, and the chunk is the whole number of
+    token counts next ABOVE an even load: with ``experts_total`` (the
+    router's width) the held experts get ``k * E_held / experts_total``
+    pairs a token at even routing, so the chunk is ``k * E_held //
+    experts_total + 1`` token counts (32 of 512 under top-10: 0.625 pairs
+    a token, one token count; 16 of 128 under top-8: 1.0, two - a chunk
+    of one would leave a load within a percent of even AT its edge, one
+    chunk or two from step to step). One rule for every caller; without
+    ``experts_total`` a token count. A load near even takes one chunk."""
     x = ctx.get_input(op, "X")
     ids = ctx.get_input(op, "TopkIds")
     wts = ctx.get_input(op, "TopkWeights")
@@ -191,9 +198,12 @@ def _moe_experts(ctx, op):
     x2 = x.reshape(-1, h)
     k = ids.shape[-1]
     _count("ragged_loop")
+    T, E = x2.shape[0], ctx.get_input(op, "WGate").shape[0]
+    total = int(op.attr("experts_total", 0) or 0)
+    chunk_rows = T * (k * E // total + 1 if total else 1)
     out = moe_experts_dropless(
         x2, ids.reshape(-1, k), wts.reshape(-1, k),
         ctx.get_input(op, "WGate"), ctx.get_input(op, "WUp"),
         ctx.get_input(op, "WDown"), int(op.attr("expert_offset", 0)),
-        chunk_rows=x2.shape[0])
+        chunk_rows=chunk_rows)
     ctx.set_output(op, "Out", out.astype(x.dtype).reshape(lead + (h,)))

@@ -410,8 +410,13 @@ def _swiglu(ctx, op):
 def _rotary_embedding(ctx, op):
     """Rotate-half rotary position embedding on the first ``rotary_dim``
     of X's last axis (partial rotary: the rest passes through). X is
-    [B, H, S, d]; the position of row ``s`` is ``s``. Angles in f32, Out
-    in X's dtype."""
+    [B, H, S, d]. Without ``Positions`` the position of row ``s`` is
+    ``s``. ``Positions`` [B, S] gives each row's own; ``Positions``
+    [3, B, S] with ``mrope_section`` (three counts of frequency pairs that
+    add up to ``rotary_dim / 2``) gives the first count of pairs the first
+    row's positions, the next the second's, the rest the third's (text:
+    three equal rows, which is the plain embedding). Angles in f32, Out in
+    X's dtype."""
     import jax.numpy as jnp
 
     x = ctx.get_input(op, "X")
@@ -420,8 +425,21 @@ def _rotary_embedding(ctx, op):
     assert rd % 2 == 0 and rd <= d, (rd, d)
     inv = 1.0 / (float(op.attr("theta", 10000.0))
                  ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
-    ang = (jnp.arange(S, dtype=jnp.float32)[:, None] * inv)[None, None]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)           # [1, 1, S, rd/2]
+    pos = ctx.get_input(op, "Positions")
+    sections = op.attr("mrope_section", None)
+    if pos is None:
+        ang = (jnp.arange(S, dtype=jnp.float32)[:, None] * inv)[None, None]
+    else:
+        ang = pos.astype(jnp.float32)[..., None] * inv   # [(3,) B, S, rd/2]
+        if sections:
+            assert pos.ndim == 3 and pos.shape[0] == 3 and \
+                sum(sections) == rd // 2, (pos.shape, sections, rd)
+            pair = jnp.arange(rd // 2)
+            ang = jnp.where(pair < sections[0], ang[0], jnp.where(
+                pair < sections[0] + sections[1], ang[1], ang[2]))
+        assert ang.ndim == 3, (pos.shape, sections)
+        ang = ang[:, None]                               # [B, 1, S, rd/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :rd // 2], xf[..., rd // 2:rd]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
@@ -709,19 +727,22 @@ def _fused_multihead_attention(ctx, op):
     drop = 0.0 if is_test else p
     key = ctx.next_rng() if drop > 0.0 else None
     kv_heads = int(op.attr("num_kv_heads", 0) or 0)
+    select = ctx.get_input(op, "Select")
     if kv_heads and kv_heads != q.shape[1]:
         # grouped-query attention: K/V arrive [B, Hkv, S, d] and each KV
         # head serves H/Hkv consecutive Q heads; repeated to the Q head
-        # count before the kernel (autodiff sums the copies' gradients)
-        import jax.numpy as jnp
-
+        # count before the kernel (autodiff sums the copies' gradients),
+        # but for the select tier, which takes K/V at their own head count
         assert k.shape[1] == kv_heads and q.shape[1] % kv_heads == 0, (
             q.shape, k.shape, kv_heads)
-        k = jnp.repeat(k, q.shape[1] // kv_heads, axis=1)
-        v = jnp.repeat(v, q.shape[1] // kv_heads, axis=1)
+        if select is None:
+            import jax.numpy as jnp
+
+            k = jnp.repeat(k, q.shape[1] // kv_heads, axis=1)
+            v = jnp.repeat(v, q.shape[1] // kv_heads, axis=1)
     ctx.set_output(op, "Out", fused_attention(
         q, k, v, bias, scale=scale, dropout_prob=drop, rng_key=key,
-        causal=bool(op.attr("causal", False))))
+        causal=bool(op.attr("causal", False)), select=select))
 
 
 @register("fused_multihead_attention_packed", has_state=True)

@@ -307,9 +307,27 @@ def _module_name(event_name):
     return re.sub(r"\(\d+\)$", "", event_name)
 
 
+_CUSTOM_CALL_OPERANDS = re.compile(r"\bcustom-call\(([^)]*)\)")
+_OPERAND = re.compile(r"%([^\s,)]+)")
+
+
 def op_names_of(hlo_text):
-    """``{instruction name: op_name}`` of a compiled module's HLO text."""
-    return dict(_HLO_OP_NAME.findall(hlo_text))
+    """``{instruction name: op_name}`` of a compiled module's HLO text.
+    A kernel that the compiler put in a program op's place (the TPU's
+    ``lax.ragged_dot``: a ``custom-call`` whose ``op_name`` is the
+    compiler's own, ``ragged-dot-none``, with no scope in it) takes the
+    ``op_name`` of its first operand that has a scope: what feeds it was
+    lowered under the same program op."""
+    names = dict(_HLO_OP_NAME.findall(hlo_text))
+    for name, rest in _HLO_LINE.findall(hlo_text):
+        if "/" in names.get(name, "/"):
+            continue
+        call = _CUSTOM_CALL_OPERANDS.search(rest)
+        for operand in _OPERAND.findall(call.group(1)) if call else ():
+            if "/" in names.get(operand, ""):
+                names[name] = names[operand]
+                break
+    return names
 
 
 # The newest compiled step, kept as what its HLO text can be made from

@@ -57,6 +57,22 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       the jaxpr it always did. Fewer KV than Q heads: the
       ``fused_multihead_attention`` op repeats K/V to the Q head count
       before the kernel (``num_kv_heads``).
+  ``select`` given ([B, S, S] integer, nonzero = the query may see the
+      key: a learned sparse attention's set of keys a query, one for all
+      heads of a batch row, no gradient; ``sparse_index`` makes it) —
+      select tier (_select_*) under ``causal``, whatever S a 128-row
+      tile divides (tile 1024 / 512 / 256 / 128, the largest that
+      divides S; no bias, no dropout) with at most 8 query heads a K/V
+      head: the flash tier's online softmax and split backward with the
+      selection's tile added to each score tile as 0 / -1e30, the causal
+      tile skipping kept. A grid step holds a K/V head's H / Hkv query
+      heads: the selection's tile and the K/V tile are fetched once a
+      step and serve them all, K/V stay at their own head count, dk/dv
+      sum the group's heads inside. Elsewhere (not ``causal``, no tile
+      divides S, a wider group, off the chip without the interpreter)
+      the selection is a [B, 1, S, S] additive bias on the fallback
+      below, which is the kernels' oracle. Without ``select`` nothing
+      here is reached.
 
 Packed layout (``fused_attention_packed``, FORCE=packed): q/k/v stay in
 the fc-native [B, S, H*d] layout with heads handled inside the kernel;
@@ -131,7 +147,7 @@ _MAX_FUSED_SEQ = 1024
 
 
 KERNEL_TIERS = ("block", "block_bwd", "long", "long_bwd", "flash",
-                "flash_bwd", "decode", "paged")
+                "flash_bwd", "select", "decode", "paged")
 
 
 # The name of every ``pl.pallas_call`` here, one a site: the device
@@ -140,6 +156,7 @@ KERNEL_TIERS = ("block", "block_bwd", "long", "long_bwd", "flash",
 KERNEL_NAMES = (
     "attn_block_fwd", "attn_block_bwd", "attn_long_fwd", "attn_long_bwd",
     "attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv",
+    "attn_select_fwd", "attn_select_bwd_dq", "attn_select_bwd_dkv",
     "attn_packed_fwd", "attn_packed_bwd",
     "attn_res_fwd", "attn_res_bwd_dq", "attn_res_bwd_dkv",
     "attn_decode", "attn_paged")
@@ -984,6 +1001,337 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
     return dq, dk, dv, dbias
 
 
+# ---------------------------------------------------------------------------
+# Select tier: attention under a per-(query, key) selection.
+#
+# ``select`` [B, S, S] (int8, nonzero = query t may see key s) is one set a
+# (batch row, query), shared by all heads and not differentiable: a learned
+# sparse attention's indexer makes it (``fluid/ops/sparse_attention.py``).
+# The kernels are the flash tier's online softmax and split backward with
+# the selection added to each score tile as 0 / -1e30. A grid step holds
+# the G = H / Hkv query heads of ONE K/V head: the selection's tile, its
+# 0 / -1e30 form and the K/V tile are fetched and made once a step and
+# serve the group, so the selection is read Hkv times a call, not H
+# times, and K and V are never repeated to the Q head count. Causal only
+# (a learned selection picks among the keys before the query): the tiles
+# wholly above the diagonal are skipped as in the flash tier
+# (``_flash_ktile``), and column > row is masked beside the selection.
+# No bias and no dropout (a caller with either gets an error).
+# ---------------------------------------------------------------------------
+# Largest first: measured v5e B=1, H=32, Hkv=4, S=16384, d=128 (PR 32), ms a
+# call forward / dq / dk-dv: 17.4 / 20.0 / 25.9 at Tb=1024, 29.8 / 21.5 / 31.5
+# at 512 (the flash tier without a selection, K/V repeated: 18.4 / 22.4 / 27.4).
+_SELECT_BLOCK_CANDIDATES = (1024, 512, 256, 128)
+_SELECT_MAX_GROUP = 8       # what the VMEM limit below was sized for
+
+# At Tb=1024 and G=8 a step holds ~45 MB (a group's q / o / do blocks and
+# row statistics double-buffered, the f32 carries, a few [Tb, Tb] f32
+# tiles): over Mosaic's 16 MB default and the flash tier's 32; the v5e
+# compiler takes the cell's shape at 48. The chip has 128 MiB of VMEM.
+_SELECT_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _for_heads(G, body):
+    """``body(g)`` for each of a step's G heads, one after the other (a
+    ``fori_loop``: unrolled, the kernels read 0.8 ms of 65 faster a call
+    and compile in 18 s, not 2)."""
+    jax.lax.fori_loop(0, G, lambda g, c: (body(g), c)[1], 0)
+
+
+def _select_block(S):
+    for tb in _SELECT_BLOCK_CANDIDATES:
+        if S % tb == 0:
+            return tb
+    return None
+
+
+def _select_group(H, Hkv):
+    """G, the query heads of a K/V head (a grid step's heads), or None
+    where the heads do not divide or the group is wider than the kernels'
+    VMEM limit was sized for."""
+    if Hkv <= 0 or H % Hkv or H // Hkv > _SELECT_MAX_GROUP:
+        return None
+    return H // Hkv
+
+
+def _use_select_kernel(q, k, causal):
+    return (causal and _supports_pallas()
+            and _select_block(q.shape[2]) is not None
+            and _select_group(q.shape[1], k.shape[1]) is not None)
+
+
+def count_select_pairs(S, topk, sites=1):
+    """Trace-time record, once a traced selection site, of the (query,
+    key) pairs a (batch row, head) that a top-``topk`` selection keeps and
+    of the causal pairs it chose from."""
+    from ..fluid import monitor as _monitor
+
+    k = min(int(topk), S)
+    kept = k * (k + 1) // 2 + (S - k) * k
+    for kind, n in (("kept", kept), ("causal", S * (S + 1) // 2)):
+        _monitor.counter(
+            "attn_select_pairs_total",
+            "(query, key) pairs a (batch row, head) under a learned top-k "
+            "selection: kept, and the causal pairs chosen from "
+            "(trace-time: once a traced selection site, not per step)",
+            labels={"kind": kind}).inc(n * sites)
+
+
+def _select_neg(sel_ref, neg_scr, qi, j):
+    """The step's selection tile as what is added to a score: 0 where the
+    query may see the key (selected, and column <= row), else -1e30; made
+    once, read by every head."""
+    keep = sel_ref[0].astype(jnp.int32) != 0
+    tb = keep.shape[0]
+    rows = qi * tb + jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)
+    cols = j * tb + jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
+    keep = jnp.logical_and(keep, cols <= rows)
+    neg_scr[...] = jnp.where(keep, 0.0, -1e30).astype(jnp.float32)
+
+
+def _select_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
+                       neg_scr, acc_scr, m_scr, l_scr, *, scale, G, nk):
+    """Grid (B, H/G, nq, nk), k-tile fastest: the flash forward for each
+    of the step's G heads in turn. m starts at -1e29, above a masked
+    score: a row that has seen no selected key yet keeps p = exp(-1e30 +
+    1e29) = 0 and l = 0, and the first selected key's corr = exp(-1e29 -
+    m) = 0 starts it."""
+    j, qi = _flash_ktile(nk, True)
+
+    @pl.when(j >= 0)
+    def _tile():
+        _select_neg(sel_ref, neg_scr, qi, j)
+
+        @pl.when(j == 0)
+        def _init():
+            m_scr[...] = jnp.full(m_scr.shape, -1e29, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        def head(g):
+            q, k, v = q_ref[0, g], k_ref[0, 0], v_ref[0, 0]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = s + neg_scr[...]
+            m_prev = m_scr[g]                             # [Tb, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[g] = l_scr[g] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[g] = acc_scr[g] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[g] = m_new
+
+        _for_heads(G, head)
+
+    @pl.when(j == qi)
+    def _emit():
+        def head(g):
+            l = l_scr[g]
+            o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
+            lse_ref[0, g] = m_scr[g] + jnp.log(l)
+
+        _for_heads(G, head)
+
+
+def _select_dq_kernel(q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref, dd_ref,
+                      dq_ref, neg_scr, *, scale, G, nk):
+    """Grid (B, H/G, nq, nk), k-tile fastest: dq of the step's G heads,
+    accumulated over the k-tile sweep; p = exp(s - L) from the saved
+    logsumexp, 0 where the selection masks."""
+    j, qi = _flash_ktile(nk, True)
+
+    @pl.when(j >= 0)
+    def _tile():
+        _select_neg(sel_ref, neg_scr, qi, j)
+
+        @pl.when(j == 0)
+        def _init():
+            dq_ref[...] = jnp.zeros(dq_ref.shape, dq_ref.dtype)
+
+        def head(g):
+            q, k, v, do = q_ref[0, g], k_ref[0, 0], v_ref[0, 0], do_ref[0, g]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            p = jnp.exp(s + neg_scr[...] - lse_ref[0, g])
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * dp - p * dd_ref[0, g]
+            dq_ref[0, g] += jax.lax.dot_general(
+                ds.astype(q.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+
+        _for_heads(G, head)
+
+
+def _select_dkdv_kernel(q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref,
+                        dd_ref, dk_ref, dv_ref, neg_scr, *, scale, G):
+    """Grid (B, Hkv, nk, nq), q-tile fastest: dk and dv of the step's K/V
+    head, accumulated over the q-tile sweep and over its G query heads
+    (f32, [B, Hkv, S, d]: no repeated K/V to sum back). The sweep's first
+    j steps, above the diagonal, are skipped."""
+    j, i = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(i >= j)
+    def _tile():
+        _select_neg(sel_ref, neg_scr, i, j)
+
+        @pl.when(i == j)
+        def _init():
+            dk_ref[...] = jnp.zeros(dk_ref.shape, dk_ref.dtype)
+            dv_ref[...] = jnp.zeros(dv_ref.shape, dv_ref.dtype)
+
+        def head(g):
+            q, k, v, do = q_ref[0, g], k_ref[0, 0], v_ref[0, 0], do_ref[0, g]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            p = jnp.exp(s + neg_scr[...] - lse_ref[0, g])
+            lp = q.dtype
+            dv_ref[0, 0] += jax.lax.dot_general(
+                p.astype(lp), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * dp - p * dd_ref[0, g]
+            dk_ref[0, 0] += jax.lax.dot_general(
+                ds.astype(lp), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+
+        _for_heads(G, head)
+
+
+def _select_specs(q, k, kfast=True):
+    """Block specs of the select kernels' grid ``(B, Hkv, slow, fast)``,
+    with ``_flash_specs``' treatment of the steps that have no tile."""
+    B, H, S, d = q.shape
+    G = _select_group(H, k.shape[1])
+    TB = _select_block(S)
+    nt = S // TB
+
+    def tiles(*g):      # grid indices -> (b, K/V head, q-tile, k-tile)
+        b, h, i, j = g if kfast else (g[0], g[1], g[3], g[2])
+        if kfast:
+            j = jnp.maximum(j - (nt - 1 - i), 0)
+        else:
+            i = jnp.maximum(i, j)
+        return b, h, i, j
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda *g: index(*tiles(*g)))
+
+    qspec = spec((1, G, TB, d), lambda b, h, i, j: (b, h, i, 0))
+    kspec = spec((1, 1, TB, d), lambda b, h, i, j: (b, h, j, 0))
+    sspec = spec((1, TB, TB), lambda b, h, i, j: (b, i, j))
+    rowspec = spec((1, G, TB, 1), lambda b, h, i, j: (b, h, i, 0))
+    return G, TB, nt, qspec, kspec, sspec, rowspec
+
+
+def _pallas_attention_select(q, k, v, select, scale):
+    """Returns (o, lse); k, v at their own head count [B, Hkv, S, d]."""
+    _count_kernel("select")
+    B, H, S, d = q.shape
+    G, TB, nt, qspec, kspec, sspec, rowspec = _select_specs(q, k)
+    f32 = jnp.float32
+    return _kernel_call(
+        "attn_select_fwd",
+        functools.partial(_select_fwd_kernel, scale=scale, G=G, nk=nt),
+        grid=(B, H // G, nt, nt),
+        in_specs=[qspec, kspec, kspec, sspec],
+        out_specs=[qspec, rowspec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((B, H, S, 1), f32)],
+        scratch_shapes=[pltpu.VMEM((TB, TB), f32),
+                        pltpu.VMEM((G, TB, d), f32),
+                        pltpu.VMEM((G, TB, 1), f32),
+                        pltpu.VMEM((G, TB, 1), f32)],
+        compiler_params=_SELECT_COMPILER_PARAMS,
+    )(q, k, v, select)
+
+
+def _pallas_attention_select_bwd(q, k, v, select, do, o, lse, scale):
+    _count_kernel("select")     # dq
+    _count_kernel("select")     # dk/dv
+    B, H, S, d = q.shape
+    G, TB, nt, qspec, kspec, sspec, rowspec = _select_specs(q, k)
+    f32 = jnp.float32
+    dd = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1, keepdims=True)
+    neg = pltpu.VMEM((TB, TB), f32)
+    dq = _kernel_call(
+        "attn_select_bwd_dq",
+        functools.partial(_select_dq_kernel, scale=scale, G=G, nk=nt),
+        grid=(B, H // G, nt, nt),
+        in_specs=[qspec, kspec, kspec, sspec, qspec, rowspec, rowspec],
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, f32),
+        scratch_shapes=[neg],
+        compiler_params=_SELECT_COMPILER_PARAMS,
+    )(q, k, v, select, do, lse, dd)
+    _, _, _, qspec_t, kspec_t, sspec_t, rowspec_t = _select_specs(
+        q, k, kfast=False)
+    dk, dv = _kernel_call(
+        "attn_select_bwd_dkv",
+        functools.partial(_select_dkdv_kernel, scale=scale, G=G),
+        grid=(B, H // G, nt, nt),
+        in_specs=[qspec_t, kspec_t, kspec_t, sspec_t, qspec_t, rowspec_t,
+                  rowspec_t],
+        out_specs=[kspec_t, kspec_t],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, f32),
+                   jax.ShapeDtypeStruct(k.shape, f32)],
+        scratch_shapes=[neg],
+        compiler_params=_SELECT_COMPILER_PARAMS,
+    )(q, k, v, select, do, lse, dd)
+    return dq, dk, dv
+
+
+def _select_fallback(q, k, v, select, scale, causal):
+    """Off the kernels: the selection as a [B, 1, S, S] additive bias on
+    ``_ref_attention`` / ``_blockwise_attention`` (K/V repeated to the Q
+    head count) - the kernels' oracle."""
+    rep = q.shape[1] // k.shape[1]
+    if rep > 1:
+        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    bias = jnp.where(select != 0, 0.0, -1e30).astype(jnp.float32)[:, None]
+    return _fallback_attention(q, k, v, bias, scale, 0.0, None, causal)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _selected(q, k, v, select, scale, causal):
+    if _use_select_kernel(q, k, causal):
+        return _pallas_attention_select(q, k, v, select, scale)[0]
+    return _select_fallback(q, k, v, select, scale, causal)
+
+
+def _selected_fwd(q, k, v, select, scale, causal):
+    if _use_select_kernel(q, k, causal):
+        o, lse = _pallas_attention_select(q, k, v, select, scale)
+        return o, (q, k, v, select, (o, lse))
+    return (_select_fallback(q, k, v, select, scale, causal),
+            (q, k, v, select, None))
+
+
+def _selected_bwd(scale, causal, res, do):
+    q, k, v, select, kernel_res = res
+    if kernel_res is not None:
+        o, lse = kernel_res
+        dq, dk, dv = _pallas_attention_select_bwd(
+            q, k, v, select, do, o, lse, scale)
+        dq, dk, dv = (dq.astype(q.dtype), dk.astype(k.dtype),
+                      dv.astype(v.dtype))
+    else:
+        _, vjp = jax.vjp(lambda q_, k_, v_: _select_fallback(
+            q_, k_, v_, select, scale, causal), q, k, v)
+        dq, dk, dv = vjp(do)
+    return dq, dk, dv, np.zeros(select.shape, dtype=jax.dtypes.float0)
+
+
+_selected.defvjp(_selected_fwd, _selected_bwd)
+
+
 _PACKED_MAX_SEQ = 256  # past this even hc=1 chunks overflow the temp
                        # budget (22 live [S, S] f32 tiles, _packed_hc)
 
@@ -1658,15 +2006,28 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
-                    rng_key=None, causal=False):
+                    rng_key=None, causal=False, select=None):
     """softmax(q·kᵀ·scale + bias)·v fused per (batch, head).
 
     q/k/v: [B, H, S, d]; bias broadcastable [B, 1|H, 1|S, S] additive
     (0 keep / -1e4 mask); returns [B, H, S, d] in q's dtype. ``causal``
     masks column > row inside the kernels, by index: no [S, S] bias, so
     the flash tier (row-broadcast bias only) stays open to it.
+    ``select`` [B, S, S] (integer, nonzero = the query may see the key;
+    shared by all heads, no gradient) takes the select tier: k and v then
+    come at their OWN head count [B, Hkv, S, d], and neither a bias nor
+    dropout goes with it.
     """
     B, H, S, d = q.shape
+    if select is not None:
+        if bias is not None or dropout_prob:
+            raise NotImplementedError(
+                "fused_attention: select goes with neither a bias nor "
+                "dropout")
+        assert select.shape == (B, S, S), (select.shape, q.shape)
+        return _selected(q, k, v, select,
+                         float(scale) if scale else 1.0 / math.sqrt(d),
+                         bool(causal))
     scale, bias, seed = _prep_bias_seed(B, S, d, bias, scale,
                                         dropout_prob, rng_key)
     return _fused(q, k, v, bias, scale, float(dropout_prob), seed,

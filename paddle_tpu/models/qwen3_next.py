@@ -21,7 +21,12 @@ head in an attention layer's query projection.
 """
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu.fluid import layers, optimizer
+from paddle_tpu.fluid import layers
+
+from . import decoder_blocks
+from .decoder_blocks import attr as _attr
+from .decoder_blocks import proj as _proj
+from .decoder_blocks import rms as _rms
 
 
 class Qwen3NextConfig:
@@ -68,24 +73,6 @@ class Qwen3NextConfig:
 
     def is_full_attention(self, i):
         return (i + 1) % self.full_attention_interval == 0
-
-
-def _attr(name, cfg):
-    return fluid.ParamAttr(
-        name=name,
-        initializer=fluid.initializer.Normal(0.0, cfg.initializer_range))
-
-
-def _proj(x, size, name, cfg):
-    """A bias-free projection of the last axis of ``x`` [B, S, *]."""
-    return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_attr(name + "_w", cfg), name=name)
-
-
-def _rms(x, name, cfg, zero_centered=True):
-    return layers.rms_norm(x, epsilon=cfg.rms_norm_eps,
-                           zero_centered=zero_centered,
-                           param_attr=fluid.ParamAttr(name=name), name=name)
 
 
 def _gated_delta_net(x, cfg, p):
@@ -144,15 +131,7 @@ def _gated_attention(x, cfg, p):
 
 
 def _moe(x, cfg, p):
-    ids, wts = layers.moe_route(
-        x, cfg.num_experts_total, cfg.num_experts_per_tok,
-        norm_topk_prob=cfg.norm_topk_prob,
-        param_attr=_attr(p + "_router_w", cfg), name=p + "_route")
-    routed = layers.moe_experts(
-        x, ids, wts, cfg.num_experts, cfg.moe_intermediate_size,
-        expert_offset=cfg.expert_offset,
-        gate_attr=_attr(p + "_gate_w", cfg), up_attr=_attr(p + "_up_w", cfg),
-        down_attr=_attr(p + "_down_w", cfg), name=p + "_experts")
+    routed = decoder_blocks.routed_experts(x, cfg, p)
     f = cfg.shared_expert_intermediate_size
     shared = _proj(layers.swiglu(_proj(x, f, p + "_shared_gate", cfg),
                                  _proj(x, f, p + "_shared_up", cfg),
@@ -188,31 +167,7 @@ def decoder(tokens, cfg):
 
 def build_train_program(cfg, batch, seq_len, lr=1e-4, use_amp=True,
                         recompute=False, seed=7):
-    """Next-token cross-entropy over every position of ``tokens`` /
-    ``labels`` [batch, seq_len] (the caller shifts), Adam, AMP bf16 over
-    float32 masters where ``use_amp``, and with ``recompute`` the
-    ``RecomputeOptimizer``'s checkpoints at the layer boundaries.
-    Returns ``(main, startup, loss)``."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = seed
-    with fluid.program_guard(main, startup):
-        tokens = layers.data("tokens", shape=[batch, seq_len], dtype="int64",
-                             append_batch_size=False)
-        labels = layers.data("labels", shape=[batch, seq_len], dtype="int64",
-                             append_batch_size=False)
-        hidden, boundaries = decoder(tokens, cfg)
-        logits = _proj(hidden, cfg.vocab_size, "lm_head", cfg)
-        ce = layers.softmax_with_cross_entropy(
-            layers.reshape(logits, [-1, cfg.vocab_size]),
-            layers.reshape(labels, [-1, 1]))
-        loss = layers.mean(ce)
-        opt = optimizer.Adam(learning_rate=lr)
-        if recompute:
-            opt = optimizer.RecomputeOptimizer(opt)
-            opt._set_checkpoints(boundaries)
-        if use_amp:
-            from ..fluid.contrib import mixed_precision
-
-            opt = mixed_precision.decorate(opt)
-        opt.minimize(loss)
-    return main, startup, loss
+    """``decoder_blocks.build_train_program`` round ``decoder``."""
+    return decoder_blocks.build_train_program(
+        decoder, cfg, batch, seq_len, lr=lr, use_amp=use_amp,
+        recompute=recompute, seed=seed)

@@ -1,5 +1,6 @@
-"""The delta rule's kernels and the causal flash attention's at the
-benchmark cell's shape, compiled for a DESCRIBED v5e (no chip attached):
+"""The delta rule's kernels, the causal flash attention's, and the select
+tier's with its selection op, each at its benchmark cell's shape, compiled
+for a DESCRIBED v5e (no chip attached):
 what the chip's compiler refuses - a slice off the tiling, too much
 VMEM, an op Mosaic cannot lower - it refuses here, at no chip time.
 Nothing runs, so nothing is said about results or speed. The topology is
@@ -32,8 +33,8 @@ def one_chip():
 @pytest.fixture
 def compiled_text(one_chip, monkeypatch):
     """``fn -> HLO text`` of ``fn`` over the cell's q, k, v, gc, beta (or
-    over ``shapes``, bfloat16), compiled for the chip with the kernels NOT
-    interpreted."""
+    over ``shapes``, bfloat16 or ``(shape, dtype)``), compiled for the chip
+    with the kernels NOT interpreted."""
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
@@ -51,7 +52,8 @@ def compiled_text(one_chip, monkeypatch):
             spec((B, HV, S // 128, 128), jnp.float32))
     yield lambda fn, shapes=None: jax.jit(fn).lower(*(
         args if shapes is None else
-        [spec(s, jnp.bfloat16) for s in shapes])).compile().as_text()
+        [spec(*(s if isinstance(s[0], tuple) else (s, jnp.bfloat16)))
+         for s in shapes])).compile().as_text()
     jax.config.update("jax_enable_compilation_cache", True)
 
 
@@ -87,3 +89,40 @@ def test_causal_flash_kernels_compile_for_v5e_at_the_cells_shape(
             argnums=(0, 1, 2)), [(B, HA, S, DA)] * 3)
     for name in ("attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv"):
         assert name in text
+
+
+# keye-vl-2.0-30b-a3b.train-s16384: batch, Q heads, KV heads, sequence, head
+# dim; the indexer's heads, head dim and topk
+KB, KH, KHKV, KS, KD, KHI, KDI, KTOPK = 1, 32, 4, 16384, 128, 16, 64, 2048
+
+
+def test_select_kernels_compile_for_v5e_at_the_cells_shape(compiled_text,
+                                                           monkeypatch):
+    """Forward, dq and dk/dv under a selection: a group of 8 heads a grid
+    step at tile 1024 fits the kernels' VMEM limit, and the dynamic head
+    index and the int8 selection tile pass Mosaic."""
+    from paddle_tpu.kernels import attention as A
+
+    monkeypatch.setattr(A, "_supports_pallas", lambda: True)
+    text = compiled_text(
+        jax.grad(lambda q, k, v, sel: jnp.sum(A.fused_attention(
+            q, k, v, scale=KD ** -0.5, causal=True,
+            select=sel).astype(jnp.float32)), argnums=(0, 1, 2)),
+        [(KB, KH, KS, KD), (KB, KHKV, KS, KD), (KB, KHKV, KS, KD),
+         ((KB, KS, KS), jnp.int8)])
+    for name in ("attn_select_fwd", "attn_select_bwd_dq",
+                 "attn_select_bwd_dkv"):
+        assert name in text
+    assert A._select_block(KS) == 1024
+    assert A._select_group(KH, KHKV) == 8
+
+
+def test_sparse_index_compiles_for_v5e_at_the_cells_shape(compiled_text):
+    """The selection of one layer, chunked: what it holds beside its
+    [S, S] byte mask stays far under one [S, S] float32 (1.07e9 B)."""
+    from paddle_tpu.fluid.ops.sparse_attention import sparse_index_select
+
+    text = compiled_text(
+        lambda q, k, w: sparse_index_select(q, k, w, KTOPK, 512),
+        [(KB, KHI, KS, KDI), (KB, KS, KDI), (KB, KS, KHI)])
+    assert "s8[1,16384,16384]" in text

@@ -140,8 +140,13 @@ def test_qat_freeze_roundtrip_and_int8():
                   for n in main.global_block().vars
                   if getattr(main.global_block().vars[n], "persistable",
                              False) and scope.find_var(n) is not None}
-        wnames = sorted(n for n in params if n.endswith(".w_0"))
-        bnames = sorted(n for n in params if n.endswith(".b_0"))
+        # in the order the layers were made: fc_9 before fc_10 (the names'
+        # counter is the process's, so it depends on the tests before)
+        by_number = lambda n: (len(n), n)       # noqa: E731
+        wnames = sorted((n for n in params if n.endswith(".w_0")),
+                        key=by_number)
+        bnames = sorted((n for n in params if n.endswith(".b_0")),
+                        key=by_number)
         h = np.maximum(X @ qd(params[wnames[0]]) + params[bnames[0]], 0)
         ref = h @ qd(params[wnames[1]]) + params[bnames[1]]
 

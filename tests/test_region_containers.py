@@ -82,3 +82,35 @@ def test_newest_step_regions_holds_no_container(monkeypatch):
     # a step that cannot be lowered again (a disk-tier wrapper)
     profiler.note_compiled_step(object(), ())
     assert profiler.newest_step_regions() is None
+
+
+# What the v5e's compiler makes of ``lax.ragged_dot`` inside ``moe_experts``
+# (a compiled keye step's lines, shapes and payloads cut): its own kernel,
+# whose ``op_name`` is the compiler's and names no program op.
+RAGGED_TEXT = """
+ENTRY %main {
+  %select_multiply_fusion.12 = bf16[512,256]{1,0} fusion(%p.1, %p.2), kind=kLoop, metadata={op_name="jit(train_step)/layer_0_moe_experts/mul"}
+  %convert_element_type.40 = bf16[4,256,128]{2,1,0} convert(%p.3), metadata={op_name="jit(train_step)/layer_0_cast/convert_element_type"}
+  %ragged-dot-metadata.8 = (s32[5]{0}, s32[9]{0}, s32[9]{0}, s32[1]{0}) custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %fusion.3 = s32[4]{0} fusion(%p.4), kind=kLoop, metadata={op_name="jit(train_step)/autodiff/transpose(jvp(layer_0_moe_experts))/sub"}
+  %get-tuple-element.5 = s32[1]{0} get-tuple-element(%ragged-dot-metadata.8), index=3
+  %ragged-dot-none.48 = f32[512,128]{1,0} custom-call(%get-tuple-element.5, %get-tuple-element.5, /*index=5*/%select_multiply_fusion.12, %convert_element_type.40), custom_call_target="tpu_custom_call", frontend_attributes={mosaic_fusion_entry_point="true"}, metadata={op_name="ragged-dot-none"}
+  %attn.2 = bf16[8,128]{1,0} custom-call(%p.5), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/layer_0_fused_multihead_attention/attn_select_fwd"}
+  %copy.9 = f32[8]{0} copy(%select_multiply_fusion.12)
+}
+"""
+
+
+@pytest.mark.parametrize("instruction,region", [
+    ("ragged-dot-none.48", ("forward", "moe_experts")),
+    ("ragged-dot-metadata.8", ("backward", "moe_experts")),
+    ("attn.2", ("forward", "fused_multihead_attention")),
+    ("select_multiply_fusion.12", ("forward", "moe_experts")),
+    ("copy.9", ("unattributed", "")),
+])
+def test_a_compilers_kernel_is_filed_under_what_feeds_it(instruction, region):
+    """A ``custom-call`` whose ``op_name`` holds no scope takes its first
+    scoped operand's; a kernel with a scope of its own, and an operation
+    that XLA inserted without any ``op_name``, stay where they were."""
+    names = profiler.op_names_of(RAGGED_TEXT)
+    assert profiler.region_of(names.get(instruction, "")) == region

@@ -222,7 +222,7 @@ def test_every_pallas_call_carries_a_name_of_the_table():
     named = [c.args[0] for c in _calls(attention, "_kernel_call")]
     assert all(isinstance(a, ast.Constant) for a in named)
     names = [a.value for a in named]
-    assert len(names) == 14 and len(set(names)) == len(names)
+    assert len(names) == 17 and len(set(names)) == len(names)
     assert set(names) == set(attention.KERNEL_NAMES)
     for tier in attention.KERNEL_TIERS:     # a name for every counted tier
         stem = "attn_" + tier.replace("_bwd", "")
