@@ -57,9 +57,20 @@ def _replay_forward_checkpointed(ctx, prior_ops, wrt_names, overrides,
 
     Only the loss needs to survive to the caller: each segment returns just
     the env entries later segments (or the loss) consume, so the residual
-    set the grad transform saves is exactly those boundary values.
+    set the grad transform saves is those boundary values - and what a
+    producer inside the segment has marked with
+    ``kernels.common.keep_across_recompute`` (a kernel's output that is
+    dear to make and small to hold: attention's ``o`` and row logsumexp,
+    ``sparse_index``'s mask). Everything else in a segment is made again
+    in the backward pass; a segment with no marked value lowers as under a
+    bare ``jax.checkpoint``.
     """
     import jax
+
+    from ...kernels.common import RECOMPUTE_KEEP, recompute_segment
+
+    keep_marked = jax.checkpoint_policies.save_only_these_names(
+        RECOMPUTE_KEEP)
 
     # segment boundaries: after the op that (last) produces each checkpoint
     producer = {}
@@ -118,7 +129,8 @@ def _replay_forward_checkpointed(ctx, prior_ops, wrt_names, overrides,
         if is_last:
             env = run_seg(env)
         else:
-            env = jax.checkpoint(run_seg)(env)
+            with recompute_segment():
+                env = jax.checkpoint(run_seg, policy=keep_marked)(env)
     return env
 
 

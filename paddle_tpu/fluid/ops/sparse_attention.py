@@ -138,8 +138,13 @@ def _sparse_index(ctx, op):
     int8 (module docstring); attrs ``topk``, ``chunk_size``."""
     import jax
 
+    from ...kernels.common import keep_across_recompute
+
     q = ctx.get_input(op, "Q")
     k = ctx.get_input(op, "K")
     w = ctx.get_input(op, "W")
-    ctx.set_output(op, "Select", jax.lax.stop_gradient(sparse_index_select(
-        q, k, w, int(op.attr("topk")), int(op.attr("chunk_size", 512)))))
+    select = jax.lax.stop_gradient(sparse_index_select(
+        q, k, w, int(op.attr("topk")), int(op.attr("chunk_size", 512))))
+    # 32 counting passes a chunk for a byte an entry: kept, not made again
+    ctx.set_output(op, "Select", keep_across_recompute(select,
+                                                       "sparse_index"))

@@ -877,7 +877,12 @@ class RecomputeOptimizer:
     """Activation recomputation (reference ``optimizer.py:3341``). Under the
     functional-autodiff design the checkpoint list is carried on the autodiff
     op; its lowering wraps forward segments in ``jax.checkpoint`` so XLA
-    rematerializes instead of saving activations."""
+    rematerializes instead of saving activations. What survives a segment's
+    boundary: the checkpoint vars, and the values a producer inside the
+    segment marked as dear to make and small to hold
+    (``kernels.common.keep_across_recompute``: the flash and select
+    attention tiers' output and row logsumexp, ``sparse_index``'s mask) -
+    the producer decides, there is nothing to set here."""
 
     def __init__(self, optimizer):
         self._optimizer = optimizer

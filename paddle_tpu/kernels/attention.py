@@ -140,7 +140,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import interpret as _interpret
-from .common import named_pallas_call
+from .common import keep_across_recompute, named_pallas_call
 from .common import supports_pallas as _supports_pallas
 
 _MAX_FUSED_SEQ = 1024
@@ -1308,7 +1308,8 @@ def _selected(q, k, v, select, scale, causal):
 
 def _selected_fwd(q, k, v, select, scale, causal):
     if _use_select_kernel(q, k, causal):
-        o, lse = _pallas_attention_select(q, k, v, select, scale)
+        o, lse = (keep_across_recompute(x, "attn_select") for x in
+                  _pallas_attention_select(q, k, v, select, scale))
         return o, (q, k, v, select, (o, lse))
     return (_select_fallback(q, k, v, select, scale, causal),
             (q, k, v, select, None))
@@ -1961,8 +1962,9 @@ def _fused_fwd(q, k, v, bias, scale, p_drop, seed, causal=False):
         # the split backward regenerates probabilities from the row
         # logsumexp and needs rowsum(do*o), so o and lse join the
         # residuals (flash-attention-2 residual set: q, k, v, o, L)
-        o, lse = _pallas_attention_flash(q, k, v, bias, scale, p_drop,
-                                         seed, causal)
+        o, lse = (keep_across_recompute(x, "attn_flash") for x in
+                  _pallas_attention_flash(q, k, v, bias, scale, p_drop,
+                                          seed, causal))
         return o, (q, k, v, bias, seed, (o, lse))
     out = _fused(q, k, v, bias, scale, p_drop, seed, causal)
     return out, (q, k, v, bias, seed, None)

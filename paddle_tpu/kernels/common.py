@@ -1,11 +1,18 @@
 """What every Pallas kernel file here shares: whether the kernels can run
-(a TPU, or the interpreter asked for), and the one ``pl.pallas_call`` of
-the package, which names the kernel for the trace."""
+(a TPU, or the interpreter asked for), the one ``pl.pallas_call`` of
+the package, which names the kernel for the trace, and the mark a producer
+puts on a value that recomputation is to keep (``keep_across_recompute``)."""
 
+import contextlib
 import os
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+
+# the one name ``ops/autodiff.py``'s checkpointed replay saves by
+RECOMPUTE_KEEP = "recompute_keep"
+_segment_depth = [0]    # > 0 while a checkpointed segment is being traced
 
 
 def interpret():
@@ -40,3 +47,46 @@ def named_pallas_call(name, kernel, **kw):
             return call(*args)
 
     return named
+
+
+@contextlib.contextmanager
+def recompute_segment():
+    """Held by ``ops/autodiff.py`` while it traces a checkpointed segment
+    (the segment's ops and their ``custom_vjp`` forward rules), so that a
+    marking site knows that its value can be kept."""
+    _segment_depth[0] += 1
+    try:
+        yield
+    finally:
+        _segment_depth[0] -= 1
+
+
+def keep_across_recompute(x, what):
+    """Mark ``x`` as a value that a recomputed segment keeps for its
+    backward pass instead of making it again (``ops/autodiff.py``:
+    ``jax.checkpoint`` under ``save_only_these_names(RECOMPUTE_KEEP)``).
+    Outside a checkpointed segment ``x`` comes back as it is: a program
+    without checkpoints lowers to the text it had before any producer
+    marked anything.
+
+    The rule a producer decides by: mark a value when a kernel or a
+    multi-pass op made it, it is deterministic given the segment's inputs,
+    and making it again costs well over 0.03 ms a MB held. The three
+    marked today (chip runs, PR 32): the select tier's ``o`` and row
+    logsumexp, 17.4 ms for 136 MB; ``sparse_index``'s byte mask, 10.6 ms
+    for 268 MB; the flash tier's ``o`` and logsumexp, 8.96 ms for 135 MB. A
+    projection's output costs far less a MB and is not marked.
+
+    ``recompute_kept_bytes_total{what}`` counts the bytes, once a site
+    traced inside a checkpointed segment (trace-time, not per step)."""
+    if not _segment_depth[0]:
+        return x
+    from ..fluid import monitor
+
+    monitor.counter(
+        "recompute_kept_bytes_total",
+        "bytes a checkpointed segment keeps for its backward pass instead "
+        "of recomputing them, by producer (trace-time: once a site traced "
+        "inside a segment, not per step)",
+        labels={"what": what}).inc(x.size * x.dtype.itemsize)
+    return checkpoint_name(x, RECOMPUTE_KEEP)

@@ -8,7 +8,6 @@ import collections
 import jax
 import numpy as np
 import pytest
-from jax.interpreters import partial_eval as pe
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers, optimizer
@@ -16,6 +15,7 @@ from paddle_tpu.fluid.contrib import mixed_precision
 from paddle_tpu.fluid.registry import registry
 from paddle_tpu.models import bert
 from test_recompute import _build as _build_recompute
+from test_recompute import _live_jaxpr
 from test_sparse import _build_emb_sgd
 
 SEQ = 64
@@ -70,16 +70,9 @@ def _count(jaxpr, counts, outer=""):
 
 
 def _live_counts(main, startup, loss, feed):
-    """Counts over the step's jaxpr with what its outputs (the fetched loss,
-    the state, the rng key) do not need taken away."""
-    exe = fluid.Executor()
-    with fluid.scope_guard(fluid.Scope()):
-        exe.run(startup)
-        fn, args = exe.as_function(main, feed, [loss])
-        closed = jax.make_jaxpr(fn)(*args)
-    live, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+    """Counts over the step's live jaxpr."""
     counts = collections.Counter()
-    _count(live, counts)
+    _count(_live_jaxpr(main, startup, loss, feed), counts)
     return counts
 
 

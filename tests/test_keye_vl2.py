@@ -189,9 +189,9 @@ def test_startup_draws_the_embedding_as_the_config_says(embedding_std):
 
 def test_bf16_step_with_recomputation_follows_the_reference(both_sides):
     """The cell's own policy at the toy size: AMP bf16 over float32
-    masters and checkpoints at the layer boundaries (the selection made
-    again in the backward pass). Three steps' losses within bf16's reach
-    of the float32 reference's."""
+    masters and checkpoints at the layer boundaries (the selection and
+    attention's output kept across them). Three steps' losses within
+    bf16's reach of the float32 reference's."""
     import compare
 
     fam = _family()

@@ -6,6 +6,7 @@ the backward pass (jax.checkpoint's optimization barriers keep XLA from
 CSE-ing them away)."""
 
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers, optimizer
@@ -40,6 +41,32 @@ def _train(use_recompute, steps=4):
                 for _ in range(steps)]
 
 
+def _step_function(main, startup, loss, feed):
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor()
+        exe.run(startup)
+        return exe.as_function(main, feed, [loss])
+
+
+def _lowered(main, startup, loss, feed):
+    """The step's StableHLO text."""
+    import jax
+
+    fn, args = _step_function(main, startup, loss, feed)
+    return jax.jit(fn).lower(*args).as_text()
+
+
+def _live_jaxpr(main, startup, loss, feed):
+    """The step's jaxpr with what its outputs (the fetched loss, the
+    state, the rng key) do not need taken away."""
+    import jax
+    from jax.interpreters import partial_eval as pe
+
+    fn, args = _step_function(main, startup, loss, feed)
+    closed = jax.make_jaxpr(fn)(*args)
+    return pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))[0]
+
+
 def test_recompute_matches_baseline():
     base = _train(False)
     remat = _train(True)
@@ -47,21 +74,204 @@ def test_recompute_matches_baseline():
 
 
 def test_recompute_actually_rematerializes():
-    import jax
-
-    def lowered(use_recompute):
-        main, startup, loss = _build(use_recompute)
-        exe = fluid.Executor()
-        feed = {"x": np.zeros((8, 32), np.float32)}
-        with fluid.scope_guard(fluid.Scope()):
-            exe.run(startup)
-            fn, args = exe.as_function(main, feed, [loss])
-        return jax.jit(fn).lower(*args).as_text()
-
-    base, remat = lowered(False), lowered(True)
+    feed = {"x": np.zeros((8, 32), np.float32)}
+    base = _lowered(*_build(False), feed)
+    remat = _lowered(*_build(True), feed)
     # jax.checkpoint emits optimization_barrier (so XLA can't CSE the
     # recompute away) and duplicates the checkpointed segments' matmuls
     assert remat.count("optimization_barrier") > 0
     assert remat.count("dot_general") > base.count("dot_general"), (
         "checkpointed program lowered to no extra matmuls: "
         "jax.checkpoint segments were not applied")
+
+
+# -- what a segment keeps: kernels.common.keep_across_recompute --------------
+# Two layers of projections -> (sparse_index ->) fused attention -> output
+# projection, a checkpoint after each, on the Pallas interpreter; tile 64,
+# so S = 128 is 2 x 2 tiles and takes the select / flash tier.
+_B, _S, _H, _HKV, _D, _HI, _DI, _TOPK, _LAYERS = 1, 128, 4, 2, 16, 2, 8, 24, 2
+_KEPT_BYTES = {     # a layer: o and lse in float32, the mask a byte an entry
+    "attn_select": _B * _H * _S * (_D + 1) * 4,
+    "attn_flash": _B * _H * _S * (_D + 1) * 4,
+    "sparse_index": _B * _S * _S}
+_TIERS = {"select": ("attn_select", "sparse_index"), "flash": ("attn_flash",)}
+
+
+@pytest.fixture
+def interpreted_tiers(monkeypatch):
+    from paddle_tpu.kernels import attention as A
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(A, "_SELECT_BLOCK_CANDIDATES", (64,))
+    monkeypatch.setattr(A, "_FLASH_BLOCK_CANDIDATES", (64,))
+    monkeypatch.setattr(A, "_MAX_FUSED_SEQ", 64)
+    monkeypatch.setattr(A, "_MAX_LONG_SEQ", 0)
+
+
+def _bare_checkpoint(monkeypatch):
+    """The replay as it was before it kept anything: no policy."""
+    import jax
+
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+
+
+def _attention_program(tier, use_recompute):
+    def heads(x, n, d):
+        return layers.transpose(layers.reshape(x, [0, 0, n, d]),
+                                [0, 2, 1, 3])
+
+    def proj(x, width):
+        return layers.fc(x, width, num_flatten_dims=2, bias_attr=False)
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = 5
+    # (fresh names: two builds of one program lower to one text)
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        h = layers.data("x", shape=[_S, 32], dtype="float32")
+        cuts = []
+        for _ in range(_LAYERS):
+            select = None
+            if tier == "select":
+                select = layers.sparse_index(
+                    heads(proj(h, _HI * _DI), _HI, _DI), proj(h, _DI),
+                    proj(h, _HI), _TOPK, chunk_size=64)
+            o = layers.fused_attention(
+                heads(proj(h, _H * _D), _H, _D),
+                heads(proj(h, _HKV * _D), _HKV, _D),
+                heads(proj(h, _HKV * _D), _HKV, _D), scale=_D ** -0.5,
+                causal=True, num_kv_heads=_HKV, select=select)
+            h = h + proj(layers.reshape(layers.transpose(o, [0, 2, 1, 3]),
+                                        [0, 0, _H * _D]), 32)
+            cuts.append(h)
+        loss = layers.mean(proj(h, 1))
+        opt = optimizer.SGD(learning_rate=0.1)
+        if use_recompute:
+            opt = optimizer.RecomputeOptimizer(opt)
+            opt._set_checkpoints(cuts)
+        opt.minimize(loss)
+    feed = {"x": np.random.RandomState(0).randn(_B, _S, 32).astype(
+        np.float32)}
+    return main, startup, loss, feed
+
+
+def _kept_bytes(what):
+    from paddle_tpu.fluid import monitor
+
+    return monitor.counter("recompute_kept_bytes_total",
+                           labels={"what": what}).value
+
+
+def _bisections(jaxpr, transposed, outer=""):
+    """``kth_largest``'s 32-pass loops in a jaxpr, by whether they sit
+    under a transpose (= are made again in the backward pass)."""
+    import jax
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        stack = outer + str(eqn.source_info.name_stack)
+        if eqn.primitive.name == "scan" and eqn.params["length"] == 32:
+            n += ("transpose(" in stack) == transposed
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _bisections(sub, transposed, stack + "/")
+    return n
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "bare"])
+@pytest.mark.parametrize("tier", ["select", "flash"])
+def test_a_kept_value_is_made_once_a_layer(interpreted_tiers, monkeypatch,
+                                           tier, kept):
+    """Under checkpoints the forward attention kernel (and the selection's
+    bisection) is in the step once a layer: its ``o``, row logsumexp and
+    mask cross the boundary. Under the bare checkpoint each is there twice,
+    the second time in the backward pass."""
+    import collections
+
+    from test_autodiff_one_forward import _count
+
+    if not kept:
+        _bare_checkpoint(monkeypatch)
+    live = _live_jaxpr(*_attention_program(tier, True))
+    counts = collections.Counter()
+    _count(live, counts)
+    fwd = "attn_%s_fwd" % tier
+    replayed = 0 if kept else _LAYERS
+    assert counts[(fwd, False)] == _LAYERS
+    assert counts[(fwd, True)] == replayed
+    assert counts[("attn_%s_bwd_dq" % tier, True)] == _LAYERS
+    assert counts[("attn_%s_bwd_dkv" % tier, True)] == _LAYERS
+    assert sum(n for (name, _), n in counts.items()
+               if name.startswith("attn_")) == 3 * _LAYERS + replayed
+    if tier == "select":
+        # as many as the program without checkpoints holds: one selection
+        # a layer (a selection walks its chunks in groups, a loop each)
+        once = _bisections(_live_jaxpr(*_attention_program(tier, False)),
+                           False)
+        assert once > 0 and once % _LAYERS == 0
+        assert _bisections(live, False) == once
+        assert _bisections(live, True) == (0 if kept else once)
+    # the projections inside a segment are still made again
+    assert counts[("dot_general", True)] > 0
+
+
+@pytest.mark.parametrize("tier", ["select", "flash"])
+def test_kept_values_change_no_number(interpreted_tiers, monkeypatch, tier):
+    """Three steps' losses and every gradient of every step, bit for bit
+    what the bare checkpoint gives: the backward pass reads from a buffer
+    the bits a second launch would have produced."""
+    def three_steps():
+        main, startup, loss, feed = _attention_program(tier, True)
+        ad = next(op for op in main.global_block().ops
+                  if op.type == "autodiff")
+        fetch = [loss] + list(ad.attr("grad_names"))
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor()
+            exe.run(startup)
+            return [np.asarray(x) for _ in range(3)
+                    for x in exe.run(main, feed=feed, fetch_list=fetch)]
+
+    got = three_steps()
+    with monkeypatch.context() as m:
+        _bare_checkpoint(m)
+        want = three_steps()
+    assert len(got) == len(want) >= 3 * 10
+    assert got[0] != got[-len(got) // 3]       # the steps train
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("tier", ["select", "flash"])
+def test_kept_bytes_counter_reads_the_shapes(interpreted_tiers, tier):
+    """``recompute_kept_bytes_total{what}``: once a site traced inside a
+    checkpointed segment; a program without checkpoints counts nothing."""
+    before = {w: _kept_bytes(w) for w in _KEPT_BYTES}
+    _live_jaxpr(*_attention_program(tier, False))
+    assert {w: _kept_bytes(w) for w in _KEPT_BYTES} == before
+    _live_jaxpr(*_attention_program(tier, True))
+    for what, a_layer in _KEPT_BYTES.items():
+        want = _LAYERS * a_layer if what in _TIERS[tier] else 0
+        assert _kept_bytes(what) - before[what] == want, what
+
+
+@pytest.mark.parametrize("tier", ["select", "flash"])
+def test_without_checkpoints_the_tags_lower_to_nothing(interpreted_tiers,
+                                                       monkeypatch, tier):
+    from paddle_tpu.kernels import attention as A
+    from paddle_tpu.kernels import common
+
+    tagged = _lowered(*_attention_program(tier, False))
+    monkeypatch.setattr(A, "keep_across_recompute", lambda x, what: x)
+    monkeypatch.setattr(common, "keep_across_recompute", lambda x, what: x)
+    assert _lowered(*_attention_program(tier, False)) == tagged
+
+
+def test_an_untagged_segment_lowers_as_under_the_bare_checkpoint(
+        monkeypatch):
+    def lowered():
+        with fluid.unique_name.guard():
+            return _lowered(*_build(True),
+                            {"x": np.zeros((8, 32), np.float32)})
+
+    kept = lowered()
+    _bare_checkpoint(monkeypatch)
+    assert lowered() == kept
