@@ -1620,7 +1620,11 @@ def fused_attention(q, k, v, attn_bias=None, scale=None, dropout_prob=0.0,
     query; it carries no gradient, goes with neither ``attn_bias`` nor
     dropout, and takes the kernels' select tier (with ``causal``, any S
     that a 128-row tile divides and at most 8 Q heads a K/V head; else
-    the masked jnp form)."""
+    the masked jnp form). ``v`` may be narrower or wider than ``q`` and
+    ``k`` ([B, H, S, dv]; latent attention: 192-wide queries and keys
+    against 128-wide values): the result is [B, H, S, dv], the kernels'
+    flash tier serves it at every S that a 128-row tile divides (nothing
+    is padded), the jnp forms elsewhere."""
     helper = LayerHelper("fused_multihead_attention", **locals())
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
@@ -1800,17 +1804,25 @@ def causal_conv1d(input, kernel_size, param_attr=None, name=None):
 
 def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
                      chunk_size=64, name=None):
-    """The gated delta rule of a Gated DeltaNet layer (ops/
-    linear_attention.py): q, k [B, S, Hk, dk], v [B, S, Hv, dv], ``a`` and
-    ``b`` [B, S, Hv] the pre-activations of the decay and of beta. Creates
-    the per-head ``A_log`` (0: decay rate 1) and ``dt_bias`` (1)."""
+    """The gated delta rule of a linear-attention layer (ops/
+    linear_attention.py): q, k [B, S, Hk, dk], v [B, S, Hv, dv], ``b``
+    [B, S, Hv] the pre-activation of beta, ``a`` the decay's.
+
+    ``a`` [B, S, Hv]: ONE decay a head and position (Gated DeltaNet);
+    creates the per-head ``A_log`` (0: decay rate 1) and ``dt_bias`` (1),
+    both [Hv]. ``a`` [B, S, Hv, dk]: a decay a key CHANNEL (Kimi Delta
+    Attention; Hk = Hv then); ``A_log`` stays [Hv] and ``dt_bias`` is
+    [Hv * dk]. The op tells the two rules apart by ``a``'s rank; each has
+    its Pallas kernels where head dims are multiples of 128 and the chunk
+    packs into a 128-row group, and its chunked XLA form elsewhere."""
     from ..initializer import Constant
 
     helper = LayerHelper("gated_delta_rule", **locals())
-    heads, dtype = [int(v.shape[2])], "float32"
-    a_log = helper.create_parameter(a_log_attr, heads, dtype,
+    heads, dtype = int(v.shape[2]), "float32"
+    channels = heads * int(a.shape[3]) if len(a.shape) == 4 else heads
+    a_log = helper.create_parameter(a_log_attr, [heads], dtype,
                                     default_initializer=Constant(0.0))
-    dt_bias = helper.create_parameter(dt_bias_attr, heads, dtype,
+    dt_bias = helper.create_parameter(dt_bias_attr, [channels], dtype,
                                       default_initializer=Constant(1.0))
     out = helper.create_variable_for_type_inference(v.dtype)
     helper.append_op(
@@ -1822,21 +1834,41 @@ def gated_delta_rule(q, k, v, a, b, a_log_attr=None, dt_bias_attr=None,
 
 
 def moe_route(input, num_experts, k, norm_topk_prob=True, param_attr=None,
-              name=None):
+              name=None, scoring="softmax", bias_attr=None,
+              routed_scaling_factor=None):
     """Router over all ``num_experts`` experts: ``(ids, weights)`` of the
     ``k`` largest of ``softmax(x W)`` (f32), renormalised to sum 1 where
     ``norm_topk_prob``. ``ids`` is int32 [..., k] and carries no
-    gradient."""
+    gradient. ``scoring`` ``"sigmoid"`` scores each expert by itself
+    (``sigmoid(x W)``); ``bias_attr`` then creates a ``[num_experts]``
+    bias (zero, float32) that is added to the scores for the CHOICE of the
+    ``k`` experts only - the weights stay the chosen experts' own scores,
+    so it gets no gradient from the loss: a load-balancing rule moves it,
+    build it ``trainable=False``. ``routed_scaling_factor`` multiplies the
+    (renormalised) weights. Left as they are, the op is the softmax
+    router."""
+    from ..initializer import Constant
+
     helper = LayerHelper("moe_route", **locals())
     w = helper.create_parameter(
         param_attr, [int(input.shape[-1]), int(num_experts)], "float32")
     ids = helper.create_variable_for_type_inference("int32",
                                                     stop_gradient=True)
     wts = helper.create_variable_for_type_inference("float32")
-    helper.append_op(type="moe_route", inputs={"X": [input], "Weight": [w]},
+    inputs = {"X": [input], "Weight": [w]}
+    attrs = {"k": int(k), "norm_topk_prob": bool(norm_topk_prob)}
+    if scoring != "softmax":
+        assert scoring == "sigmoid", scoring
+        attrs["scoring"] = scoring
+        if bias_attr is not None:
+            inputs["Bias"] = [helper.create_parameter(
+                bias_attr, [int(num_experts)], "float32",
+                default_initializer=Constant(0.0))]
+    if routed_scaling_factor:
+        attrs["routed_scaling_factor"] = float(routed_scaling_factor)
+    helper.append_op(type="moe_route", inputs=inputs,
                      outputs={"TopkIds": [ids], "TopkWeights": [wts]},
-                     attrs={"k": int(k),
-                            "norm_topk_prob": bool(norm_topk_prob)})
+                     attrs=attrs)
     return ids, wts
 
 

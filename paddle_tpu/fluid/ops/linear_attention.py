@@ -18,6 +18,16 @@ else ``gated_delta_rule_chunked`` in plain XLA - a ``lax.scan`` over the
 chunks holds the two matmuls that touch the state, everything else is
 batched over all chunks - which is also the kernels' oracle. The
 ``autodiff`` op differentiates either like any lowering.
+
+The same op runs the CHANNEL-GATED rule (Kimi Delta Attention; Kimi
+Linear's token mixer three layers in four), told by its gate's rank: ``A``
+[B, S, Hv, dk] with ``DtBias`` [Hv * dk] is a log decay a key channel,
+``S' = Diag(exp(g_t)) S_{t-1}`` with the rest as above. Its chunked form
+(``kda_chunked`` here, the kernels ``kda_chunk_fwd`` / ``kda_chunk_bwd``
+beside the scalar rule's) has no ``[C, C]`` decay matrix: the decay sits
+inside every contraction over dk, and is formed pair by pair in levels so
+that no factor exceeds 1 (``kernels/delta_rule.py``'s header has the
+equations). Dispatch is the same: by what the shapes show.
 """
 
 import functools
@@ -207,6 +217,84 @@ def gated_delta_rule_chunked(q, k, v, g, beta, chunk_size=64):
     return o[:, :S]
 
 
+def kda_chunked(q, k, v, g, beta, chunk_size=64):
+    """The chunked CHANNEL-gated delta rule. q, k [B, S, H, dk]
+    (normalised and scaled by the caller), v [B, S, H, dv], g [B, S, H, dk]
+    (log decay a key channel, <= 0) and beta [B, S, H] in f32. Returns o
+    [B, S, H, dv] in f32. Precisions as ``gated_delta_rule_chunked``. A
+    pairwise decay ``exp(Gc_i - Gc_j)`` is the product of two factors <= 1
+    through the row r where i's and j's positions first part (level b = 1,
+    2, .. C/2: i in an odd block of b, j in the even block before it, r
+    the odd block's first row); ``exp(-Gc)`` is never formed."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    cd = v.dtype
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    C = int(chunk_size)
+    assert C & (C - 1) == 0, "the chunk is a power of two: %d" % C
+    pad = (-S) % C
+    if pad:     # beta = 0, g = 0: a padded position leaves the state alone
+        q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for t in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    N = (S + pad) // C
+
+    def chunks(t):      # [B, S, H, ...] -> [B, H, N, C, ...]
+        t = jnp.moveaxis(t, 2, 1)
+        return t.reshape((B, H, N, C) + t.shape[3:])
+
+    q, k, v, g, beta = (chunks(t) for t in (q, k, v, g, beta))
+    gc = jnp.cumsum(g.astype(f32), axis=3)              # [B, H, N, C, dk]
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
+    qf, kf = q.astype(f32), k.astype(f32)
+    k_beta = kf * beta[..., None]
+    idx = jnp.arange(C)
+    a = jnp.zeros((B, H, N, C, C), f32)
+    # the diagonal of q k^T carries no decay
+    qk = jnp.eye(C, dtype=f32) * jnp.sum(qf * kf, -1)[..., None]
+    b = 1
+    while b < C:
+        odd = (idx // b) % 2 == 1
+        d = gc - gc[..., idx // (2 * b) * (2 * b) + b, :]
+        e = jnp.exp(jnp.where(odd[:, None], d, -d))     # both sides <= 1
+        pair = (idx[:, None] // (2 * b) == idx[None, :] // (2 * b)) \
+            & odd[:, None] & ~odd[None, :]
+        ke = (kf * e).astype(cd)
+        a = a + jnp.where(pair, mm("bhnid,bhnjd->bhnij",
+                                   (k_beta * e).astype(cd), ke), 0.0)
+        qk = qk + jnp.where(pair, mm("bhnid,bhnjd->bhnij",
+                                     (qf * e).astype(cd), ke), 0.0)
+        b *= 2
+    t = _inv_unit_lower()(jnp.eye(C, dtype=f32) + a)
+    e_g = jnp.exp(gc)
+    rhs = jnp.concatenate([v.astype(f32) * beta[..., None], k_beta * e_g],
+                          axis=-1)
+    uw = mm("bhnij,bhnjd->bhnid", t.astype(cd), rhs.astype(cd))
+    u, w = uw[..., :dv], uw[..., dv:]
+    g_last = gc[..., -1, :]                             # [B, H, N, dk]
+    k_dec = (kf * jnp.exp(g_last[..., None, :] - gc)).astype(cd)
+
+    def step(state, xs):
+        u_n, w_n, k_n, gl_n = xs
+        v_new = u_n - mm("bhcd,bhde->bhce", w_n, state.astype(cd))
+        new = state * jnp.exp(gl_n)[..., None] + mm(
+            "bhcd,bhce->bhde", k_n, v_new.astype(cd))
+        return new, (state.astype(cd), v_new.astype(cd))
+
+    lead = lambda x: jnp.moveaxis(x, 2, 0)              # noqa: E731
+    _, (states, v_new) = jax.lax.scan(
+        step, jnp.zeros((B, H, dk, dv), f32),
+        (lead(u), lead(w.astype(cd)), lead(k_dec), lead(g_last)))
+    states, v_new = jnp.moveaxis(states, 0, 2), jnp.moveaxis(v_new, 0, 2)
+    o = mm("bhncd,bhnde->bhnce", (qf * e_g).astype(cd), states) \
+        + mm("bhnij,bhnjd->bhnid", qk.astype(cd), v_new)
+    o = jnp.moveaxis(o.reshape(B, H, N * C, dv), 1, 2)
+    return o[:, :S]
+
+
 @register("gated_delta_rule")
 def _gated_delta_rule(ctx, op):
     """Q, K [B, S, Hk, dk], V [B, S, Hv, dv] (Hv a multiple of Hk: each
@@ -214,7 +302,8 @@ def _gated_delta_rule(ctx, op):
     and beta's pre-activations), ALog, DtBias [Hv] -> Out [B, S, Hv, dv].
     ``g = -exp(ALog) * softplus(A + DtBias)`` and ``beta = sigmoid(B)``
     in f32; q and k are L2-normalised over the head dim and q is scaled
-    by ``dk ** -0.5``."""
+    by ``dk ** -0.5``. A [B, S, Hv, dk] with DtBias [Hv * dk] (ALog stays
+    [Hv]; Hk = Hv) is a decay a key CHANNEL: the channel-gated rule."""
     import jax
     import jax.numpy as jnp
 
@@ -226,7 +315,12 @@ def _gated_delta_rule(ctx, op):
     b = ctx.get_input(op, "B").astype(f32)
     a_log = ctx.get_input(op, "ALog").astype(f32)
     dt_bias = ctx.get_input(op, "DtBias").astype(f32)
-    g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+    channel = a.ndim == 4
+    if channel:
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            a + dt_bias.reshape(a.shape[2:]))
+    else:
+        g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
     beta = jax.nn.sigmoid(b)
     rep = v.shape[2] // q.shape[2]
     assert rep * q.shape[2] == v.shape[2], (q.shape, v.shape)
@@ -238,7 +332,7 @@ def _gated_delta_rule(ctx, op):
         out = delta_rule.gated_delta_rule_pallas(       # counts "pallas"
             q, k, v, g, beta, chunk_size=chunk, l2norm_eps=eps)
     else:
-        _count("chunked")
+        _count("kda_chunked" if channel else "chunked")
         qf, kf = q.astype(f32), k.astype(f32)
         qf = qf * jax.lax.rsqrt(jnp.sum(qf * qf, -1, keepdims=True) + eps)
         kf = kf * jax.lax.rsqrt(jnp.sum(kf * kf, -1, keepdims=True) + eps)
@@ -246,5 +340,6 @@ def _gated_delta_rule(ctx, op):
             kf.astype(v.dtype)
         if rep > 1:
             qn, kn = (jnp.repeat(t, rep, axis=2) for t in (qn, kn))
-        out = gated_delta_rule_chunked(qn, kn, v, g, beta, chunk_size=chunk)
+        out = (kda_chunked if channel else gated_delta_rule_chunked)(
+            qn, kn, v, g, beta, chunk_size=chunk)
     ctx.set_output(op, "Out", out.astype(v.dtype))

@@ -36,21 +36,55 @@ def _count(impl):
         "per traced program, not per step)", labels={"impl": impl}).inc()
 
 
+def _count_route(scoring):
+    from .. import monitor
+
+    monitor.counter(
+        "moe_route_dispatch_total",
+        "moe_route lowerings traced, by scoring (trace-time: one per "
+        "traced site, not per step)", labels={"scoring": scoring}).inc()
+
+
 @register("moe_route")
 def _moe_route(ctx, op):
     """X [..., h], Weight [h, E] -> TopkIds [..., k] (int32) and
     TopkWeights [..., k] (f32): softmax over all E experts in f32 (the
     matmul at ``highest``: a bf16 pass flips near-tied choices), the k
-    largest, renormalised to sum 1 where ``norm_topk_prob``."""
+    largest, renormalised to sum 1 where ``norm_topk_prob``.
+
+    ``scoring`` ``sigmoid``: the scores are ``sigmoid(x W)``, each expert
+    by itself; an optional ``Bias`` [E] is added for the CHOICE only (the
+    k largest of ``score + bias``; the weights are the chosen experts' own
+    scores, so the bias carries no gradient), the renormalisation divides
+    by ``sum + 1e-20``, and ``routed_scaling_factor`` multiplies the
+    weights. Without these three the op is the softmax router it was, to
+    the bit."""
     import jax
     import jax.numpy as jnp
 
     x = ctx.get_input(op, "X").astype(jnp.float32)
     w = ctx.get_input(op, "Weight").astype(jnp.float32)
-    p = jax.nn.softmax(jnp.matmul(x, w, precision="highest"), axis=-1)
-    vals, ids = jax.lax.top_k(p, int(op.attr("k")))
-    if op.attr("norm_topk_prob", True):
-        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    scoring = op.attr("scoring", "softmax")
+    _count_route(scoring)
+    logits = jnp.matmul(x, w, precision="highest")
+    if scoring == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+        vals, ids = jax.lax.top_k(p, int(op.attr("k")))
+        if op.attr("norm_topk_prob", True):
+            vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    else:
+        assert scoring == "sigmoid", scoring
+        p = jax.nn.sigmoid(logits)
+        bias = ctx.get_input(op, "Bias")
+        choice = p if bias is None else p + jax.lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, ids = jax.lax.top_k(choice, int(op.attr("k")))
+        vals = jnp.take_along_axis(p, ids, axis=-1)
+        if op.attr("norm_topk_prob", True):
+            vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+    factor = op.attr("routed_scaling_factor", None)
+    if factor:
+        vals = vals * float(factor)
     ctx.set_output(op, "TopkIds", ids.astype(jnp.int32))
     ctx.set_output(op, "TopkWeights", vals)
 

@@ -53,7 +53,16 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       the tiles computed keep their order: every output is what it was,
       to the bit. ``attn_flash_tiles_total{kind}`` counts a site's tiles
       computed and skipped. The block and long tiers hold all of K a step
-      and have no sweep to shorten. A call without ``causal`` traces to
+      and have no sweep to shorten. v of ANOTHER WIDTH than q and k (dv !=
+      d: latent attention's 192-wide queries and keys against 128-wide
+      values) is the flash tier's at every S a tile divides, whatever S:
+      its three kernels block v, o, do and dv at ``[Tb, dv]`` and q, k, dq
+      and dk at ``[Tb, d]`` (``_flash_specs``), the PV, dV and dP products
+      run at dv and the score and dq / dk products at d, so nothing is
+      padded; the block and long tiers, which hold q, k and v under one
+      block shape, are passed over, and where no tile divides S (or off
+      the chip) the jnp forms below take two widths as they are. With dv =
+      d every spec, kernel and jaxpr is what it was. A call without ``causal`` traces to
       the jaxpr it always did. Fewer KV than Q heads: the
       ``fused_multihead_attention`` op repeats K/V to the Q head count
       before the kernel (``num_kv_heads``).
@@ -277,14 +286,14 @@ def _blockwise_attention(q, k, v, bias, scale, p_drop, seed,
         bias_f = jnp.pad(bias_f, ((0, 0), (0, 0), (0, 0), (0, pad)),
                          constant_values=-1e30)
     kb = jnp.moveaxis(k.reshape(B, H, nb, block, d), 2, 0)
-    vb = jnp.moveaxis(v.reshape(B, H, nb, block, d), 2, 0)
+    vb = jnp.moveaxis(v.reshape(B, H, nb, block, v.shape[-1]), 2, 0)
     bb = jnp.moveaxis(
         bias_f.reshape(B, bias_f.shape[1], bias_f.shape[2], nb, block),
         3, 0)
 
     m0 = jnp.full((B, H, S), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, H, S), jnp.float32)
-    o0 = jnp.zeros((B, H, S, d), jnp.float32)
+    o0 = jnp.zeros((B, H, S, v.shape[-1]), jnp.float32)
 
     def step(carry, xs):
         m, l, o = carry
@@ -673,11 +682,16 @@ def _flash_block(S):
     return None
 
 
-def _use_flash_kernel(q, p_drop, bias):
+def _use_flash_kernel(q, p_drop, bias, v=None):
+    """``v`` given with another width than q's (and k's): the block and
+    long tiers hold q, k and v under one block shape and cannot serve, so
+    the flash tier takes every S that a tile divides."""
     B, H, S, d = q.shape
-    if not _supports_pallas() or S <= _MAX_FUSED_SEQ:
+    if not _supports_pallas():
         return False
-    if _use_long_kernel(q, p_drop, bias):
+    two = v is not None and v.shape[-1] != d
+    if not two and (S <= _MAX_FUSED_SEQ
+                    or _use_long_kernel(q, p_drop, bias)):
         return False        # the measured-faster long tier wins <=~3k
     if _flash_block(S) is None:
         return False
@@ -887,8 +901,11 @@ def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
         _tile()
 
 
-def _flash_specs(q, bias, causal, kfast=True):
-    """Block specs of the flash kernels' grid ``(B, H, slow, fast)``:
+def _flash_specs(q, bias, causal, kfast=True, dv=None):
+    """Block specs of the flash kernels' grid ``(B, H, slow, fast)``; the
+    last two are the q-tile's and the k-tile's at v's width ``dv`` (o and
+    do; v and dv), which are ``qspec`` and ``kspec`` again where v is as
+    wide as q and k:
     ``kfast`` has the k-tile fastest (forward, dq), else the q-tile (dk/dv).
     Under ``causal`` the steps of a sweep that have no tile come first
     (``_flash_ktile``; in dk/dv the q-tiles before the k-tile) and name the
@@ -923,7 +940,10 @@ def _flash_specs(q, bias, causal, kfast=True):
     # laid out [B, H*nt, 1, S] to satisfy the TPU block-shape rule
     dbpspec = spec((1, 1, 1, TB),
                    lambda b, h, i, j: (b, h * nt + i, 0, j))
-    return TB, nt, qspec, kspec, bspec, rowspec, dbpspec
+    dv = d if dv is None else dv
+    ospec = spec((1, 1, TB, dv), lambda b, h, i, j: (b, h, i, 0))
+    vspec = spec((1, 1, TB, dv), lambda b, h, i, j: (b, h, j, 0))
+    return TB, nt, qspec, kspec, bspec, rowspec, dbpspec, ospec, vspec
 
 
 def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed,
@@ -932,7 +952,9 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed,
 
     _count_kernel("flash")
     B, H, S, d = q.shape
-    TB, nt, qspec, kspec, bspec, rowspec, _ = _flash_specs(q, bias, causal)
+    dv = v.shape[-1]        # v's own width; q's and k's is d
+    TB, nt, qspec, kspec, bspec, rowspec, _, ospec, vspec = _flash_specs(
+        q, bias, causal, dv=dv)
     _count_flash_tiles(nt, causal)
     f32 = jnp.float32
     return _kernel_call(
@@ -941,11 +963,11 @@ def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed,
                           n_heads=H, nq=nt, nk=nt, causal=causal),
         grid=(B, H, nt, nt),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  qspec, kspec, kspec, bspec],
-        out_specs=[qspec, rowspec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                  qspec, kspec, vspec, bspec],
+        out_specs=[ospec, rowspec],
+        out_shape=[jax.ShapeDtypeStruct((B, H, S, dv), q.dtype),
                    jax.ShapeDtypeStruct((B, H, S, 1), f32)],
-        scratch_shapes=[pltpu.VMEM((TB, d), f32),
+        scratch_shapes=[pltpu.VMEM((TB, dv), f32),
                         pltpu.VMEM((TB, 1), f32),
                         pltpu.VMEM((TB, 1), f32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
@@ -956,8 +978,8 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
                                 p_drop, causal=False):
     _count_kernel("flash_bwd")
     B, H, S, d = q.shape
-    TB, nt, qspec, kspec, bspec, rowspec, dbpspec = _flash_specs(
-        q, bias, causal)
+    TB, nt, qspec, kspec, bspec, rowspec, dbpspec, ospec, vspec = \
+        _flash_specs(q, bias, causal, dv=v.shape[-1])
     _count_flash_tiles(nt, causal, sites=2)     # dq, dk/dv
     f32 = jnp.float32
     dd = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1,
@@ -968,7 +990,7 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
         functools.partial(_flash_dq_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H, nq=nt, nk=nt, causal=causal),
         grid=(B, H, nt, nt),
-        in_specs=[smem, qspec, kspec, kspec, bspec, qspec, rowspec,
+        in_specs=[smem, qspec, kspec, vspec, bspec, ospec, rowspec,
                   rowspec],
         out_specs=[qspec, dbpspec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
@@ -977,18 +999,18 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
     )(seed, q, k, v, bias, do, lse, dd)
     # transposed grid: k-tile is the SLOW tile dim so dk/dv accumulate
     # over consecutive q-tile steps
-    _, _, qspec_t, kspec_t, bspec_t, rowspec_t, _ = _flash_specs(
-        q, bias, causal, kfast=False)
+    _, _, qspec_t, kspec_t, bspec_t, rowspec_t, _, ospec_t, vspec_t = \
+        _flash_specs(q, bias, causal, kfast=False, dv=v.shape[-1])
     dk, dv = _kernel_call(
         "attn_flash_bwd_dkv",
         functools.partial(_flash_dkdv_kernel, scale=scale, p_drop=p_drop,
                           n_heads=H, nq=nt, nk=nt, causal=causal),
         grid=(B, H, nt, nt),
-        in_specs=[smem, qspec_t, kspec_t, kspec_t, bspec_t, qspec_t,
+        in_specs=[smem, qspec_t, kspec_t, vspec_t, bspec_t, ospec_t,
                   rowspec_t, rowspec_t],
-        out_specs=[kspec_t, kspec_t],
+        out_specs=[kspec_t, vspec_t],
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
-                   jax.ShapeDtypeStruct(q.shape, f32)],
+                   jax.ShapeDtypeStruct(v.shape, f32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
     )(seed, q, k, v, bias, do, lse, dd)
     dbp = dbp.reshape(B, H, nt, S)
@@ -1946,19 +1968,20 @@ def _use_kernel(q, p_drop):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 7))
 def _fused(q, k, v, bias, scale, p_drop, seed, causal=False):
-    if _use_kernel(q, p_drop):
+    two = v.shape[-1] != q.shape[-1]    # v of its own width: flash only
+    if not two and _use_kernel(q, p_drop):
         return _pallas_attention(q, k, v, bias, scale, p_drop, seed, causal)
-    if _use_long_kernel(q, p_drop, bias):
+    if not two and _use_long_kernel(q, p_drop, bias):
         return _pallas_attention_long(q, k, v, bias, scale, p_drop, seed,
                                       causal)
-    if _use_flash_kernel(q, p_drop, bias):
+    if _use_flash_kernel(q, p_drop, bias, v):
         return _pallas_attention_flash(q, k, v, bias, scale, p_drop,
                                        seed, causal)[0]
     return _fallback_attention(q, k, v, bias, scale, p_drop, seed, causal)
 
 
 def _fused_fwd(q, k, v, bias, scale, p_drop, seed, causal=False):
-    if _use_flash_kernel(q, p_drop, bias):
+    if _use_flash_kernel(q, p_drop, bias, v):
         # the split backward regenerates probabilities from the row
         # logsumexp and needs rowsum(do*o), so o and lse join the
         # residuals (flash-attention-2 residual set: q, k, v, o, L)
@@ -1978,10 +2001,11 @@ def _fused_bwd(scale, p_drop, causal, res, do):
             q, k, v, bias, seed, do, o, lse, scale, p_drop, causal)
         return (dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype),
                 dbias.astype(bias.dtype), _seed_ct(seed))
-    if _use_kernel(q, p_drop):
+    two = v.shape[-1] != q.shape[-1]
+    if not two and _use_kernel(q, p_drop):
         dq, dk, dv, dbias = _pallas_attention_bwd(q, k, v, bias, seed, do,
                                                scale, p_drop, causal)
-    elif _use_long_kernel(q, p_drop, bias):
+    elif not two and _use_long_kernel(q, p_drop, bias):
         dq, dk, dv, dbias = _pallas_attention_long_bwd(
             q, k, v, bias, seed, do, scale, p_drop, causal)
     else:
@@ -2012,7 +2036,11 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
     """softmax(q·kᵀ·scale + bias)·v fused per (batch, head).
 
     q/k/v: [B, H, S, d]; bias broadcastable [B, 1|H, 1|S, S] additive
-    (0 keep / -1e4 mask); returns [B, H, S, d] in q's dtype. ``causal``
+    (0 keep / -1e4 mask); returns [B, H, S, d] in q's dtype. v may have a
+    width ``dv`` of its own ([B, H, S, dv]; latent attention: 192-wide q
+    and k, 128-wide v): the result is then [B, H, S, dv], the flash tier
+    serves every S that a tile divides (the block and long tiers hold one
+    width) and the jnp forms everything else. ``causal``
     masks column > row inside the kernels, by index: no [S, S] bias, so
     the flash tier (row-broadcast bias only) stays open to it.
     ``select`` [B, S, S] (integer, nonzero = the query may see the key;

@@ -1,12 +1,31 @@
 """What the decoder-only model files here share (``qwen3_next.py``,
-``keye_vl2.py``): a parameter's attribute, the bias-free projection, the
-RMS norm, one expert-parallel rank's share of a routed expert layer, and
-the training program round a decoder (next-token loss, Adam,
-recomputation at the layer boundaries, AMP). A config ``cfg`` is any
-object with the published key names these read."""
+``keye_vl2.py``, ``kimi_linear.py``): a parameter's attribute, the
+bias-free projection, the RMS norm, one expert-parallel rank's share of a
+routed expert layer, and the training program round a decoder (next-token
+loss, Adam, recomputation at the layer boundaries, AMP). A config ``cfg``
+is any object with the published key names these read; ``DecoderConfig``
+is what the model files' config classes share."""
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers, optimizer
+
+
+class DecoderConfig:
+    """A model file's config class sets its published keys to the
+    published values in ``__init__`` and then calls ``_override(kw)``."""
+
+    def _override(self, kw):
+        for k, v in kw.items():
+            if not hasattr(self, k):
+                raise TypeError("%s has no key %r" % (type(self).__name__, k))
+            setattr(self, k, v)
+
+    @classmethod
+    def from_dict(cls, d):
+        """From a configuration file's dict; keys this class lacks (the
+        file's notes, keys that shape no step) are passed over."""
+        probe = cls()
+        return cls(**{k: v for k, v in d.items() if hasattr(probe, k)})
 
 
 def attr(name, cfg, trainable=True):
@@ -30,11 +49,21 @@ def rms(x, name, cfg, zero_centered=True):
 def routed_experts(x, cfg, p):
     """The router over all ``num_experts_total`` experts and the part of
     the result that the ``num_experts`` held here (from ``expert_offset``
-    on) give (``fluid/ops/moe_ops.py``)."""
+    on) give (``fluid/ops/moe_ops.py``). A config with
+    ``moe_router_activation_func`` ``"sigmoid"`` gets the sigmoid router:
+    its selection-only bias (``<p>_router_bias``, frozen, zero) and its
+    ``routed_scaling_factor``."""
+    more = {}
+    if getattr(cfg, "moe_router_activation_func", "softmax") == "sigmoid":
+        more = dict(
+            scoring="sigmoid",
+            bias_attr=fluid.ParamAttr(name=p + "_router_bias",
+                                      trainable=False),
+            routed_scaling_factor=cfg.routed_scaling_factor)
     ids, wts = layers.moe_route(
         x, cfg.num_experts_total, cfg.num_experts_per_tok,
         norm_topk_prob=cfg.norm_topk_prob,
-        param_attr=attr(p + "_router_w", cfg), name=p + "_route")
+        param_attr=attr(p + "_router_w", cfg), name=p + "_route", **more)
     return layers.moe_experts(
         x, ids, wts, cfg.num_experts, cfg.moe_intermediate_size,
         expert_offset=cfg.expert_offset, experts_total=cfg.num_experts_total,

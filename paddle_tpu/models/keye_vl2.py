@@ -35,7 +35,7 @@ from .decoder_blocks import proj as _proj
 from .decoder_blocks import rms as _rms
 
 
-class KeyeVL2Config:
+class KeyeVL2Config(decoder_blocks.DecoderConfig):
     """The keys of the model's ``config.json`` that shape a step, under
     their published names (``sa_config`` and ``rope_scaling`` as the nested
     groups they are). ``num_experts`` counts the experts HELD here;
@@ -64,18 +64,8 @@ class KeyeVL2Config:
         self.moe_intermediate_size = 768
         self.initializer_range = 0.02
         self.embedding_std = None
-        for k, v in kw.items():
-            if not hasattr(self, k):
-                raise TypeError("KeyeVL2Config has no key %r" % k)
-            setattr(self, k, v)
+        self._override(kw)
         assert self.sa_config["indexer_num_kv_heads"] == 1, self.sa_config
-
-    @classmethod
-    def from_dict(cls, d):
-        """From a configuration file's dict; keys this class lacks (the
-        file's notes, keys that shape no step) are passed over."""
-        probe = cls()
-        return cls(**{k: v for k, v in d.items() if hasattr(probe, k)})
 
 
 def _heads_first(t):

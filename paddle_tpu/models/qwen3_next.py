@@ -29,7 +29,7 @@ from .decoder_blocks import proj as _proj
 from .decoder_blocks import rms as _rms
 
 
-class Qwen3NextConfig:
+class Qwen3NextConfig(decoder_blocks.DecoderConfig):
     """The keys of the model's ``config.json`` that shape a step, under
     their published names. ``num_experts`` counts the experts HELD here;
     ``num_experts_total`` is the router's width."""
@@ -59,17 +59,7 @@ class Qwen3NextConfig:
         self.shared_expert_intermediate_size = 512
         self.initializer_range = 0.02
         self.gdn_chunk_size = 64
-        for k, v in kw.items():
-            if not hasattr(self, k):
-                raise TypeError("Qwen3NextConfig has no key %r" % k)
-            setattr(self, k, v)
-
-    @classmethod
-    def from_dict(cls, d):
-        """From a configuration file's dict; keys this class lacks (the
-        file's notes, keys that shape no step) are passed over."""
-        probe = cls()
-        return cls(**{k: v for k, v in d.items() if hasattr(probe, k)})
+        self._override(kw)
 
     def is_full_attention(self, i):
         return (i + 1) % self.full_attention_interval == 0

@@ -126,3 +126,43 @@ def test_sparse_index_compiles_for_v5e_at_the_cells_shape(compiled_text):
         lambda q, k, w: sparse_index_select(q, k, w, KTOPK, 512),
         [(KB, KHI, KS, KDI), (KB, KS, KDI), (KB, KS, KHI)])
     assert "s8[1,16384,16384]" in text
+
+
+# kimi-linear-48b-a3b.train-s16384: batch, sequence, heads, head dim, chunk
+# of the channel-gated rule; latent attention's heads and its two widths
+NB, NS, NH, ND, NC = 1, 16384, 32, 128, 64
+NDQK, NDV = 192, 128
+
+
+def test_channel_gated_kernels_compile_for_v5e_at_the_cells_shape(
+        compiled_text):
+    """``kda_chunk_fwd`` and ``kda_chunk_bwd``: the levels' masks, the
+    f32-exact 0/1 matmuls, the transposed state and the gate's f32 block
+    beside q and k fit the kernels' VMEM limit and pass Mosaic."""
+    from paddle_tpu.kernels import delta_rule
+
+    text = compiled_text(
+        jax.grad(lambda *a: jnp.sum(delta_rule._core(
+            *a, ND, ND, NC, 1e-6).astype(jnp.float32)),
+            argnums=(0, 1, 2, 3, 4)),
+        [(NB, NS, NH * ND)] * 3 + [((NB, NS, NH * ND), jnp.float32),
+                                   ((NB, NH, NS // 128, 128), jnp.float32)])
+    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    assert "gdn_chunk" not in text
+
+
+def test_flash_kernels_at_two_widths_compile_for_v5e_at_the_cells_shape(
+        compiled_text, monkeypatch):
+    """192-wide q and k against 128-wide v: a block whose last dim is no
+    multiple of 128 lanes passes Mosaic in all three kernels."""
+    from paddle_tpu.kernels import attention as A
+
+    monkeypatch.setattr(A, "_supports_pallas", lambda: True)
+    text = compiled_text(
+        jax.grad(lambda q, k, v: jnp.sum(A.fused_attention(
+            q, k, v, scale=NDQK ** -0.5, causal=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)),
+        [(NB, NH, NS, NDQK)] * 2 + [(NB, NH, NS, NDV)])
+    for name in ("attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv"):
+        assert name in text
+    assert "bf16[1,32,16384,128]" in text       # o and dv at v's own width

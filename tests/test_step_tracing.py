@@ -229,10 +229,19 @@ def test_every_pallas_call_carries_a_name_of_the_table():
         assert any(n.startswith(stem) for n in names), tier
     # the delta rule's kernels: named, and never under the attention
     # metrics' prefix
+    # (a site names its kernel by the rule: ``"kda_x" if channel else
+    # "gdn_x"`` - the two branches are read, never the condition)
     gdn = [c.args[0] for c in _calls(delta_rule, "named_pallas_call")]
-    assert all(isinstance(a, ast.Constant) for a in gdn)
-    assert sorted(a.value for a in gdn) == sorted(delta_rule.KERNEL_NAMES)
-    assert all(n.startswith("gdn_") for n in delta_rule.KERNEL_NAMES)
+    pairs = []
+    for a in gdn:
+        assert isinstance(a, ast.IfExp), ast.dump(a)
+        assert all(isinstance(n, ast.Constant) and isinstance(n.value, str)
+                   for n in (a.body, a.orelse)), ast.dump(a)
+        pairs.append((a.body.value, a.orelse.value))
+    for kda, scalar in pairs:       # one stem a site, a name for each rule
+        assert kda.startswith("kda_") and scalar == "gdn_" + kda[4:]
+    assert sorted(n for p in pairs for n in p) == sorted(
+        delta_rule.KERNEL_NAMES)
     assert not set(delta_rule.KERNEL_NAMES) & set(attention.KERNEL_NAMES)
 
 
