@@ -62,6 +62,9 @@ def _real_sizes():
         paged_pages=(16, 128),
         # B, S, Hk, Hv, d: one row of qwen3-next-80b-a3b.train-s8192
         delta_rule_case=(1, 8192, 16, 32, 128),
+        # B, S, C: the convolution of qwen3-next-80b-a3b.train-s8192 and
+        # of kimi-linear-48b-a3b.train-s16384
+        conv_cases=((2, 8192, 8192), (1, 16384, 12288)),
         transformer=transformer.Transformer.big,
         vocab=32000,
         serve=dict(batch_size=8, src_len=128, prompt_len=64,
@@ -86,6 +89,7 @@ def _toy_sizes():
         decode_case=(2, 2, 1024),
         paged_pages=(128,),
         delta_rule_case=(1, 256, 1, 2, 128),
+        conv_cases=((2, 512, 256),),
         transformer=transformer.Transformer.tiny,
         vocab=512,
         serve=dict(batch_size=4, src_len=8, prompt_len=4,
@@ -417,12 +421,55 @@ def _check_delta_rule(B, S, Hk, Hv, d, chunk=64):
             "tier": "gdn_pallas", "err": _sig(max(errs))}
 
 
+def _check_conv(B, S, C, K=4):
+    """The short convolution's kernels (kernels/causal_conv.py) in bf16
+    with the SiLU inside, forward, dx and dw, against the XLA form of the
+    same equations (``fluid/ops/linear_attention.py``); the largest
+    difference of each is reported."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.fluid.ops import linear_attention
+
+    def traced():
+        return {i: monitor.counter("conv_dispatch_total",
+                                   labels={"impl": i}).value
+                for i in ("pallas", "pallas_bwd")}
+
+    ks = jax.random.split(jax.random.PRNGKey(31), 3)
+    x = jax.random.normal(ks[0], (B, S, C)).astype(jnp.bfloat16)
+    w = (0.5 * jax.random.normal(ks[1], (C, K))).astype(jnp.bfloat16)
+    dy = jax.random.normal(ks[2], (B, S, C)).astype(jnp.bfloat16)
+
+    def run(conv):
+        y, vjp = jax.vjp(conv, x, w)
+        return (y,) + vjp(dy)
+
+    before = traced()
+    got = jax.jit(lambda: run(lambda x, w: linear_attention.causal_conv(
+        x, w, "swish")))()
+    missing = [i for i, n in traced().items() if n <= before[i]]
+    assert not missing, "causal conv: never traced %r" % missing
+    want = jax.jit(lambda: run(linear_attention._causal_conv(True)))()
+    diffs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                   - b.astype(jnp.float32))))
+             for a, b in zip(got, want)]
+    errs = [_rel_err(a, b) for a, b in zip(got, want)]
+    assert max(errs) < _TOL, (
+        "causal_conv pallas B=%d S=%d C=%d: y dx dw %r" % (B, S, C, errs))
+    return {"S": S, "B": B, "C": C, "dtype": "bfloat16",
+            "tier": "conv_pallas", "err": _sig(max(errs)),
+            "max_diff_y_dx_dw": [_sig(d) for d in diffs]}
+
+
 def phase_kernels(sz):
     import jax.numpy as jnp
 
     from paddle_tpu.kernels import attention as A
 
     cases = [_check_delta_rule(*sz.delta_rule_case)]
+    cases += [_check_conv(*case) for case in sz.conv_cases]
     B, H, C = sz.decode_case
     for dtype in (jnp.float32, jnp.bfloat16):
         for S, b, h, tier in sz.fused_cases:

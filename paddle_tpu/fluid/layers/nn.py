@@ -1788,9 +1788,10 @@ def rotary_embedding(x, rotary_dim=None, theta=10000.0, positions=None,
     return out
 
 
-def causal_conv1d(input, kernel_size, param_attr=None, name=None):
+def causal_conv1d(input, kernel_size, param_attr=None, act=None, name=None):
     """Depthwise causal convolution along axis 1 of ``input`` [B, S, C]:
-    one ``kernel_size``-tap filter a channel, no bias."""
+    one ``kernel_size``-tap filter a channel, no bias; ``act`` is None or
+    ``"swish"``, applied inside the op to the float32 sum."""
     helper = LayerHelper("causal_conv1d", **locals())
     w = helper.create_parameter(
         param_attr, [int(input.shape[-1]), int(kernel_size)],
@@ -1798,7 +1799,8 @@ def causal_conv1d(input, kernel_size, param_attr=None, name=None):
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(type="causal_conv1d",
                      inputs={"X": [input], "Filter": [w]},
-                     outputs={"Out": [out]})
+                     outputs={"Out": [out]},
+                     attrs={"activation": act or ""})
     return out
 
 

@@ -37,15 +37,19 @@ from ..registry import register
 _BASE = 16      # the diagonal blocks inverted by forward substitution
 
 
-def _count(impl):
+def _count(impl, name="gdn_dispatch_total", op="gated_delta_rule"):
     """Trace-time record of which implementation a dispatch took (one per
     traced site, not per step), beside ``attn_kernel_dispatch_total``."""
     from .. import monitor
 
     monitor.counter(
-        "gdn_dispatch_total",
-        "gated_delta_rule lowerings traced, by implementation (trace-time: "
+        name, op + " lowerings traced, by implementation (trace-time: "
         "one per traced program, not per step)", labels={"impl": impl}).inc()
+
+
+def _count_conv(impl):
+    """The convolution's: ``pallas``, ``pallas_bwd`` or ``xla``."""
+    _count(impl, "conv_dispatch_total", "causal_conv1d")
 
 
 def _taps(xp, w, S):
@@ -58,26 +62,35 @@ def _taps(xp, w, S):
 
 
 @functools.lru_cache(maxsize=None)
-def _causal_conv():
-    """``conv(x [B, S, C], w [C, K])`` with a backward written out: the
-    input gradient is the same K taps run the other way over the padded
-    cotangent, so what stays live is x and w, not K f32 copies of x."""
+def _causal_conv(silu=False):
+    """``act(conv(x [B, S, C], w [C, K]))`` in plain XLA with a backward
+    written out: the input gradient is the same K taps run the other way
+    over the padded cotangent, so what stays live is x and w, not K f32
+    copies of x. With ``silu`` the activation ``z * sigmoid(z)`` is applied
+    to the f32 sum before the one rounding, and the backward makes z again
+    from x. The kernels' oracle (``kernels/causal_conv.py`` has the
+    equations)."""
     import jax
     import jax.numpy as jnp
 
+    def padded(x, K):       # K - 1 zero rows before the start, in f32
+        return jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32)
+
     @jax.custom_vjp
     def conv(x, w):
-        K = w.shape[1]
-        return _taps(jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))), w,
-                     x.shape[1]).astype(x.dtype)
+        z = _taps(padded(x, w.shape[1]), w, x.shape[1])
+        return (z * jax.nn.sigmoid(z) if silu else z).astype(x.dtype)
 
     def bwd(res, dy):
         x, w = res
         K, S = w.shape[1], x.shape[1]
-        dx = _taps(jnp.pad(dy, ((0, 0), (0, K - 1), (0, 0))), w[:, ::-1], S)
-        xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32)
-        dyf = dy.astype(jnp.float32)
-        dw = jnp.stack([jnp.sum(dyf * xp[:, j:j + S, :], axis=(0, 1))
+        xp, dz = padded(x, K), dy.astype(jnp.float32)
+        if silu:
+            z = _taps(xp, w, S)
+            s = jax.nn.sigmoid(z)
+            dz = dz * (s * (1.0 + z * (1.0 - s)))
+        dx = _taps(jnp.pad(dz, ((0, 0), (0, K - 1), (0, 0))), w[:, ::-1], S)
+        dw = jnp.stack([jnp.sum(dz * xp[:, j:j + S, :], axis=(0, 1))
                         for j in range(K)], axis=1)
         return dx.astype(x.dtype), dw.astype(w.dtype)
 
@@ -85,14 +98,35 @@ def _causal_conv():
     return conv
 
 
+def causal_conv(x, w, activation=""):
+    """``act(conv(x, w))`` by the Pallas kernels where the input allows
+    them (``kernels/causal_conv.py:supported``: shapes, dtype, a TPU or
+    the interpreter), else by the XLA form of the same equations."""
+    from ...kernels import causal_conv as kernels
+
+    if activation not in ("", "swish"):
+        raise ValueError("causal_conv1d: activation %r is neither '' nor "
+                         "'swish'" % (activation,))
+    silu = activation == "swish"
+    if kernels.supported(x.shape, w.shape, x.dtype):
+        _count_conv("pallas")
+        return kernels.causal_conv_pallas(x, w, silu)
+    _count_conv("xla")
+    return _causal_conv(silu)(x, w)
+
+
 @register("causal_conv1d")
 def _causal_conv1d(ctx, op):
     """Depthwise causal convolution along the sequence: X [B, S, C],
-    Filter [C, K], ``Out[t] = sum_j Filter[:, j] * X[t - (K-1) + j]``
-    (zeros before the start), no bias. K shifted multiply-adds in f32:
-    for K = 4 that is cheaper on the chip than a grouped convolution."""
-    ctx.set_output(op, "Out", _causal_conv()(ctx.get_input(op, "X"),
-                                             ctx.get_input(op, "Filter")))
+    Filter [C, K], ``Out[t] = act(sum_j Filter[:, j] * X[t - (K-1) + j])``
+    (zeros before the start), no bias; ``activation`` is ``""`` or
+    ``"swish"`` (``z * sigmoid(z)`` on the f32 sum, rounded once). K
+    shifted multiply-adds in f32, as one Pallas kernel forward and one
+    backward or in XLA: for K = 4 that is cheaper on the chip than a
+    grouped convolution."""
+    ctx.set_output(op, "Out", causal_conv(
+        ctx.get_input(op, "X"), ctx.get_input(op, "Filter"),
+        op.attrs.get("activation", "")))
 
 
 def _inv_blocks(m):

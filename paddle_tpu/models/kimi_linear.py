@@ -108,10 +108,9 @@ def _low_rank(x, rank, size, p, cfg):
 def _kda(x, cfg, p):
     lin = cfg.linear_attn_config
     H, d, K = lin["num_heads"], lin["head_dim"], lin["short_conv_kernel_size"]
-    qkv = layers.swish(layers.causal_conv1d(
+    qkv = layers.causal_conv1d(
         _proj(x, 3 * H * d, p + "_qkv", cfg), K,
-        param_attr=_attr(p + "_conv_w", cfg), name=p + "_conv"),
-        name=p + "_conv_act")
+        param_attr=_attr(p + "_conv_w", cfg), act="swish", name=p + "_conv")
     q, k, v = (layers.reshape(t, [0, 0, H, d]) for t in
                layers.split(qkv, 3, dim=-1, name=p + "_split"))
     o = layers.gated_delta_rule(

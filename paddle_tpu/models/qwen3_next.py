@@ -72,10 +72,9 @@ def _gated_delta_net(x, cfg, p):
     qkvz = _proj(x, 2 * kd + 2 * vd, p + "_qkvz", cfg)
     ba = _proj(x, 2 * Hv, p + "_ba", cfg)
     qkv, z = layers.split(qkvz, [2 * kd + vd, vd], dim=-1, name=p + "_split")
-    qkv = layers.swish(layers.causal_conv1d(
+    qkv = layers.causal_conv1d(
         qkv, cfg.linear_conv_kernel_dim,
-        param_attr=_attr(p + "_conv_w", cfg), name=p + "_conv"),
-        name=p + "_conv_act")
+        param_attr=_attr(p + "_conv_w", cfg), act="swish", name=p + "_conv")
     q, k, v = layers.split(qkv, [kd, kd, vd], dim=-1, name=p + "_qkv")
     b, a = layers.split(ba, 2, dim=-1, name=p + "_ba_split")
     o = layers.gated_delta_rule(

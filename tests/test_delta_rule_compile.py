@@ -1,6 +1,6 @@
-"""The delta rule's kernels, the causal flash attention's, and the select
-tier's with its selection op, each at its benchmark cell's shape, compiled
-for a DESCRIBED v5e (no chip attached):
+"""The delta rule's kernels, the causal flash attention's, the select
+tier's with its selection op and the short convolution's, each at its
+benchmark cell's shape, compiled for a DESCRIBED v5e (no chip attached):
 what the chip's compiler refuses - a slice off the tiling, too much
 VMEM, an op Mosaic cannot lower - it refuses here, at no chip time.
 Nothing runs, so nothing is said about results or speed. The topology is
@@ -166,3 +166,24 @@ def test_flash_kernels_at_two_widths_compile_for_v5e_at_the_cells_shape(
     for name in ("attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv"):
         assert name in text
     assert "bf16[1,32,16384,128]" in text       # o and dv at v's own width
+
+
+@pytest.mark.parametrize("shape", [(B, S, HK * D * 2 + HV * D),
+                                   (NB, NS, 3 * NH * ND)])
+def test_conv_kernels_compile_for_v5e_at_the_cells_shapes(compiled_text,
+                                                          shape):
+    """``conv_silu_fwd`` and ``conv_silu_bwd`` over the q | k | v bank of a
+    DeltaNet layer ([2, 8192, 8192]) and of a KDA layer ([1, 16384,
+    12288]) in bf16: the f32 copies of a (1024, 512) block, the shifted
+    loads and the halo's clamped block pass Mosaic inside the default VMEM
+    limit. To be run before any chip call that changes a kernel."""
+    from paddle_tpu.kernels import causal_conv
+
+    def both(x, w, dy):
+        y, vjp = jax.vjp(
+            lambda x, w: causal_conv.causal_conv_pallas(x, w, True), x, w)
+        return (y,) + vjp(dy)
+
+    text = compiled_text(both, [shape, (shape[-1], 4), shape])
+    assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
+    assert causal_conv._largest(causal_conv.TILES, shape[1]) == 1024

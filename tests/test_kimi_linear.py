@@ -156,6 +156,17 @@ def test_new_counters_say_which_implementation_was_traced(both_sides):
                            labels={"impl": "kda_chunked"}).value > 0
     assert monitor.counter("moe_route_dispatch_total",
                            labels={"scoring": "sigmoid"}).value > 0
+    assert monitor.counter("conv_dispatch_total",
+                           labels={"impl": "xla"}).value > 0
+
+
+def test_the_convolutions_carry_their_activation_and_no_swish_op_is_left(
+        both_sides):
+    ops = both_sides[0]["step"].main.global_block().ops
+    convs = [op for op in ops if op.type == "causal_conv1d"]
+    assert len(convs) == 4      # the KDA layers of the toy's five
+    assert all(op.attrs["activation"] == "swish" for op in convs)
+    assert not [op for op in ops if op.type == "swish"]
 
 
 def test_config_takes_published_names_and_startup_draws_as_it_says():

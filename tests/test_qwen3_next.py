@@ -312,6 +312,19 @@ def test_new_counters_say_which_implementation_was_traced(both_sides):
                            labels={"impl": "chunked"}).value > 0
     assert monitor.counter("moe_dispatch_total",
                            labels={"impl": "ragged_loop"}).value > 0
+    assert monitor.counter("conv_dispatch_total",
+                           labels={"impl": "xla"}).value > 0
+
+
+def test_the_convolution_carries_its_activation_and_no_swish_op_is_left():
+    from paddle_tpu.models import qwen3_next
+
+    main, _, _ = qwen3_next.build_train_program(
+        qwen3_next.Qwen3NextConfig.from_dict(TOY), 1, 16)
+    ops = main.global_block().ops
+    convs = [op for op in ops if op.type == "causal_conv1d"]
+    assert convs and all(op.attrs["activation"] == "swish" for op in convs)
+    assert not [op for op in ops if op.type == "swish"]
 
 
 def test_newest_step_regions_files_instructions_under_program_ops(

@@ -208,7 +208,7 @@ def _calls(module, func_name):
 
 
 def test_every_pallas_call_carries_a_name_of_the_table():
-    from paddle_tpu.kernels import common, delta_rule
+    from paddle_tpu.kernels import causal_conv, common, delta_rule
 
     # the package's one raw call is the shared helper's, and it passes the
     # name on; neither kernel file makes one of its own
@@ -217,6 +217,7 @@ def test_every_pallas_call_carries_a_name_of_the_table():
                and kw.value.id == "name" for kw in call.keywords)
     assert not _calls(attention, "pallas_call")
     assert not _calls(delta_rule, "pallas_call")
+    assert not _calls(causal_conv, "pallas_call")
     (lifted,) = _calls(attention, "named_pallas_call")  # in _kernel_call
     assert isinstance(lifted.args[0], ast.Name)
     named = [c.args[0] for c in _calls(attention, "_kernel_call")]
@@ -243,6 +244,14 @@ def test_every_pallas_call_carries_a_name_of_the_table():
     assert sorted(n for p in pairs for n in p) == sorted(
         delta_rule.KERNEL_NAMES)
     assert not set(delta_rule.KERNEL_NAMES) & set(attention.KERNEL_NAMES)
+    # the short convolution's: constants, a name each, shared with neither
+    conv = [c.args[0] for c in _calls(causal_conv, "named_pallas_call")]
+    assert all(isinstance(a, ast.Constant) for a in conv)
+    assert sorted(a.value for a in conv) == sorted(causal_conv.KERNEL_NAMES)
+    assert not set(causal_conv.KERNEL_NAMES) & (
+        set(attention.KERNEL_NAMES) | set(delta_rule.KERNEL_NAMES))
+    assert not any(n.startswith(("attn_", "gdn_", "kda_"))
+                   for n in causal_conv.KERNEL_NAMES)
 
 
 def test_lowered_text_names_the_program_op_of_every_operation(trained):
