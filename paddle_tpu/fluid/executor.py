@@ -575,12 +575,12 @@ class _CompiledStep:
         """The compiled module as HLO text, each instruction with the
         ``op_name`` it was traced under: what the profiler's region
         table reads, since the device trace names an operation but does
-        not carry its metadata. Lowered again from the noted shapes
-        (JAX's caches make that seconds); None where the step cannot be
+        not carry its metadata. From ``profiler.step_lowering`` (the
+        newest step shares its one lowering with the region table and
+        ``newest_step_memory``); None where the step cannot be lowered
         (never called, or a disk-tier or sharded wrapper)."""
-        if self.arg_specs is None or not hasattr(self.fn, "lower"):
-            return None
-        return self.fn.lower(*self.arg_specs).compile().as_text()
+        lowering = _prof.step_lowering(self.fn, self.arg_specs)
+        return lowering[0] if lowering is not None else None
 
 
 _LIVE_STEPS = weakref.WeakSet()
@@ -864,14 +864,17 @@ class Executor:
                 "single steps already overlap via async dispatch "
                 "(fetch_mode='async')")
         _prof.begin_run()
-        t_run0 = time.perf_counter()
-        with _prof.RecordEvent(_prof.SPAN_PREPARE):
-            prep = self._prepare(program, feed, fetch_list, scope, iters,
-                                 prefetch, checkpoint)
-        if prep is None:    # a server program: its serving loop has ended
-            return []
-        return self._run_prepared(prep, t_run0, return_numpy, iters,
-                                  fetch_mode, prefetch, checkpoint)
+        try:
+            t_run0 = time.perf_counter()
+            with _prof.RecordEvent(_prof.SPAN_PREPARE):
+                prep = self._prepare(program, feed, fetch_list, scope,
+                                     iters, prefetch, checkpoint)
+            if prep is None:    # a server program: its loop has ended
+                return []
+            return self._run_prepared(prep, t_run0, return_numpy, iters,
+                                      fetch_mode, prefetch, checkpoint)
+        finally:
+            _prof.end_run()
 
     def _prepare(self, program, feed, fetch_list, scope, iters, prefetch,
                  checkpoint):

@@ -9,12 +9,14 @@ whoever takes a device trace (``start_profiler(trace_dir=...)``, a
 benchmark driver, an operator's ``jax.profiler.start_trace``) finds the
 program's spans in the ``/host:CPU`` plane of the same ``.xplane.pb``, on
 the clock of the device's ``XLA Ops`` line. The spans of ``PHASE_SPANS``
-(where ``Executor.run`` does its work) are also kept in a ring whether or
-not the profiler was started: the last seconds of any run can be read back
-with ``recent_spans``. The summary table keeps the reference's shape
-(Event / Calls / Total / Min / Max / Ave / Ratio); after a device trace
-``stop_profiler`` appends device time by region, read from the names
-``registry.lower_op`` and the Pallas kernels put on the device's work.
+(where ``Executor.run`` does its work, and the stages of every compile JAX
+makes in the process, as ``jax.monitoring`` reports them) are also kept in
+a ring whether or not the profiler was started: the last seconds of any
+run can be read back with ``recent_spans``. The summary table keeps the
+reference's shape (Event / Calls / Total / Min / Max / Ave / Ratio); after
+a device trace ``stop_profiler`` appends device time by region, read from
+the names ``registry.lower_op`` and the Pallas kernels put on the device's
+work.
 """
 
 import contextlib
@@ -26,13 +28,15 @@ import threading
 import time
 from collections import OrderedDict, deque
 
+from jax import monitoring as _jax_monitoring
 from jax.profiler import TraceAnnotation
 
 from . import monitor as _monitor
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
            "export_chrome_tracing", "dropped_span_count", "recent_spans",
-           "device_time_by_region", "RecordEvent", "PHASE_SPANS",
+           "union_seconds", "device_time_by_region", "newest_step_regions",
+           "newest_step_memory", "RecordEvent", "PHASE_SPANS", "JAX_SPANS",
            "cuda_profiler", "npu_profiler"]
 
 # -- the program's span names: the one table ---------------------------------
@@ -47,10 +51,22 @@ SPAN_FETCH = "executor.fetch"         # the one phase that waits for the device
 SPAN_CACHE_LOAD = "compile_cache.load"
 SPAN_CACHE_COMPILE = "compile_cache.compile"
 SPAN_CACHE_SAVE = "compile_cache.save"
+# the stages of a compile as JAX itself reports them (``jax.monitoring``),
+# for EVERY jitted function of the process: inside executor.compile under
+# that run's id, under run id 0 anywhere else (a driver's jitted helpers,
+# the per-op compiles of a dygraph eager trace). A function traced inside
+# another reports its own span inside the outer one's: read a name's time
+# with ``union_seconds``, never as the plain sum.
+SPAN_JAX_TRACE = "jax.trace"          # Python's walk of the ops into a jaxpr
+SPAN_JAX_LOWER = "jax.lower"          # jaxpr to StableHLO, Mosaic's inside
+SPAN_JAX_COMPILE = "jax.backend_compile"    # XLA's compile: no cache had it
+SPAN_JAX_CACHE_LOAD = "jax.cache_load"      # read from the persistent cache
+JAX_SPANS = (SPAN_JAX_TRACE, SPAN_JAX_LOWER, SPAN_JAX_COMPILE,
+             SPAN_JAX_CACHE_LOAD)
 # always in the ring and in profiler_event_seconds: a fixed, small set
 PHASE_SPANS = frozenset({
     SPAN_PREPARE, SPAN_COMPILE, SPAN_CALL, SPAN_COMMIT, SPAN_FETCH,
-    SPAN_CACHE_LOAD, SPAN_CACHE_COMPILE, SPAN_CACHE_SAVE})
+    SPAN_CACHE_LOAD, SPAN_CACHE_COMPILE, SPAN_CACHE_SAVE, *JAX_SPANS})
 
 _enabled = False
 _events = OrderedDict()  # name -> [calls, total, min, max]
@@ -99,9 +115,16 @@ def dropped_span_count():
 
 def begin_run():
     """A new run id for the ``Executor.run`` call this thread enters; the
-    phase spans the thread records from here on carry it."""
+    phase spans the thread records until ``end_run`` carry it."""
     _current_run.run_id = run_id = next(_run_ids)
     return run_id
+
+
+def end_run():
+    """The thread's ``Executor.run`` call is over: what it records from
+    here on (a later ``FetchHandle.numpy()``, a compile of the caller's
+    own) is outside any run, under run id 0."""
+    _current_run.run_id = 0
 
 
 def _record(name, seconds, series=True):
@@ -157,6 +180,51 @@ class RecordEvent:
 
 def record_event(name):
     return RecordEvent(name)
+
+
+# -- JAX's own report of a compile's stages ------------------------------------
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": SPAN_JAX_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": SPAN_JAX_LOWER,
+    "/jax/core/compile/backend_compile_duration": SPAN_JAX_COMPILE,
+}
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# whether the backend compile this thread is in found its executable in
+# JAX's persistent cache: JAX reports the hit inside the compile's interval
+# (``compiler.compile_or_get_cached``) and the interval when it closes
+_jax_cache_hit = threading.local()
+
+
+def _on_jax_event(event, **kwargs):
+    if event == _JAX_CACHE_HIT:
+        _jax_cache_hit.seen = True
+
+
+def _on_jax_duration(event, duration_secs, **kwargs):
+    """A finished stage of a compile, as a span that ends now. JAX calls
+    this where it traces, lowers or compiles: never on a cached call."""
+    name = _JAX_STAGES.get(event)
+    if name is None:
+        return
+    if name == SPAN_JAX_COMPILE and getattr(_jax_cache_hit, "seen", False):
+        _jax_cache_hit.seen = False
+        name = SPAN_JAX_CACHE_LOAD
+    _record(name, duration_secs)
+
+
+_jax_monitoring.register_event_listener(_on_jax_event)
+_jax_monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def union_seconds(intervals):
+    """What ``(start, end)`` intervals cover, overlaps counted once: the
+    time of spans that nest (a ``jax.trace`` inside another's) or run
+    side by side (a device's operations)."""
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        total += max(b, end) - max(a, end)
+        end = max(b, end)
+    return total
 
 
 def recent_spans(names=None, last_runs=None):
@@ -330,39 +398,108 @@ def op_names_of(hlo_text):
     return names
 
 
-# The newest compiled step, kept as what its HLO text can be made from
-# again (the jitted function and its arguments' shapes, no array), so
-# that a reader outside the program can file a device instruction under
-# its program op after the step object and its Executor are gone.
+# The newest compiled step, kept as what it can be lowered from again (the
+# jitted function and its arguments' shapes, no array), so that a reader
+# outside the program can file a device instruction under its program op,
+# or ask what the step holds in memory, after the step object and its
+# Executor are gone.
 _NEWEST_STEP = None
+_NEWEST_LOWERING = None     # (HLO text, memory) of it: ONE lowering's
 _NEWEST_REGIONS = None
+STEP_BYTES_KINDS = ("argument", "output", "alias", "temp", "generated_code",
+                    "total")
+_M_STEP_BYTES = {
+    kind: _monitor.gauge(
+        "executor_step_bytes",
+        help="device memory of the newest compiled step as the compiler "
+             "sized it, set where profiler.newest_step_memory() or the "
+             "region table lowered it",
+        labels={"kind": kind})
+    for kind in STEP_BYTES_KINDS}
 
 
 def note_compiled_step(fn, arg_specs):
     """``Executor.run`` calls this where it has compiled a step."""
-    global _NEWEST_STEP, _NEWEST_REGIONS
-    _NEWEST_STEP, _NEWEST_REGIONS = (fn, arg_specs), None
+    global _NEWEST_STEP, _NEWEST_LOWERING, _NEWEST_REGIONS
+    _NEWEST_STEP = (fn, arg_specs)
+    _NEWEST_LOWERING = _NEWEST_REGIONS = None
+
+
+def _step_memory(compiled):
+    """``compiled.memory_analysis()`` as ``{kind: bytes}`` over
+    ``STEP_BYTES_KINDS``; the donated state (``alias``) is in ``argument``
+    and in ``output`` and counts once in ``total``. None where the backend
+    gives no analysis."""
+    stats = compiled.memory_analysis()
+    if stats is None:
+        return None
+    memory = {kind: getattr(stats, kind + "_size_in_bytes")
+              for kind in STEP_BYTES_KINDS[:-1]}
+    memory["total"] = (memory["argument"] + memory["output"]
+                       - memory["alias"] + memory["temp"]
+                       + memory["generated_code"])
+    return memory
+
+
+def step_lowering(fn, arg_specs):
+    """``(HLO text, memory)`` of a compiled step, lowered again from its
+    noted shapes (JAX's caches make that seconds): the module's text with
+    each instruction's ``op_name``, and ``_step_memory`` of the compiler's
+    own analysis, temporaries included. The text and the numbers are
+    kept, not the ``Compiled``; the newest step's are kept here, so its
+    text, region table and memory come from one lowering. None where the
+    step cannot be lowered (never called, or a disk-tier or sharded
+    wrapper)."""
+    global _NEWEST_LOWERING
+    newest = _NEWEST_STEP is not None and _NEWEST_STEP[0] is fn
+    if newest and _NEWEST_LOWERING is not None:
+        return _NEWEST_LOWERING
+    if arg_specs is None or not hasattr(fn, "lower"):
+        return None
+    compiled = fn.lower(*arg_specs).compile()
+    lowering = (compiled.as_text(), _step_memory(compiled))
+    if newest:
+        _NEWEST_LOWERING = lowering
+        for kind, n in (lowering[1] or {}).items():
+            _M_STEP_BYTES[kind].set(n)
+    return lowering
+
+
+def _newest_lowering():
+    return step_lowering(*_NEWEST_STEP) if _NEWEST_STEP is not None else None
 
 
 def newest_step_regions():
     """``{instruction name: (phase, program op type)}`` of the newest
     compiled step's module (``region_of`` of each instruction's
     ``op_name``; ``while`` / ``conditional`` / ``call`` instructions left
-    out, their bodies' being there), built on the first call: the step is lowered again
-    from the noted shapes (JAX's caches make that seconds). None where no
-    step was compiled or it cannot be lowered (a disk-tier or sharded
-    wrapper)."""
+    out, their bodies' being there), built on the first call from
+    ``step_lowering``. None where no step was compiled or it cannot be
+    lowered (a disk-tier or sharded wrapper)."""
     global _NEWEST_REGIONS
-    if _NEWEST_REGIONS is None and _NEWEST_STEP is not None:
-        fn, arg_specs = _NEWEST_STEP
-        if hasattr(fn, "lower"):
-            text = fn.lower(*arg_specs).compile().as_text()
+    if _NEWEST_REGIONS is None:
+        lowering = _newest_lowering()
+        if lowering is not None:
+            text = lowering[0]
             containers = {name for name, rest in _HLO_LINE.findall(text)
                           if _is_container(rest)}
             _NEWEST_REGIONS = {name: region_of(op_name) for name, op_name
                                in op_names_of(text).items()
                                if name not in containers}
     return _NEWEST_REGIONS
+
+
+def newest_step_memory():
+    """What the newest compiled step holds in device memory, in bytes, as
+    the compiler sized it: ``{"argument", "output", "alias", "temp",
+    "generated_code", "total"}``, ``total = argument + output - alias +
+    temp + generated_code``. The allocator's ``peak_bytes_in_use`` leaves
+    a program's temporaries out; this has them. From the region table's
+    lowering, built on the first call of either (an untraced run pays
+    nothing), and then also in the gauges ``executor_step_bytes{kind}``.
+    None where the region table is."""
+    lowering = _newest_lowering()
+    return lowering[1] if lowering is not None else None
 
 
 def _live_hlo_texts(profile):
@@ -467,12 +604,7 @@ def device_time_by_region(profile, hlo_texts=None):
             row[1] += seconds
         intervals.setdefault(plane, []).append(
             (event.start_ns, event.start_ns + event.duration_ns))
-    busy_ns = 0.0
-    for spans in intervals.values():
-        end = float("-inf")
-        for a, b in sorted(spans):
-            busy_ns += max(b, end) - max(a, end)
-            end = max(b, end)
+    busy_ns = sum(union_seconds(spans) for spans in intervals.values())
     return {"phases": phases, "ops": ops, "instructions": instructions,
             "busy_s": busy_ns * 1e-9}
 

@@ -70,8 +70,10 @@ def test_region_table_leaves_the_container_out_so_rows_sum_to_busy():
 
 def test_newest_step_regions_holds_no_container(monkeypatch):
     fn = types.SimpleNamespace(lower=lambda *specs: types.SimpleNamespace(
-        compile=lambda: types.SimpleNamespace(as_text=lambda: HLO_TEXT)))
+        compile=lambda: types.SimpleNamespace(
+            as_text=lambda: HLO_TEXT, memory_analysis=lambda: None)))
     monkeypatch.setattr(profiler, "_NEWEST_STEP", None)
+    monkeypatch.setattr(profiler, "_NEWEST_LOWERING", None)
     monkeypatch.setattr(profiler, "_NEWEST_REGIONS", None)
     assert profiler.newest_step_regions() is None       # no step yet
     profiler.note_compiled_step(fn, (np.zeros(2),))
@@ -79,6 +81,7 @@ def test_newest_step_regions_holds_no_container(monkeypatch):
     assert regions == {"fusion.7": ("forward", "gated_delta_rule"),
                        "fusion.8": ("backward", "moe_experts")}
     assert profiler.newest_step_regions() is regions    # built once
+    assert profiler.newest_step_memory() is None    # a backend without one
     # a step that cannot be lowered again (a disk-tier wrapper)
     profiler.note_compiled_step(object(), ())
     assert profiler.newest_step_regions() is None

@@ -144,8 +144,10 @@ def test_iters_k_records_the_same_names_under_one_run_id(trained):
     ids = sorted({s[1] for s in spans})
     for name, run_id, _, _ in spans:
         (first if run_id == ids[0] else second).setdefault(name, 0)
-    assert set(first) == {profiler.SPAN_PREPARE, profiler.SPAN_COMPILE,
-                          profiler.SPAN_COMMIT, profiler.SPAN_FETCH}
+    # (the compile's own stages, ``jax.*``: tests/test_compile_stages.py)
+    assert set(first) - set(profiler.JAX_SPANS) == {
+        profiler.SPAN_PREPARE, profiler.SPAN_COMPILE, profiler.SPAN_COMMIT,
+        profiler.SPAN_FETCH}
     assert set(second) == {profiler.SPAN_PREPARE, profiler.SPAN_CALL,
                            profiler.SPAN_COMMIT, profiler.SPAN_FETCH}
 
@@ -160,8 +162,8 @@ def test_no_phase_span_waits_for_the_device(trained, monkeypatch):
     (lv,) = exe.run(main, feed=feed, fetch_list=[loss], return_numpy=False)
     (run,) = {s[1] for s in profiler.recent_spans(last_runs=1)}
     names = {s[0] for s in profiler.recent_spans(last_runs=1)}
-    assert run and names == {profiler.SPAN_PREPARE, profiler.SPAN_COMPILE,
-                             profiler.SPAN_COMMIT}
+    assert run and names - set(profiler.JAX_SPANS) == {
+        profiler.SPAN_PREPARE, profiler.SPAN_COMPILE, profiler.SPAN_COMMIT}
     assert np.isfinite(np.asarray(lv)).all()
 
 
