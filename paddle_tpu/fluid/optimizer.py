@@ -881,8 +881,9 @@ class RecomputeOptimizer:
     boundary: the checkpoint vars, and the values a producer inside the
     segment marked as dear to make and small to hold
     (``kernels.common.keep_across_recompute``: the flash and select
-    attention tiers' output and row logsumexp, ``sparse_index``'s mask) -
-    the producer decides, there is nothing to set here."""
+    attention tiers' output and row logsumexp, ``sparse_index``'s mask,
+    the expert layer's dispatch plan and the router's chosen scores and
+    ids) - the producer decides, there is nothing to set here."""
 
     def __init__(self, optimizer):
         self._optimizer = optimizer

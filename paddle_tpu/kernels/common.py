@@ -75,7 +75,10 @@ def keep_across_recompute(x, what):
     marked today (chip runs, PR 32): the select tier's ``o`` and row
     logsumexp, 17.4 ms for 136 MB; ``sparse_index``'s byte mask, 10.6 ms
     for 268 MB; the flash tier's ``o`` and logsumexp, 8.96 ms for 135 MB. A
-    projection's output costs far less a MB and is not marked.
+    projection's output costs far less a MB and is not marked. Since PR 37
+    also ``moe_experts``' dispatch plan (``moe_plan``: a layer's rows,
+    spans and weight table, 1-2.75 MB) and ``moe_route``'s chosen scores
+    and ids (``moe_route``: 1.45 ms of ``top_k`` for 1.3 MB).
 
     ``recompute_kept_bytes_total{what}`` counts the bytes, once a site
     traced inside a checkpointed segment (trace-time, not per step)."""

@@ -236,6 +236,74 @@ def test_moe_experts_dropless_under_skewed_routing(chunk_rows):
                                    atol=2e-5 * float(jnp.abs(b).max()))
 
 
+def _argsort_plan(ids, wts, E, expert_offset, chunk_rows):
+    """The dispatch plan as the program made it before PR 37 (a stable
+    argsort of the P = T * k pairs by expert, a ``bincount``, a P-long
+    gather of the weights): the oracle of the form that follows the pairs
+    held. ``(tok, w_sorted, starts, ends, n_here)``."""
+    T, k = ids.shape
+    P, CH = T * k, int(chunk_rows)
+    n_chunks = -(-P // CH)
+    local = ids.reshape(P) - expert_offset
+    key = jnp.where((local >= 0) & (local < E), local, E)   # E: not held
+    order = jnp.argsort(key, stable=True)
+    ends = jnp.cumsum(jnp.bincount(key, length=E + 1)[:E])
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    tail = n_chunks * CH - P
+    tok = jnp.pad(order // k, (0, tail)).reshape(n_chunks, CH)
+    w_sorted = jnp.pad(wts.reshape(P)[order], (0, tail)).reshape(
+        n_chunks, CH)
+    return tok, w_sorted, starts, ends, ends[-1]
+
+
+# routing -> (skew, first held expert, held experts) of 32 under top-3
+_ROUTINGS = {
+    "uniform": (None, 0, 4),
+    "one_expert_takes_a_pair_of_every_token": (5, 4, 4),
+    "no_pair_held": (None, 40, 4),
+    "every_pair_held": (None, 0, 32),
+    "expert_offset": (None, 26, 6),
+}
+
+
+@pytest.mark.parametrize("chunk_rows", [7, 48, 1000])
+@pytest.mark.parametrize("routing", sorted(_ROUTINGS))
+def test_dispatch_plan_equals_the_argsort_forms(routing, chunk_rows):
+    """The plan made from the held tables, every chunk of it, against the
+    stable argsort's, field by field: the spans, the count, and each
+    sorted row's token and weight below ``n_here`` (rows past it are
+    selected out of the walk and may hold anything)."""
+    from paddle_tpu.fluid.ops import moe_ops
+
+    skew, lo, E = _ROUTINGS[routing]
+    _, ids, wts, _, _, _ = _moe_inputs(T=150, skew=skew)
+    ids = ids.astype(jnp.int32)
+    tok, w_sorted, starts, ends, n_here = _argsort_plan(ids, wts, E, lo,
+                                                        chunk_rows)
+    held, wtab, count, through, got_starts, got_ends = \
+        moe_ops._held_tables(ids, wts, E, lo)
+    place = jax.vmap(lambda c: moe_ops._chunk_plan(
+        (held, count, through), c, chunk_rows))(
+            jnp.arange(tok.shape[0], dtype=jnp.int32))
+    n = int(n_here)
+    assert n == {"no_pair_held": 0, "every_pair_held": ids.size}.get(
+        routing, n) and int(got_ends[-1]) == n
+    if routing == "one_expert_takes_a_pair_of_every_token":
+        assert int(ends[1] - starts[1]) == ids.shape[0]
+    np.testing.assert_array_equal(got_starts, starts)
+    np.testing.assert_array_equal(got_ends, ends)
+    route = (place, got_starts, got_ends, got_ends[-1])
+    got_tok = jax.vmap(lambda c: moe_ops._row_tokens(
+        route, c, jnp.zeros((ids.shape[0], 1)), wtab))(
+            jnp.arange(tok.shape[0]))
+    np.testing.assert_array_equal(np.asarray(got_tok).ravel()[:n],
+                                  np.asarray(tok).ravel()[:n])
+    np.testing.assert_array_equal(
+        np.asarray(wtab).ravel()[np.asarray(place).ravel()[:n]],
+        np.asarray(w_sorted).ravel()[:n])
+    assert np.all(np.diff(np.asarray(place).ravel()[:n]) > 0)
+
+
 @pytest.mark.parametrize("n_here, live", [(0, 1), (8, 1), (9, 2), (24, 3),
                                           (25, 4), (40, 4)])
 def test_moe_walk_stops_after_the_last_chunk_with_a_held_pair(n_here, live):
@@ -311,7 +379,7 @@ def test_new_counters_say_which_implementation_was_traced(both_sides):
     assert monitor.counter("gdn_dispatch_total",
                            labels={"impl": "chunked"}).value > 0
     assert monitor.counter("moe_dispatch_total",
-                           labels={"impl": "ragged_loop"}).value > 0
+                           labels={"impl": "ragged_loop_held"}).value > 0
     assert monitor.counter("conv_dispatch_total",
                            labels={"impl": "xla"}).value > 0
 

@@ -162,19 +162,51 @@ def _kept_bytes(what):
                            labels={"what": what}).value
 
 
-def _bisections(jaxpr, transposed, outer=""):
-    """``kth_largest``'s 32-pass loops in a jaxpr, by whether they sit
-    under a transpose (= are made again in the backward pass)."""
+def _equations(jaxpr, outer=""):
+    """``(equation, its name stack from the step down)`` through every
+    nested jaxpr; ``transpose(`` in a stack = made in the backward pass."""
     import jax
 
-    n = 0
     for eqn in jaxpr.eqns:
         stack = outer + str(eqn.source_info.name_stack)
-        if eqn.primitive.name == "scan" and eqn.params["length"] == 32:
-            n += ("transpose(" in stack) == transposed
+        yield eqn, stack
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _bisections(sub, transposed, stack + "/")
-    return n
+            yield from _equations(sub, stack + "/")
+
+
+def _bisections(jaxpr, transposed):
+    """``kth_largest``'s 32-pass loops in a jaxpr, by whether they sit
+    under a transpose (= are made again in the backward pass)."""
+    return sum(("transpose(" in stack) == transposed
+               for eqn, stack in _equations(jaxpr)
+               if eqn.primitive.name == "scan"
+               and eqn.params["length"] == 32)
+
+
+def _three_steps(program):
+    """Three steps' losses and every gradient of every step."""
+    main, startup, loss, feed = program
+    ad = next(op for op in main.global_block().ops if op.type == "autodiff")
+    fetch = [loss] + list(ad.attr("grad_names"))
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor()
+        exe.run(startup)
+        return [np.asarray(x) for _ in range(3)
+                for x in exe.run(main, feed=feed, fetch_list=fetch)]
+
+
+def _assert_as_under_the_bare_checkpoint(monkeypatch, program, leaves):
+    """``program()``'s three steps, bit for bit what the bare checkpoint
+    gives: the backward pass reads from a buffer the bits a second making
+    would have produced."""
+    got = _three_steps(program())
+    with monkeypatch.context() as m:
+        _bare_checkpoint(m)
+        want = _three_steps(program())
+    assert len(got) == len(want) >= 3 * leaves
+    assert got[0] != got[-len(got) // 3]       # the steps train
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("kept", [True, False], ids=["kept", "bare"])
@@ -216,28 +248,8 @@ def test_a_kept_value_is_made_once_a_layer(interpreted_tiers, monkeypatch,
 
 @pytest.mark.parametrize("tier", ["select", "flash"])
 def test_kept_values_change_no_number(interpreted_tiers, monkeypatch, tier):
-    """Three steps' losses and every gradient of every step, bit for bit
-    what the bare checkpoint gives: the backward pass reads from a buffer
-    the bits a second launch would have produced."""
-    def three_steps():
-        main, startup, loss, feed = _attention_program(tier, True)
-        ad = next(op for op in main.global_block().ops
-                  if op.type == "autodiff")
-        fetch = [loss] + list(ad.attr("grad_names"))
-        with fluid.scope_guard(fluid.Scope()):
-            exe = fluid.Executor()
-            exe.run(startup)
-            return [np.asarray(x) for _ in range(3)
-                    for x in exe.run(main, feed=feed, fetch_list=fetch)]
-
-    got = three_steps()
-    with monkeypatch.context() as m:
-        _bare_checkpoint(m)
-        want = three_steps()
-    assert len(got) == len(want) >= 3 * 10
-    assert got[0] != got[-len(got) // 3]       # the steps train
-    for a, b in zip(got, want):
-        np.testing.assert_array_equal(a, b)
+    _assert_as_under_the_bare_checkpoint(
+        monkeypatch, lambda: _attention_program(tier, True), 10)
 
 
 @pytest.mark.parametrize("tier", ["select", "flash"])
@@ -275,3 +287,99 @@ def test_an_untagged_segment_lowers_as_under_the_bare_checkpoint(
     kept = lowered()
     _bare_checkpoint(monkeypatch)
     assert lowered() == kept
+
+
+# -- the expert layer's dispatch plan: made once a layer-step ----------------
+# Two layers of router -> held experts (4 of 8 under top-2, a walk of 2
+# chunks of 32 rows) added to the stream, a checkpoint after each.
+_MOE_T, _MOE_K, _MOE_HELD = 32, 2, 4
+_MOE_BLOCKS = _MOE_HELD     # 32 tokens: one block of 128 an expert
+# a layer: each sorted row's place, starts, ends, n_here (int32), and the
+# held experts' weight table (f32)
+_MOE_KEPT_BYTES = 4 * (_MOE_T * _MOE_K + 2 * _MOE_HELD + 1
+                       + _MOE_BLOCKS * 128)
+
+
+def _moe_program(use_recompute):
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = 5
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        h = layers.data("x", shape=[_MOE_T, 16], append_batch_size=False)
+        cuts = []
+        for _ in range(_LAYERS):
+            ids, wts = layers.moe_route(h, 8, _MOE_K)
+            h = h + layers.moe_experts(h, ids, wts, _MOE_HELD, 8,
+                                       expert_offset=2)
+            cuts.append(h)
+        loss = layers.mean(layers.fc(h, 1, bias_attr=False))
+        opt = optimizer.SGD(learning_rate=0.1)
+        if use_recompute:
+            opt = optimizer.RecomputeOptimizer(opt)
+            opt._set_checkpoints(cuts)
+        opt.minimize(loss)
+    feed = {"x": np.random.RandomState(0).randn(_MOE_T, 16).astype(
+        np.float32)}
+    return main, startup, loss, feed
+
+
+def _plan_equations(jaxpr):
+    """``[forward, transposed]``: equations under the plan's scope
+    (``moe_plan``: the held table's counts and a chunk's rows), by whether
+    they sit under a transpose (= are made again in the backward pass)."""
+    n = [0, 0]
+    for _, stack in _equations(jaxpr):
+        if "moe_plan" in stack:
+            n["transpose(" in stack] += 1
+    return n
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "bare"])
+def test_the_dispatch_plan_is_made_once_a_layer(monkeypatch, kept):
+    """Under checkpoints the step holds the expert layer's plan once a
+    layer, as the program without checkpoints does: the backward walk goes
+    by the rows the forward walk wrote. Under the bare checkpoint the
+    recomputed segment makes it again."""
+    import collections
+
+    from test_autodiff_one_forward import _count
+
+    if not kept:
+        _bare_checkpoint(monkeypatch)
+    once = _plan_equations(_live_jaxpr(*_moe_program(False)))
+    assert once[0] > 0 and once[0] % _LAYERS == 0 and once[1] == 0
+    live = _live_jaxpr(*_moe_program(True))
+    fwd, again = _plan_equations(live)
+    assert fwd == once[0]
+    assert again == (0 if kept else once[0])
+    # nor is the router's top_k made again: its values and ids are kept
+    counts = collections.Counter()
+    _count(live, counts)
+    assert counts[("top_k", False)] == _LAYERS
+    assert counts[("top_k", True)] == (0 if kept else _LAYERS)
+    # the router's matmul and the experts' grouped GEMMs still are
+    assert counts[("dot_general", True)] > 0
+    assert counts[("ragged_dot_general", True)] > 0
+
+
+def test_a_kept_plan_changes_no_number(monkeypatch):
+    _assert_as_under_the_bare_checkpoint(
+        monkeypatch, lambda: _moe_program(True), 9)
+
+
+def test_kept_plan_bytes_are_counted_once_a_site():
+    before = _kept_bytes("moe_plan")
+    _live_jaxpr(*_moe_program(False))
+    assert _kept_bytes("moe_plan") == before
+    route = _kept_bytes("moe_route")
+    _live_jaxpr(*_moe_program(True))
+    assert _kept_bytes("moe_plan") - before == _LAYERS * _MOE_KEPT_BYTES
+    # the chosen experts' scores (f32) and ids (int32)
+    assert _kept_bytes("moe_route") - route == _LAYERS * 2 * 4 * _MOE_T * _MOE_K
+
+
+def test_without_checkpoints_the_plans_mark_lowers_to_nothing(monkeypatch):
+    from paddle_tpu.kernels import common
+
+    tagged = _lowered(*_moe_program(False))
+    monkeypatch.setattr(common, "keep_across_recompute", lambda x, what: x)
+    assert _lowered(*_moe_program(False)) == tagged

@@ -2,8 +2,8 @@
 the layer boundaries, attention on the Pallas interpreter: the compiled
 step names each forward attention kernel once a layer that has one (its
 ``o`` and row logsumexp cross the boundary,
-``kernels.common.keep_across_recompute``), and recomputation changes no
-loss."""
+``kernels.common.keep_across_recompute``, as does the expert layer's
+dispatch plan), and recomputation changes no loss."""
 
 import collections
 import os
@@ -21,9 +21,9 @@ for _p in (ROOT, os.path.join(ROOT, "benchmark")):
 # 4), what its segments keep
 _MODELS = {
     "keye": ("keye-vl-2.0-30b-a3b.train-s16384", "attn_select", 4,
-             ("attn_select", "sparse_index")),
+             ("attn_select", "sparse_index", "moe_plan", "moe_route")),
     "qwen": ("qwen3-next-80b-a3b.train-s8192", "attn_flash", 1,
-             ("attn_flash",)),
+             ("attn_flash", "moe_plan", "moe_route")),
 }
 
 
