@@ -1902,7 +1902,7 @@ def moe_experts(input, topk_ids, topk_weights, experts_held, expert_width,
     experts of width ``expert_width``, no biases), dropless (ops/
     moe_ops.py). What absent experts would add is left out.
     ``experts_total`` (the router's width) lets the op size its walk's
-    chunk as the whole number of token counts next above an even load."""
+    chunk to the load its experts hold at even routing: 3/2 of it."""
     helper = LayerHelper("moe_experts", **locals())
     h, E, f = int(input.shape[-1]), int(experts_held), int(expert_width)
     dtype = _data_type(input)

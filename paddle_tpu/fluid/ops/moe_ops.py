@@ -15,7 +15,16 @@ in chunks of ``chunk_rows`` rows: the first chunk always, the others in a
 loop that stops after the last chunk with a held pair in it (a trip count
 known only on the device, so the walk brings its own backward, a loop of
 the same length). So every pair that lands here is computed whatever the
-routing, and the work follows the load, a chunk's worth at a time. Within
+routing, and the work follows the load, a chunk's worth at a time. **The
+chunk is sized to the load held** (``walk_chunk_rows``, from shapes
+alone): 3/2 of the pairs the held experts get at even routing. Every row
+a chunk holds past the last held pair is still gathered, selected to
+zero, multiplied and scatter-added (0.41-0.46 ms a layer a thousand rows
+on a v5e), so the chunk is no larger than a load near even needs; and a
+chunk more costs 1.5-5 ms a layer whatever its rows (its plan, a ``[T,
+h]`` dx, every held expert's dW written and added in the loop's carry),
+so it has room above even and a load that swings far past it walks
+whole chunks of that size, not small ones. Within
 a chunk the three expert GEMMs are grouped ones (``lax.ragged_dot``, which
 the TPU compiler lowers to its own grouped-matmul kernel). Plain XLA: no
 Pallas kernel of this repo here yet.
@@ -48,6 +57,11 @@ import functools
 
 from ..registry import register
 
+# the walk's chunk as a share of the even load (``walk_chunk_rows``), in
+# whole steps of rows
+_CHUNK_LOAD = (3, 2)
+_ROW_STEP = 512
+
 
 def _count(impl):
     """Trace-time record of which implementation a dispatch took (one per
@@ -58,6 +72,16 @@ def _count(impl):
         "moe_dispatch_total",
         "moe_experts lowerings traced, by implementation (trace-time: one "
         "per traced program, not per step)", labels={"impl": impl}).inc()
+
+
+def _gauge_chunk_rows(chunk_rows):
+    """Trace-time record of the walk's chunk size, the newest traced."""
+    from .. import monitor
+
+    monitor.gauge(
+        "moe_walk_chunk_rows",
+        "rows of each chunk of the expert walk, as last traced (trace-time; "
+        "how many chunks a step walks is the device's)").set(chunk_rows)
 
 
 def _count_route(scoring):
@@ -267,7 +291,10 @@ def _chunk_rows(route, c, x, wtab, w_gate, w_up, w_down):
 
 
 def _live_chunks(route):
-    """How many chunks hold a held pair (the first counts always)."""
+    """How many chunks hold a held pair (the first counts always). The
+    chunk holds 3/2 of the even load (``walk_chunk_rows``), so a load near
+    even walks one, and one that swings far past it ``ceil(n_here / CH)``
+    of the same size."""
     import jax.numpy as jnp
 
     place, _, _, n_here = route
@@ -322,7 +349,8 @@ def _walk_fwd(route, tables, x, wtab, w_gate, w_up, w_down):
 def _walk_bwd(args, d_out):
     """Chunk by chunk, each chunk's rows computed again and transposed;
     the gradients of the weight table and of the experts' weights add up
-    in the loop's carry."""
+    in the loop's carry, in their own dtypes (the experts' bf16 under
+    AMP)."""
     import jax
 
     route, x, wtab, w_gate, w_up, w_down = args
@@ -370,6 +398,22 @@ def moe_experts_dropless(x, ids, wts, w_gate, w_up, w_down, expert_offset,
                    w_down)
 
 
+def walk_chunk_rows(T, k, E_held, experts_total):
+    """The rows of each chunk of the expert walk, from shapes alone. At
+    even routing the held experts get ``even = T * k * E_held /
+    experts_total`` pairs; the chunk holds 3/2 of that, rounded up to 512
+    rows, and at most all ``T * k`` pairs. A chunk of exactly ``even``
+    would put a load within a few percent of even at its edge - one chunk
+    or two from step to step - and a chunk more costs as much as 0.3-1 of
+    an even load of rows (the module's docstring). Without
+    ``experts_total``, a token count."""
+    if not experts_total:
+        return T
+    num, den = _CHUNK_LOAD
+    steps = -(-(T * k * E_held * num) // (experts_total * den * _ROW_STEP))
+    return min(steps * _ROW_STEP, T * k)
+
+
 @register("moe_experts")
 def _moe_experts(ctx, op):
     """X [..., h], TopkIds / TopkWeights [..., k] (``moe_route``'s),
@@ -379,15 +423,13 @@ def _moe_experts(ctx, op):
     in expert-major order (the module's docstring: planned from the pairs
     held, a chunk at a time, and once a layer-step under recomputation;
     ``moe_dispatch_total{impl="ragged_loop_held"}``) are walked a chunk at
-    a time, and the chunk is the whole number of
-    token counts next ABOVE an even load: with ``experts_total`` (the
-    router's width) the held experts get ``k * E_held / experts_total``
-    pairs a token at even routing, so the chunk is ``k * E_held //
-    experts_total + 1`` token counts (32 of 512 under top-10: 0.625 pairs
-    a token, one token count; 16 of 128 under top-8: 1.0, two - a chunk
-    of one would leave a load within a percent of even AT its edge, one
-    chunk or two from step to step). One rule for every caller; without
-    ``experts_total`` a token count. A load near even takes one chunk."""
+    a time, the chunk sized to the load the held experts get at even
+    routing (``walk_chunk_rows``; ``experts_total`` is the router's
+    width): 3/2 of it (32 of 512 experts held under top-10 at 16,384
+    tokens: 15,360 rows; 16 of 128 under top-8: 24,576). So a load up to
+    3/2 of even takes one chunk. One rule for every caller; without
+    ``experts_total`` a token count. The size traced is in the gauge
+    ``moe_walk_chunk_rows``."""
     x = ctx.get_input(op, "X")
     ids = ctx.get_input(op, "TopkIds")
     wts = ctx.get_input(op, "TopkWeights")
@@ -396,8 +438,9 @@ def _moe_experts(ctx, op):
     k = ids.shape[-1]
     _count("ragged_loop_held")
     T, E = x2.shape[0], ctx.get_input(op, "WGate").shape[0]
-    total = int(op.attr("experts_total", 0) or 0)
-    chunk_rows = T * (k * E // total + 1 if total else 1)
+    chunk_rows = walk_chunk_rows(
+        T, k, E, int(op.attr("experts_total", 0) or 0))
+    _gauge_chunk_rows(chunk_rows)
     out = moe_experts_dropless(
         x2, ids.reshape(-1, k), wts.reshape(-1, k),
         ctx.get_input(op, "WGate"), ctx.get_input(op, "WUp"),

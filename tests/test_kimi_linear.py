@@ -11,6 +11,7 @@ compute plus the shared expert ONCE add up to the uncut layer."""
 import functools
 import os
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -293,8 +294,8 @@ def kernel_and_oracle():
     from paddle_tpu.fluid.ops.linear_attention import kda_chunked
     from paddle_tpu.kernels import delta_rule
 
-    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
-    try:
+    # set for the fixture alone: the variable as it was comes back after
+    with mock.patch.dict(os.environ, {"PADDLE_TPU_PALLAS_INTERPRET": "1"}):
         args = _kda_inputs(200, B=1, H=2, dk=128, dv=128, seed=9)
         assert delta_rule.supported(128, 128, 64)
         out = {}
@@ -307,8 +308,6 @@ def kernel_and_oracle():
                 return (o,) + vjp(jnp.cos(o))
 
             out[name] = jax.jit(both)(*args)
-    finally:
-        del os.environ["PADDLE_TPU_PALLAS_INTERPRET"]
     return out
 
 
@@ -382,8 +381,8 @@ def two_widths():
     from paddle_tpu.fluid import monitor
     from paddle_tpu.kernels import attention as A
 
-    os.environ["PADDLE_TPU_PALLAS_INTERPRET"] = "1"
-    try:
+    # set for the fixture alone: the variable as it was comes back after
+    with mock.patch.dict(os.environ, {"PADDLE_TPU_PALLAS_INTERPRET": "1"}):
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q, k = (jax.random.normal(ks[i], (1, 2, 256, 192)) for i in (0, 1))
         v, do = (jax.random.normal(ks[i], (1, 2, 256, 128)) for i in (2, 3))
@@ -408,8 +407,6 @@ def two_widths():
             q[..., :128], k128, v, bias, 0.1, 0.0, seed, True)
         out["narrow"] = A._pallas_attention_flash(
             q[..., :128], k128, v[..., :64], bias, 0.1, 0.0, seed, True)
-    finally:
-        del os.environ["PADDLE_TPU_PALLAS_INTERPRET"]
     return out
 
 

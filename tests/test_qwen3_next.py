@@ -207,40 +207,118 @@ def _dense(x, ids, wts, wg, wu, wd, lo, hi):
     return out
 
 
-@pytest.mark.parametrize("chunk_rows", [7, 48, 1000])
-def test_moe_experts_dropless_under_skewed_routing(chunk_rows):
-    """Expert 5 takes a pair of every token (48 of the 144 pairs, on one
-    of four held experts): nothing is dropped, whatever the chunking - 7
-    rows a chunk walks many chunks and cuts through groups, 1000 holds
-    every pair in one."""
+# rows a chunk: 7 walks many chunks and cuts through groups, 1000 holds
+# every pair in one, the others cut the walk elsewhere
+_CHUNKS = [7, 48, 1000, 20, 40, 100]
+
+
+def _walk_against_dense(x, ids, wts, wg, wu, wd, lo, E, chunk_rows):
+    """The walk over experts lo..lo+E, its output and all five gradients
+    (x, the weights a token gives its choices, the three expert weights),
+    against ``_dense``."""
     from paddle_tpu.fluid.ops.moe_ops import moe_experts_dropless
 
-    x, ids, wts, wg, wu, wd = _moe_inputs(skew=5)
-    assert int(jnp.sum(ids == 5)) == x.shape[0]
+    hi = lo + E
     with jax.default_matmul_precision("highest"):
-        got = moe_experts_dropless(x, ids, wts, wg[4:8], wu[4:8], wd[4:8],
-                                   4, chunk_rows)
-        want = _dense(x, ids, wts, wg, wu, wd, 4, 8)
+        got = moe_experts_dropless(x, ids, wts, wg[lo:hi], wu[lo:hi],
+                                   wd[lo:hi], lo, chunk_rows)
+        want = _dense(x, ids, wts, wg, wu, wd, lo, hi)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-        args = (x, wts, wg[4:8], wu[4:8], wd[4:8])
+        args = (x, wts, wg[lo:hi], wu[lo:hi], wd[lo:hi])
         g_got = jax.grad(lambda x_, w_, a, b, c: jnp.sum(jnp.sin(
-            moe_experts_dropless(x_, ids, w_, a, b, c, 4, chunk_rows))),
+            moe_experts_dropless(x_, ids, w_, a, b, c, lo, chunk_rows))),
             argnums=(0, 1, 2, 3, 4))(*args)
         g_want = jax.grad(lambda x_, w_, a, b, c: jnp.sum(jnp.sin(_dense(
-            x_, ids, w_, jnp.zeros_like(wg).at[4:8].set(a),
-            jnp.zeros_like(wu).at[4:8].set(b),
-            jnp.zeros_like(wd).at[4:8].set(c), 4, 8))),
+            x_, ids, w_, jnp.zeros_like(wg).at[lo:hi].set(a),
+            jnp.zeros_like(wu).at[lo:hi].set(b),
+            jnp.zeros_like(wd).at[lo:hi].set(c), lo, hi))),
             argnums=(0, 1, 2, 3, 4))(*args)
-    for a, b in zip(g_got, g_want):
+    for a, b, name in zip(g_got, g_want, "x wts gate up down".split()):
+        assert np.all(np.isfinite(a)), name
         np.testing.assert_allclose(a, b, rtol=2e-4,
-                                   atol=2e-5 * float(jnp.abs(b).max()))
+                                   atol=2e-5 * float(jnp.abs(b).max()),
+                                   err_msg=name)
+
+
+def _held_pairs(ids, lo, E):
+    return int(jnp.sum((ids >= lo) & (ids < lo + E)))
+
+
+@pytest.mark.parametrize("chunk_rows", _CHUNKS)
+def test_moe_experts_dropless_under_skewed_routing(chunk_rows):
+    """Expert 5 takes a pair of every token (48 of the 144 pairs, on one
+    of four held experts): nothing is dropped, whatever the chunking."""
+    x, ids, wts, wg, wu, wd = _moe_inputs(skew=5)
+    assert int(jnp.sum(ids == 5)) == x.shape[0]
+    _walk_against_dense(x, ids, wts, wg, wu, wd, 4, 4, chunk_rows)
+
+
+# load -> (a chunk's rows less the held pairs, held experts of 32)
+_EDGES = {"under_the_first_chunk": (1, 4), "at_its_edge": (0, 4),
+          "one_row_over": (-1, 4), "every_pair_held": (-44, 32)}
+
+
+@pytest.mark.parametrize("load", sorted(_EDGES))
+def test_moe_walk_about_the_first_chunks_edge(load):
+    """A load one row under the first chunk, filling it, one row over it
+    (a second chunk of a single held pair), and every pair held (chunks to
+    the last row): the output and all five gradients are the dense
+    form's."""
+    room, E = _EDGES[load]
+    x, ids, wts, wg, wu, wd = _moe_inputs()
+    n = _held_pairs(ids, 0, E)
+    assert n == (ids.size if E == 32 else n) and n > 8
+    _walk_against_dense(x, ids, wts, wg, wu, wd, 0, E, n + room)
+
+
+def test_rows_past_the_last_held_pair_bring_no_nan(monkeypatch):
+    """The TPU's grouped matmul leaves the rows of no group unwritten, in
+    its result and in its input's gradient: here they are written NaN.
+    Over chunks whose last is part empty, neither the output nor any
+    gradient sees them."""
+    from paddle_tpu.fluid.ops import moe_ops
+
+    real = jax.lax.ragged_dot
+
+    @jax.custom_vjp
+    def unwritten(lhs, rhs, sizes):
+        rows = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(sizes)
+        return jnp.where(rows, real(lhs, rhs, sizes), jnp.nan)
+
+    def fwd(lhs, rhs, sizes):
+        return unwritten(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def bwd(res, g):
+        lhs, rhs, sizes = res
+        _, vjp = jax.vjp(lambda a, b: real(a, b, sizes), lhs, rhs)
+        d_lhs, d_rhs = vjp(g)
+        rows = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(sizes)
+        return jnp.where(rows, d_lhs, jnp.nan), d_rhs, None
+
+    unwritten.defvjp(fwd, bwd)
+    x, ids, wts, wg, wu, wd = _moe_inputs()
+    n = _held_pairs(ids, 0, 4)
+    chunk = n - 7           # a second chunk of 7 held pairs, part empty
+    assert 7 < chunk
+    jax.clear_caches()
+    moe_ops._once.cache_clear()
+    monkeypatch.setattr(
+        jax.lax, "ragged_dot",
+        lambda lhs, rhs, sizes, **kw: unwritten(lhs, rhs, sizes))
+    try:
+        _walk_against_dense(x, ids, wts, wg, wu, wd, 0, 4, chunk)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+        moe_ops._once.cache_clear()
 
 
 def _argsort_plan(ids, wts, E, expert_offset, chunk_rows):
-    """The dispatch plan as the program made it before PR 37 (a stable
-    argsort of the P = T * k pairs by expert, a ``bincount``, a P-long
-    gather of the weights): the oracle of the form that follows the pairs
-    held. ``(tok, w_sorted, starts, ends, n_here)``."""
+    """The dispatch plan as the program made it before it followed the
+    pairs held (a stable argsort of the P = T * k pairs by expert, a
+    ``bincount``, a P-long gather of the weights): the oracle of the form
+    that follows the pairs held. ``(tok, w_sorted, starts, ends,
+    n_here)``."""
     T, k = ids.shape
     P, CH = T * k, int(chunk_rows)
     n_chunks = -(-P // CH)
@@ -266,7 +344,7 @@ _ROUTINGS = {
 }
 
 
-@pytest.mark.parametrize("chunk_rows", [7, 48, 1000])
+@pytest.mark.parametrize("chunk_rows", _CHUNKS)
 @pytest.mark.parametrize("routing", sorted(_ROUTINGS))
 def test_dispatch_plan_equals_the_argsort_forms(routing, chunk_rows):
     """The plan made from the held tables, every chunk of it, against the
@@ -304,15 +382,43 @@ def test_dispatch_plan_equals_the_argsort_forms(routing, chunk_rows):
     assert np.all(np.diff(np.asarray(place).ravel()[:n]) > 0)
 
 
-@pytest.mark.parametrize("n_here, live", [(0, 1), (8, 1), (9, 2), (24, 3),
-                                          (25, 4), (40, 4)])
-def test_moe_walk_stops_after_the_last_chunk_with_a_held_pair(n_here, live):
+# (chunks, rows a chunk): four chunks of 8, or four of 12
+_LIVE = [pytest.param(n, live, (4, 8), id="%d-%d" % (n, live))
+         for n, live in [(0, 1), (8, 1), (9, 2), (24, 3), (25, 4), (40, 4)]]
+_LIVE += [pytest.param(n, live, (4, 12), id="12-%d-%d" % (n, live))
+          for n, live in [(0, 1), (12, 1), (13, 2), (24, 2), (25, 3),
+                          (37, 4), (60, 4)]]
+
+
+@pytest.mark.parametrize("n_here, live, chunks", _LIVE)
+def test_moe_walk_stops_after_the_last_chunk_with_a_held_pair(
+        n_here, live, chunks):
     """The walk's trip count follows the load, a chunk at a time: the
     first chunk counts always, and no more chunks than there are."""
     from paddle_tpu.fluid.ops.moe_ops import _live_chunks
 
-    route = (jnp.zeros((4, 8), jnp.int32), None, None, jnp.int32(n_here))
+    route = (jnp.zeros(chunks, jnp.int32), None, None, jnp.int32(n_here))
     assert int(_live_chunks(route)) == live
+
+
+# cell -> (tokens, top-k, experts held, router's width), rows a chunk
+_CELL_CHUNKS = {
+    "lfm2-24b-a2b.train-s8192": ((32768, 4, 8, 64), 24576),
+    "keye-vl-2.0-30b-a3b.train-s16384": ((16384, 8, 16, 128), 24576),
+    "qwen3-next-80b-a3b.train-s8192": ((16384, 10, 32, 512), 15360),
+    "kimi-linear-48b-a3b.train-s16384": ((16384, 8, 8, 256), 6144),
+    "no_router_width": ((16384, 8, 8, 0), 16384),
+    "capped_at_every_pair": ((48, 3, 32, 32), 144),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_CHUNKS))
+def test_walk_chunk_rows_at_the_cells_shapes(cell):
+    """3/2 of the even load, in steps of 512 rows, at most every pair."""
+    from paddle_tpu.fluid.ops.moe_ops import walk_chunk_rows
+
+    shape, want = _CELL_CHUNKS[cell]
+    assert walk_chunk_rows(*shape) == want
 
 
 def test_sixteen_shares_add_up_to_the_uncut_expert_layer():
@@ -382,6 +488,29 @@ def test_new_counters_say_which_implementation_was_traced(both_sides):
                            labels={"impl": "ragged_loop_held"}).value > 0
     assert monitor.counter("conv_dispatch_total",
                            labels={"impl": "xla"}).value > 0
+
+
+def test_the_walks_chunk_size_is_in_a_gauge():
+    """A run says which chunk size it traced: 1,024 tokens under top-2,
+    4 of 8 experts held, an even load of 1,024 pairs - a chunk of 3/2 of
+    it."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import layers, monitor
+
+    T, h, E_total, k, held = 1024, 8, 8, 2, 4
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        xv = layers.data("x", shape=[T, h], append_batch_size=False)
+        ids, wts = layers.moe_route(xv, E_total, k)
+        out = layers.moe_experts(xv, ids, wts, held, 8,
+                                 experts_total=E_total)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor()
+        exe.run(startup)
+        got, = exe.run(main, feed={"x": np.random.RandomState(0).randn(
+            T, h).astype(np.float32)}, fetch_list=[out])
+    assert np.all(np.isfinite(got))
+    assert monitor.gauge("moe_walk_chunk_rows").value == 1536
 
 
 def test_the_convolution_carries_its_activation_and_no_swish_op_is_left():
