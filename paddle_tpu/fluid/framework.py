@@ -28,9 +28,11 @@ _name_scope_stack = []
 
 @contextlib.contextmanager
 def name_scope(prefix=None):
-    """Reference ``fluid.name_scope``: cosmetic op grouping recorded as
-    the ``op_namescope`` attr (what the reference's graph viewer
-    groups by); no effect on execution."""
+    """Reference ``fluid.name_scope``: op grouping recorded as the
+    ``op_namescope`` attr (what the reference's graph viewer groups by)
+    and carried onto every HLO operation the op lowers to, so a device
+    trace can be read by model module (``profiler.module_of``); no effect
+    on what is computed."""
     if prefix:
         _name_scope_stack.append(str(prefix))
     try:
@@ -402,8 +404,9 @@ class Block:
     def append_op(self, type, inputs=None, outputs=None, attrs=None):
         op = Operator(self, type, inputs, outputs, attrs)
         if _name_scope_stack:
-            # cosmetic namespace for viz/debug tools (reference
-            # op_desc "op_namescope"); ignored by every lowering
+            # the module an op belongs to (reference op_desc
+            # "op_namescope"): no lowering reads it; lower_op puts it on
+            # the op's HLO op_name (registry.module_scope)
             op.attrs.setdefault("op_namescope",
                                 "/".join(_name_scope_stack))
         self.ops.append(op)

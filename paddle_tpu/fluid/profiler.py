@@ -406,6 +406,7 @@ def op_names_of(hlo_text):
 _NEWEST_STEP = None
 _NEWEST_LOWERING = None     # (HLO text, memory) of it: ONE lowering's
 _NEWEST_REGIONS = None
+_NEWEST_MODULES = None
 STEP_BYTES_KINDS = ("argument", "output", "alias", "temp", "generated_code",
                     "total")
 _M_STEP_BYTES = {
@@ -420,9 +421,9 @@ _M_STEP_BYTES = {
 
 def note_compiled_step(fn, arg_specs):
     """``Executor.run`` calls this where it has compiled a step."""
-    global _NEWEST_STEP, _NEWEST_LOWERING, _NEWEST_REGIONS
+    global _NEWEST_STEP, _NEWEST_LOWERING, _NEWEST_REGIONS, _NEWEST_MODULES
     _NEWEST_STEP = (fn, arg_specs)
-    _NEWEST_LOWERING = _NEWEST_REGIONS = None
+    _NEWEST_LOWERING = _NEWEST_REGIONS = _NEWEST_MODULES = None
 
 
 def _step_memory(compiled):
@@ -469,6 +470,19 @@ def _newest_lowering():
     return step_lowering(*_NEWEST_STEP) if _NEWEST_STEP is not None else None
 
 
+def _newest_op_names():
+    """``{instruction name: op_name}`` of the newest compiled step's
+    module, containers left out, or None."""
+    lowering = _newest_lowering()
+    if lowering is None:
+        return None
+    text = lowering[0]
+    containers = {name for name, rest in _HLO_LINE.findall(text)
+                  if _is_container(rest)}
+    return {name: op_name for name, op_name in op_names_of(text).items()
+            if name not in containers}
+
+
 def newest_step_regions():
     """``{instruction name: (phase, program op type)}`` of the newest
     compiled step's module (``region_of`` of each instruction's
@@ -478,15 +492,27 @@ def newest_step_regions():
     lowered (a disk-tier or sharded wrapper)."""
     global _NEWEST_REGIONS
     if _NEWEST_REGIONS is None:
-        lowering = _newest_lowering()
-        if lowering is not None:
-            text = lowering[0]
-            containers = {name for name, rest in _HLO_LINE.findall(text)
-                          if _is_container(rest)}
-            _NEWEST_REGIONS = {name: region_of(op_name) for name, op_name
-                               in op_names_of(text).items()
-                               if name not in containers}
+        names = _newest_op_names()
+        if names is not None:
+            _NEWEST_REGIONS = {name: region_of(op_name)
+                               for name, op_name in names.items()}
     return _NEWEST_REGIONS
+
+
+def newest_step_modules():
+    """``{instruction name: model module}`` of the newest compiled step's
+    module (``module_of`` of each instruction's ``op_name``: ``"mtp"`` for
+    what an op built inside ``fluid.name_scope("mtp")`` lowered to -
+    forward, replay and backward - and ``""`` for the rest), beside
+    ``newest_step_regions`` and from the same lowering. None where that
+    table is."""
+    global _NEWEST_MODULES
+    if _NEWEST_MODULES is None:
+        names = _newest_op_names()
+        if names is not None:
+            _NEWEST_MODULES = {name: module_of(op_name)
+                               for name, op_name in names.items()}
+    return _NEWEST_MODULES
 
 
 def newest_step_memory():
@@ -555,6 +581,24 @@ def region_of(op_name):
     if "transpose(" in op_name or op_type == "autodiff":
         return "backward", op_type
     return "forward", op_type
+
+
+def module_of(op_name):
+    """The model module of a device operation's ``op_name``: what the
+    ``module.<name>`` scope that ``registry.lower_op`` opened round an op
+    built inside ``fluid.name_scope(<name>)`` names (the innermost, where
+    scopes nest), under any transform (``transpose(jvp(module.mtp))``) or
+    rematerialisation, or ``""`` for an operation of no module or with no
+    ``op_name``. ``region_of`` passes such a scope over: no op type is
+    called so."""
+    from .registry import MODULE_SCOPE
+
+    module = ""
+    for scope in (op_name or "").split("/")[:-1]:
+        m = _SCOPE.match(scope)
+        if m and m.group(1).startswith(MODULE_SCOPE):
+            module = m.group(1)[len(MODULE_SCOPE):]
+    return module
 
 
 def device_time_by_region(profile, hlo_texts=None):

@@ -218,15 +218,35 @@ def op_scope(op):
     return (tag.group(0) if tag else "") + op.type
 
 
+# the scope a model module's ops are lowered under, outside ``op_scope``:
+# ``module.mtp`` for an op built inside ``fluid.name_scope("mtp")``
+MODULE_SCOPE = "module."
+
+
+def module_scope(op):
+    """The ``jax.named_scope`` that says which model module an op belongs
+    to - ``module.<name>`` from the ``fluid.name_scope`` it was built in
+    (its ``op_namescope``; nested scopes joined by ``.``) - or None for an
+    op built outside any. ``profiler.module_of`` reads it back, forward,
+    replay and backward alike. Trace-time only."""
+    scope = op.attrs.get("op_namescope")
+    return MODULE_SCOPE + scope.replace("/", ".") if scope else None
+
+
 def lower_op(ctx, op):
     """Lower ONE op with error attribution + LoD propagation — the single
     entry every lowering loop (block, sub-block, replay, pipeline stage)
     must use so failures name the failing op and its creation site."""
+    import contextlib
+
     import jax
 
     EXECUTED_OP_TYPES.add(op.type)
+    module = module_scope(op)
     try:
-        with jax.named_scope(op_scope(op)):
+        with (jax.named_scope(module) if module
+              else contextlib.nullcontext()), \
+                jax.named_scope(op_scope(op)):
             registry.get(op.type).lower(ctx, op)
     except EnforceError:
         raise

@@ -28,7 +28,8 @@ weight; no bias anywhere)::
          k_s] with k_s shared by all heads; NO rotation (mla_use_nope);
          softmax((q . k) (nope + rope)^-0.5) v over s <= t, v 128 wide
          against 192-wide q and k (``layers.fused_attention`` at two
-         widths); out = Wo o
+         widths); out = Wo o (``decoder_blocks.latent_attention``,
+         q_lora_rank null)
     MoE  ``decoder_blocks.routed_experts`` (sigmoid scores, a selection-
          only bias, frozen and zero, ``routed_scaling_factor``) plus the
          shared expert, which is whole
@@ -63,10 +64,12 @@ class KimiLinearConfig(decoder_blocks.DecoderConfig):
                            19, 21, 22, 23, 25, 26],
             "num_heads": 32, "short_conv_kernel_size": 4}
         self.num_attention_heads = 32
+        self.q_lora_rank = None
         self.kv_lora_rank = 512
         self.qk_nope_head_dim = 128
         self.qk_rope_head_dim = 64
         self.v_head_dim = 128
+        self.mla_use_nope = True
         self.rms_norm_eps = 1e-5
         self.first_k_dense_replace = 1
         self.intermediate_size = 9216
@@ -86,6 +89,8 @@ class KimiLinearConfig(decoder_blocks.DecoderConfig):
         assert sorted(lin["kda_layers"] + lin["full_attn_layers"]) == list(
             range(1, self.num_hidden_layers + 1)), lin
         assert self.moe_router_activation_func == "sigmoid"
+        # latent attention without a query low-rank and without positions
+        assert self.q_lora_rank is None and self.mla_use_nope
 
     # what ``decoder_blocks.routed_experts`` reads, under the names the
     # other decoders' configs publish
@@ -127,47 +132,6 @@ def _kda(x, cfg, p):
                  p + "_o", cfg)
 
 
-def _mla(x, cfg, p):
-    H = cfg.num_attention_heads
-    dn, ds, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-
-    def heads_first(t):
-        return layers.transpose(t, [0, 2, 1, 3])        # [B, H, S, d]
-
-    q = heads_first(layers.reshape(
-        _proj(x, H * (dn + ds), p + "_q", cfg), [0, 0, H, dn + ds]))
-    c, k_s = layers.split(_proj(x, cfg.kv_lora_rank + ds, p + "_kv_a", cfg),
-                          [cfg.kv_lora_rank, ds], dim=-1,
-                          name=p + "_kv_a_split")
-    kv = layers.reshape(
-        _proj(_rms(c, p + "_kv_norm", cfg), H * (dn + dv), p + "_kv_b", cfg),
-        [0, 0, H, dn + dv])
-    k_n, v = layers.split(kv, [dn, dv], dim=-1, name=p + "_kv_b_split")
-    # one 64-wide key part for all heads, no rotation on any part
-    k_s = layers.expand(layers.unsqueeze(k_s, [1]), [1, H, 1, 1])
-    k = layers.concat([heads_first(k_n), k_s], axis=3)
-    ctx = layers.fused_attention(q, k, heads_first(v),
-                                 scale=(dn + ds) ** -0.5, causal=True,
-                                 name=p + "_core")
-    return _proj(layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                                [0, 0, H * dv]), cfg.hidden_size, p + "_o",
-                 cfg)
-
-
-def _mlp(x, width, cfg, p):
-    return _proj(layers.swiglu(_proj(x, width, p + "_gate", cfg),
-                               _proj(x, width, p + "_up", cfg),
-                               name=p + "_act"),
-                 cfg.hidden_size, p + "_down", cfg)
-
-
-def _moe(x, cfg, p):
-    return layers.elementwise_add(
-        decoder_blocks.routed_experts(x, cfg, p),
-        _mlp(x, cfg.moe_intermediate_size * cfg.num_shared_experts, cfg,
-             p + "_shared"), name=p + "_sum")
-
-
 def decoder(tokens, cfg):
     """``tokens`` [B, S] int64 -> (hidden states after the final norm
     [B, S, h], the residual stream after each layer)."""
@@ -179,12 +143,14 @@ def decoder(tokens, cfg):
         p = "layer_%d" % i
         h = _rms(x, p + "_norm1", cfg)
         x = layers.elementwise_add(
-            x, _mla(h, cfg, p + "_mla") if cfg.is_full_attention(i)
+            x, decoder_blocks.latent_attention(h, cfg, p + "_mla")
+            if cfg.is_full_attention(i)
             else _kda(h, cfg, p + "_kda"), name=p + "_res1")
         h = _rms(x, p + "_norm2", cfg)
         x = layers.elementwise_add(
-            x, _mlp(h, cfg.intermediate_size, cfg, p + "_mlp")
-            if i < cfg.first_k_dense_replace else _moe(h, cfg, p + "_moe"),
+            x, decoder_blocks.mlp(h, cfg.intermediate_size, cfg, p + "_mlp")
+            if i < cfg.first_k_dense_replace
+            else decoder_blocks.routed_and_shared_experts(h, cfg, p + "_moe"),
             name=p + "_res2")
         boundaries.append(x)
     return _rms(x, "final_norm", cfg), boundaries

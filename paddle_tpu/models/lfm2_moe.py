@@ -130,14 +130,6 @@ def _attention(x, cfg, p):
                  cfg)
 
 
-def _mlp(x, cfg, p):
-    width = cfg.intermediate_size
-    return _proj(layers.swiglu(_proj(x, width, p + "_gate", cfg),
-                               _proj(x, width, p + "_up", cfg),
-                               name=p + "_act"),
-                 cfg.hidden_size, p + "_down", cfg)
-
-
 def decoder(tokens, cfg):
     """``tokens`` [B, S] int64 -> (hidden states after the final norm
     [B, S, h], the residual stream after each layer)."""
@@ -153,7 +145,8 @@ def decoder(tokens, cfg):
             else _conv(h, cfg, p + "_conv"), name=p + "_res1")
         h = _rms(x, p + "_norm2", cfg)
         x = layers.elementwise_add(
-            x, _mlp(h, cfg, p + "_mlp") if i < cfg.num_dense_layers
+            x, decoder_blocks.mlp(h, cfg.intermediate_size, cfg, p + "_mlp")
+            if i < cfg.num_dense_layers
             else decoder_blocks.routed_experts(h, cfg, p + "_moe"),
             name=p + "_res2")
         boundaries.append(x)

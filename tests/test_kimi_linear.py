@@ -532,7 +532,7 @@ def test_thirty_two_ranks_shares_and_one_shared_expert_add_up_to_the_layer():
                 num_experts_total=E, num_experts_per_token=k,
                 expert_offset=r * held))
             parts.append(decoder_blocks.routed_experts(xv, cfg, "r%d" % r))
-        shared = kimi_linear._mlp(xv, f, cfg, "shared")
+        shared = decoder_blocks.mlp(xv, f, cfg, "shared")
     scope = fluid.Scope()
     with fluid.scope_guard(scope), jax.default_matmul_precision("highest"):
         exe = fluid.Executor()
@@ -560,3 +560,84 @@ def test_thirty_two_ranks_shares_and_one_shared_expert_add_up_to_the_layer():
     assert sum(np.abs(o).max() > 0 for o in outs[:32]) > 16
     np.testing.assert_allclose((sum(outs[:32]) + outs[32])[0], uncut,
                                rtol=1e-4, atol=1e-5)
+
+
+# -- the shared latent-attention block -------------------------------------------
+def _parents_mla(x, cfg, p):
+    """``kimi_linear._mla`` as it was before latent attention moved to
+    ``decoder_blocks.latent_attention``, kept verbatim."""
+    from paddle_tpu.fluid import layers
+    from paddle_tpu.models.kimi_linear import _proj, _rms
+
+    H = cfg.num_attention_heads
+    dn, ds, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+    def heads_first(t):
+        return layers.transpose(t, [0, 2, 1, 3])        # [B, H, S, d]
+
+    q = heads_first(layers.reshape(
+        _proj(x, H * (dn + ds), p + "_q", cfg), [0, 0, H, dn + ds]))
+    c, k_s = layers.split(_proj(x, cfg.kv_lora_rank + ds, p + "_kv_a", cfg),
+                          [cfg.kv_lora_rank, ds], dim=-1,
+                          name=p + "_kv_a_split")
+    kv = layers.reshape(
+        _proj(_rms(c, p + "_kv_norm", cfg), H * (dn + dv), p + "_kv_b", cfg),
+        [0, 0, H, dn + dv])
+    k_n, v = layers.split(kv, [dn, dv], dim=-1, name=p + "_kv_b_split")
+    # one 64-wide key part for all heads, no rotation on any part
+    k_s = layers.expand(layers.unsqueeze(k_s, [1]), [1, H, 1, 1])
+    k = layers.concat([heads_first(k_n), k_s], axis=3)
+    ctx = layers.fused_attention(q, k, heads_first(v),
+                                 scale=(dn + ds) ** -0.5, causal=True,
+                                 name=p + "_core")
+    return _proj(layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                                [0, 0, H * dv]), cfg.hidden_size, p + "_o",
+                 cfg)
+
+
+def _lowered_kimi_step():
+    """The StableHLO text of the toy's whole training step as the cell
+    builds it (AMP, recomputation at the layer boundaries; S = 128, so
+    attention takes the flash tier under the interpreter), built in a
+    fresh name space, every operation's location its ``op_name`` (the
+    Python source lines left out: they differ by file)."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import kimi_linear
+
+    with fluid.unique_name.guard():
+        main, startup, loss = kimi_linear.build_train_program(
+            kimi_linear.KimiLinearConfig.from_dict(TOY), 2, 128,
+            use_amp=True, recompute=True)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor()
+        exe.run(startup)
+        feed = {k: np.zeros((2, 128), "int64") for k in ("tokens", "labels")}
+        fn, args = exe.as_function(main, feed, [loss], scope=scope)
+        limit = jax.config.jax_traceback_in_locations_limit
+        jax.config.update("jax_traceback_in_locations_limit", 0)
+        try:
+            return jax.jit(fn).lower(*args).as_text(debug_info=True)
+        finally:
+            jax.config.update("jax_traceback_in_locations_limit", limit)
+
+
+def test_kimis_step_lowers_as_the_parents_latent_attention_made_it():
+    """``decoder_blocks.latent_attention`` without a query low-rank and
+    under ``mla_use_nope`` builds the ops the parent's ``_mla`` built, in
+    the same order under the same names: the step's lowered text is the
+    same, character for character, every operation's ``op_name`` with
+    it."""
+    from paddle_tpu.models import decoder_blocks
+
+    text = _lowered_kimi_step()
+    with mock.patch.object(decoder_blocks, "latent_attention", _parents_mla):
+        parents = _lowered_kimi_step()
+    assert "layer_3_fused_multihead_attention" in text
+    assert "rotary_embedding" not in text and "module." not in text
+    if text != parents:     # (pytest's diff of two 2 MB texts takes minutes)
+        line = next(i for i, (a, b) in enumerate(zip(
+            text.splitlines() + [""], parents.splitlines() + [""])) if a != b)
+        pytest.fail("the lowered step departs from the parent's at line %d:"
+                    "\n%s\n%s" % (line, text.splitlines()[line][:300],
+                                   parents.splitlines()[line][:300]))
