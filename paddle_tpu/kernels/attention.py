@@ -42,18 +42,22 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       (``_causal_mask``), so a causal call needs no [S, S] bias and keeps
       the tier its S selects: the flash tier takes row-broadcast bias
       only, and a ``[1, 1, S, S]`` causal bias would fall to the
-      blockwise scan. The flash tier does only the causal work: a score
-      tile wholly above the diagonal (k-tile j > q-tile i; nt(nt-1)/2 of
-      nt² a (batch, head)) is skipped in all three kernels. Its steps
-      come FIRST in their sweep (``_flash_ktile``; in dk/dv they do by
-      the grid's order): the body sits under ``pl.when``, and their block
-      index is that of the sweep's first tile (``_flash_specs``), so the
-      pipeline fetches that block under the row before and issues no DMA
-      for them. Such a tile's probabilities were exp(-1e30 - m) = 0, and
-      the tiles computed keep their order: every output is what it was,
-      to the bit. ``attn_flash_tiles_total{kind}`` counts a site's tiles
-      computed and skipped. The block and long tiers hold all of K a step
-      and have no sweep to shorten. v of ANOTHER WIDTH than q and k (dv !=
+      blockwise scan. The flash tier does only the causal work. Its grid
+      walks the nt(nt+1)/2 tiles on and below the diagonal and no others
+      (``_causal_steps``: two scalar-prefetched tables give a step's
+      q-tile and k-tile), so a tile wholly above it costs neither a DMA
+      nor a grid step. A tile below the diagonal is computed whole and
+      unmasked. The diagonal tile is worked in strips (``_diag_strips``,
+      edge ``_diag_edge``): a strip meets the blocks before its diagonal
+      block, unmasked, and that block, masked; the blocks above the
+      diagonal are neither computed nor masked. Such a block's
+      probabilities were exp(-1e30 - m) = 0, so what changes is the order
+      of the sums inside the diagonal tile, not what is summed.
+      ``attn_flash_tiles_total{kind}`` counts a site's tiles computed and
+      skipped, ``attn_causal_work_total{tier, kind}`` its grid steps,
+      empty steps, scores computed and scores masked. The block and long
+      tiers hold all of K a step and have no sweep to shorten; the causal
+      mask covers their whole tile. v of ANOTHER WIDTH than q and k (dv !=
       d: latent attention's 192-wide queries and keys against 128-wide
       values) is the flash tier's at every S a tile divides, whatever S:
       its three kernels block v, o, do and dv at ``[Tb, dv]`` and q, k, dq
@@ -62,8 +66,9 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       padded; the block and long tiers, which hold q, k and v under one
       block shape, are passed over, and where no tile divides S (or off
       the chip) the jnp forms below take two widths as they are. With dv =
-      d every spec, kernel and jaxpr is what it was. A call without ``causal`` traces to
-      the jaxpr it always did. Fewer KV than Q heads: the
+      d every spec, kernel and jaxpr is what it was. A call without
+      ``causal`` traces to the jaxpr it always did. Fewer KV than Q heads:
+      the
       ``fused_multihead_attention`` op repeats K/V to the Q head count
       before the kernel (``num_kv_heads``).
   ``select`` given ([B, S, S] integer, nonzero = the query may see the
@@ -73,12 +78,13 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       tile divides (tile 1024 / 512 / 256 / 128, the largest that
       divides S; no bias, no dropout) with at most 8 query heads a K/V
       head: the flash tier's online softmax and split backward with the
-      selection's tile added to each score tile as 0 / -1e30, the causal
-      tile skipping kept. A grid step holds a K/V head's H / Hkv query
-      heads: the selection's tile and the K/V tile are fetched once a
-      step and serve them all, K/V stay at their own head count, dk/dv
-      sum the group's heads inside. Elsewhere (not ``causal``, no tile
-      divides S, a wider group, off the chip without the interpreter)
+      selection's tile added to each score tile as 0 / -1e30, over the
+      flash tier's causal tiles and strips. A grid step holds a K/V
+      head's H / Hkv query heads: the selection's tile and the K/V tile
+      are fetched once a step and serve them all, K/V stay at their own
+      head count, dk/dv sum the group's heads inside. Elsewhere (not
+      ``causal``, no tile divides S, a wider group, off the chip without
+      the interpreter)
       the selection is a [B, 1, S, S] additive bias on the fallback
       below, which is the kernels' oracle. Without ``select`` nothing
       here is reached.
@@ -190,11 +196,10 @@ def _count_kernel(tier):
         "per traced program, not per step)", labels={"tier": tier}).inc()
 
 
-def _count_flash_tiles(nt, causal, sites=1):
-    """Trace-time record, once a traced flash ``pallas_call`` site (of
-    which the caller builds ``sites``), of the score tiles a (batch, head)
-    that the kernel computes and of those it skips (under ``causal``, the
-    ones wholly above the diagonal)."""
+def _count_flash_tiles(nt, causal):
+    """Trace-time record, once a traced flash ``pallas_call`` site, of the
+    score tiles a (batch, head) that the kernel computes and of those it
+    skips (under ``causal``, the ones wholly above the diagonal)."""
     from ..fluid import monitor as _monitor
 
     computed = nt * (nt + 1) // 2 if causal else nt * nt
@@ -204,7 +209,7 @@ def _count_flash_tiles(nt, causal, sites=1):
             "flash attention score tiles a (batch, head), computed or "
             "skipped as wholly above the causal diagonal (trace-time: "
             "once a traced kernel site, not per step)",
-            labels={"kind": kind}).inc(n * sites)
+            labels={"kind": kind}).inc(n)
 
 
 _ATTN_FORCE_VALUES = ("flash", "packed", "decode", "paged", "ring",
@@ -709,196 +714,421 @@ def _flash_seed(seed0, b, h, i, j, n_heads, nq, nk):
     return seed0 + (((b * n_heads + h) * nq + i) * nk + j)
 
 
-def _flash_ktile(nk, causal):
-    """``(j, i)``: the k-tile that this step of a q-tile's sweep computes
-    (grid ``(B, H, nq, nk)``, k-tile fastest) and, under ``causal``, the
-    q-tile (else None). Read at the kernel's top level: the interpreter
-    resolves program ids there only. Under ``causal`` q-tile i has the
-    i + 1 tiles 0..i to compute (q and k share the tile edge: a tile with
-    j > i lies wholly above the diagonal, its probabilities were
-    exp(-1e30 - m) = 0 and it added 0.0 everywhere) and takes them on its
-    LAST i + 1 steps, in the same order, so the sweep ends on the diagonal.
-    The steps before them have no tile (j < 0) and hold the block of tile
-    0, which the pipeline fetched under the row before (``_flash_specs``):
-    they move no data."""
-    j = pl.program_id(3)
+def _flash_uniform(seed_ref, b, h, i, j, n_heads, nq, nk, shape):
+    """Tile (i, j)'s dropout draw, ``shape`` = [Tb, Tb] uniforms. Every
+    kernel draws a tile whole under its seed, and a part of the diagonal
+    tile takes its slice of the draw, so all three drop the same pairs."""
+    pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j, n_heads, nq, nk))
+    return _uniform_from_bits(pltpu.prng_random_bits(shape))
+
+
+def _dropped(u, p, p_drop):
+    return jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
+
+
+def _drop_by(u, p_drop, *part):
+    """``p -> p`` dropped by the tile's draw ``u`` (a strip's ``part``:
+    its rows and columns of it), or None without dropout."""
+    return None if u is None else lambda p: _dropped(u[part], p, p_drop)
+
+
+# A causal sweep works its diagonal tile in strips of rows (of columns in
+# dk/dv), so that the blocks wholly above the diagonal are not computed:
+# at most ``_DIAG_STRIPS`` strips, each at least ``_DIAG_MIN_EDGE`` wide (a
+# strip's lane offset is a whole number of 128-lane vregs). The forward
+# cuts its tile only at d >= ``_DIAG_FWD_MIN_D``. Measured v5e, ms a call
+# at Tb = 1024, the diagonal tile whole / in strips of 512 / 256 / 128
+# (PR 41): dq 22.49 / 21.53 / 20.97 / 20.97 and dk/dv 28.23 / 27.05 /
+# 26.57 / 28.93 at d = 64 (B 4, H 32, S 8192); dq 6.19 / 5.87 / 5.70 /
+# 5.67 and dk/dv 8.24 / 7.82 / 7.75 / 7.62 at d = 256 (B 1, H 20); the
+# forward 17.78 whole against 18.76 in strips of 256 at d = 64, 4.72
+# against 4.56 at d = 256 (16.79 against 16.95 in the select tier at d =
+# 128, 22.05 against 21.80 at 192 / 128).
+_DIAG_STRIPS = 4
+_DIAG_MIN_EDGE = 128
+_DIAG_FWD_MIN_D = 192
+
+
+def _diag_edge(tb, d, forward=False):
+    """The strip edge of a ``tb``-row diagonal tile at head width ``d``;
+    ``tb`` itself (one strip: the whole tile, masked) where none fits, and
+    in the ``forward`` kernel below d = ``_DIAG_FWD_MIN_D``: there the
+    strips' smaller matmuls and extra row reductions cost more than the
+    blocks they leave out."""
+    if forward and d < _DIAG_FWD_MIN_D:
+        return tb
+    for n in (_DIAG_STRIPS, 2):
+        if tb % n == 0 and tb // n >= _DIAG_MIN_EDGE:
+            return tb // n
+    return tb
+
+
+def _diag_strips(tb, e, rows):
+    """The strips of a ``tb``-edge diagonal tile cut at edge ``e``, as
+    ``(strip, parts)``: ``strip`` a slice of the tile's rows (``rows``) or
+    columns, ``parts`` ``(slice, on the diagonal)`` of what it meets. A row
+    strip meets the keys before its diagonal block and that block, a column
+    strip that block and the queries after it; the blocks above the
+    diagonal are in no strip. The backward kernels mask the diagonal block
+    alone; the forward takes a row strip's keys as one part
+    (``_flash_fwd_causal``)."""
+    for o in range(0, tb, e):
+        parts = ([(slice(0, o), False), (slice(o, o + e), True)] if rows
+                 else [(slice(o, o + e), True), (slice(o + e, tb), False)])
+        yield slice(o, o + e), [(p, diag) for p, diag in parts
+                                if p.stop > p.start]
+
+
+def _causal_steps(nt, by_row):
+    """The (q-tile, k-tile) of each step of a causal sweep, as the two
+    int32 tables that ``PrefetchScalarGridSpec`` hands to the kernel and to
+    every index map: the tiles on and below the diagonal and no others,
+    nt(nt + 1) / 2 a (batch, head). ``by_row`` (forward, dq): row by row,
+    k-tile fastest, so that a row's carries and its dq block stay put and
+    the row ends on its diagonal tile; else (dk/dv) k-tile by k-tile, each
+    column from its diagonal tile down, so that dk and dv start there."""
+    if by_row:
+        steps = [(i, j) for i in range(nt) for j in range(i + 1)]
+    else:
+        steps = [(i, j) for j in range(nt) for i in range(j, nt)]
+    return tuple(np.asarray(t, np.int32) for t in zip(*steps))
+
+
+def _sweep(causal, B, H, nt, by_row=True, **specs):
+    """``(pallas_call keywords, leading operands)`` of a flash or select
+    sweep over ``(B, H)``: the grid ``(B, H, nt, nt)`` and no leading
+    operand, or under ``causal`` the grid ``(B, H, steps)`` over
+    ``_causal_steps`` and its two tables (numpy: a call built once serves
+    many traces, and passes them in as constants each time)."""
     if not causal:
-        return j, None
-    i = pl.program_id(2)
-    return j - (nk - 1 - i), i
+        return dict(grid=(B, H, nt, nt), **specs), ()
+    steps = _causal_steps(nt, by_row)
+    return dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, H, len(steps[0])), **specs)), steps
+
+
+def _count_causal_work(tier, nt, tb, edge, heads=1):
+    """Trace-time record, once a traced causal kernel site of ``tier``
+    (``flash`` or ``select``), of its work a (batch row, grid head: a
+    head, or a select step's K/V group of ``heads`` query heads): grid
+    steps, the steps among them with no tile, score elements computed, and
+    those among them masked as above the diagonal."""
+    from ..fluid import monitor as _monitor
+
+    qt, kt = _causal_steps(nt, True)
+    strips = tb // edge
+    work = {"steps": len(qt),
+            "empty_steps": int(np.sum(kt > qt)),
+            "scores": heads * (nt * (nt - 1) // 2 * tb * tb
+                               + nt * strips * (strips + 1) // 2
+                               * edge * edge),
+            "masked_scores": heads * nt * strips * edge * (edge - 1) // 2}
+    for kind, n in work.items():
+        _monitor.counter(
+            "attn_causal_work_total",
+            "causal attention kernel work a (batch row, head or K/V group): "
+            "grid steps, steps with no tile, score elements computed and "
+            "those masked above the diagonal (trace-time: once a traced "
+            "kernel site, not per step)",
+            labels={"tier": tier, "kind": kind}).inc(n)
+
+
+def _total(xs):
+    """The sum of ``xs`` from the left (one term: that term, no add)."""
+    return functools.reduce(jnp.add, xs)
+
+
+def _scores(q, k, scale):
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * scale
+
+
+def _online_softmax(s, v, m_ref, l_ref, acc_ref, idx, drop=None):
+    """One online-softmax step for the rows ``ref[idx]`` of the carries:
+    ``s`` the rows' scores against some keys, ``v`` those keys' values;
+    ``drop(p)`` drops from the weights as the values see them (the
+    denominator keeps them whole)."""
+    m_prev = m_ref[idx]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[idx] = l_ref[idx] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    if drop is not None:
+        p = drop(p)
+    acc_ref[idx] = acc_ref[idx] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[idx] = m_new
+
+
+def _ds(s, v, do, lse, dd, drop=None):
+    """dS of (a part of) a tile from its scores: p = exp(s - L) from the
+    saved logsumexp is exactly softmax without a second online pass;
+    dS = P_dropped dP - P rowsum(dO O), dP = dO V^T."""
+    p = jnp.exp(s - lse)
+    pd = p if drop is None else drop(p)
+    return _dscores(p, pd, do, v, dd)
+
+
+def _dscores(p, pd, do, v, dd):
+    dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    return pd * dpd - p * dd
+
+
+def _dkdv(s, q, v, do, lse, dd, drop=None):
+    """``(dK unscaled, dV)`` of (a part of) a tile from its scores."""
+    p = jnp.exp(s - lse)
+    pd = p if drop is None else drop(p)
+    lp = q.dtype
+    dv = jax.lax.dot_general(pd.astype(lp), do, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = _dscores(p, pd, do, v, dd)
+    return jax.lax.dot_general(ds.astype(lp), q, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32), dv
+
+
+def _dq_of(ds, k):
+    """dS K, unscaled."""
+    return jax.lax.dot_general(ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_emit(o_ref, lse_ref, acc_scr, m_scr, l_scr):
+    l = l_scr[...]
+    o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    lse_ref[0, 0] = m_scr[...] + jnp.log(l)           # [Tb, 1]
+
+
+def _flash_init(acc_scr, m_scr, l_scr):
+    m_scr[...] = jnp.full(m_scr.shape, -1e30, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
 
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                       lse_ref, acc_scr, m_scr, l_scr, *, scale, p_drop,
-                      n_heads, nq, nk, causal=False):
+                      n_heads, nq, nk):
     """Grid (B, H, nq, nk), k-tile fastest: classic online softmax. The
     (m, l, acc) carries live in VMEM scratch across the k-tile sweep; o
     and the row logsumexp L are written on the last k-tile. Dropout
     masks only the value accumulation — the denominator uses undropped
-    weights (same semantics as _blockwise_attention)."""
+    weights (same semantics as _blockwise_attention). Under ``causal``:
+    ``_flash_fwd_causal``."""
+    j = pl.program_id(3)
+    q = q_ref[0, 0]                                   # [Tb, d]
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    s = _scores(q, k, scale)
+    s = s + bias_ref[0, 0]                        # [1, Tb] row-broadcast
+    pl.when(j == 0)(lambda: _flash_init(acc_scr, m_scr, l_scr))
 
-    j, qi = _flash_ktile(nk, causal)
+    def drop(p):
+        b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        return _dropped(_flash_uniform(seed_ref, b, h, i, j, n_heads, nq,
+                                       nk, p.shape), p, p_drop)
 
-    def _tile():
-        q = q_ref[0, 0]                               # [Tb, d]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = s + bias_ref[0, 0]                    # [1, Tb] row-broadcast
-        if causal:
-            s = _causal_mask(s, qi * q.shape[0], j * k.shape[0])
+    _online_softmax(s, v, m_scr, l_scr, acc_scr, ...,
+                    drop if p_drop > 0.0 else None)
+    pl.when(j == nk - 1)(
+        lambda: _flash_emit(o_ref, lse_ref, acc_scr, m_scr, l_scr))
 
-        @pl.when(j == 0)
-        def _init():
-            m_scr[...] = jnp.full(m_scr.shape, -1e30, jnp.float32)
-            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-        m_prev = m_scr[...]                           # [Tb, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                        # [Tb, Tb]
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        if p_drop > 0.0:
-            b, h, i = (pl.program_id(0), pl.program_id(1),
-                       pl.program_id(2))
-            pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
-                                        n_heads, nq, nk))
-            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-            p = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+def _flash_fwd_causal(qt_ref, kt_ref, seed_ref, q_ref, k_ref, v_ref,
+                      bias_ref, o_ref, lse_ref, acc_scr, m_scr, l_scr, *,
+                      scale, p_drop, n_heads, nt, edge):
+    """Grid (B, H, nt(nt + 1) / 2) over the causal tiles row by row
+    (``_causal_steps``): a tile below the diagonal is one unmasked online
+    softmax step; the diagonal tile, last of its row, one step a row strip
+    of ``edge`` against the keys up to its diagonal block's end, masked by
+    index (as one part: two cost a second pass of row reductions, more
+    than the mask on the blocks before the diagonal one); then o and L
+    are written."""
+    b, h, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    i, j = qt_ref[t], kt_ref[t]
+    tb = q_ref.shape[2]
 
-    if causal:      # j < 0: a step of the sweep that has no tile
-        pl.when(j >= 0)(_tile)
-    else:
-        _tile()
+    u = (_flash_uniform(seed_ref, b, h, i, j, n_heads, nt, nt, (tb, tb))
+         if p_drop > 0.0 else None)
 
-    @pl.when(j == (qi if causal else nk - 1))
-    def _emit():
-        l = l_scr[...]
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log(l)       # [Tb, 1]
+    pl.when(j == 0)(lambda: _flash_init(acc_scr, m_scr, l_scr))
+
+    @pl.when(j < i)
+    def _below():
+        s = _scores(q_ref[0, 0], k_ref[0, 0], scale) + bias_ref[0, 0]
+        _online_softmax(s, v_ref[0, 0], m_scr, l_scr, acc_scr, ...,
+                        _drop_by(u, p_drop))
+
+    @pl.when(j == i)
+    def _diagonal():
+        for o in range(0, tb, edge):
+            rows, keys = slice(o, o + edge), slice(0, o + edge)
+            s = (_scores(q_ref[0, 0, rows], k_ref[0, 0, keys], scale)
+                 + bias_ref[0, 0, :, keys])
+            _online_softmax(_causal_mask(s, o), v_ref[0, 0, keys], m_scr,
+                            l_scr, acc_scr, rows,
+                            _drop_by(u, p_drop, rows, keys))
+        _flash_emit(o_ref, lse_ref, acc_scr, m_scr, l_scr)
 
 
 def _flash_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                      lse_ref, dd_ref, dq_ref, dbias_ref, *, scale, p_drop,
-                     n_heads, nq, nk, causal=False):
+                     n_heads, nq, nk):
     """Split backward, half 1 — grid (B, H, nq, nk), k-tile fastest: the
     dq block (keyed on the q-tile) accumulates over consecutive k-tile
-    steps. Probabilities regenerate from the saved logsumexp: p =
-    exp(s - L) is exactly softmax without a second online pass. Also
-    emits per-(q-tile) dbias partials, reduced outside the kernel."""
+    steps. Probabilities regenerate from the saved logsumexp (``_ds``).
+    Also emits per-(q-tile, k-tile) dbias partials, reduced outside the
+    kernel. Under ``causal``: ``_flash_dq_causal``."""
+    j = pl.program_id(3)
+    q = q_ref[0, 0]                                   # [Tb, d]
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    do = do_ref[0, 0]
+    lse = lse_ref[0, 0]                               # [Tb, 1]
+    dd = dd_ref[0, 0]                                 # rowsum(do*o) [Tb, 1]
+    s = _scores(q, k, scale)
+    s = s + bias_ref[0, 0]
 
-    j, qi = _flash_ktile(nk, causal)
+    def drop(p):
+        b, h, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        return _dropped(_flash_uniform(seed_ref, b, h, i, j, n_heads, nq,
+                                       nk, p.shape), p, p_drop)
 
-    def _tile():
-        q = q_ref[0, 0]                               # [Tb, d]
+    ds = _ds(s, v, do, lse, dd, drop if p_drop > 0.0 else None)
+    dbias_ref[0, 0] = jnp.sum(ds, axis=0, keepdims=True)
+    contrib = _dq_of(ds, k) * scale
+
+    @pl.when(j == 0)
+    def _init():
+        dq_ref[0, 0] = contrib
+
+    @pl.when(j != 0)
+    def _acc():
+        dq_ref[0, 0] += contrib
+
+
+def _flash_dq_causal(qt_ref, kt_ref, seed_ref, q_ref, k_ref, v_ref,
+                     bias_ref, do_ref, lse_ref, dd_ref, dq_ref, dbias_ref, *,
+                     scale, p_drop, n_heads, nt, edge):
+    """Grid (B, H, nt(nt + 1) / 2) over the causal tiles row by row: dq
+    starts at 0 on a row's first tile and gathers every tile's part; the
+    diagonal tile's is gathered strip by strip, and so is its dbias
+    partial, column block by column block."""
+    b, h, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    i, j = qt_ref[t], kt_ref[t]
+    tb = q_ref.shape[2]
+
+    u = (_flash_uniform(seed_ref, b, h, i, j, n_heads, nt, nt, (tb, tb))
+         if p_drop > 0.0 else None)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, dq_ref.dtype)
+
+    @pl.when(j < i)
+    def _below():
         k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                           # [Tb, 1]
-        dd = dd_ref[0, 0]                             # rowsum(do*o) [Tb, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = s + bias_ref[0, 0]
-        if causal:
-            s = _causal_mask(s, qi * q.shape[0], j * k.shape[0])
-        p = jnp.exp(s - lse)                      # undropped softmax rows
-        if p_drop > 0.0:
-            b, h, i = (pl.program_id(0), pl.program_id(1),
-                       pl.program_id(2))
-            pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
-                                        n_heads, nq, nk))
-            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-            pd = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
-        else:
-            pd = p
-        dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        ds = pd * dpd - p * dd                        # [Tb, Tb]
+        s = _scores(q_ref[0, 0], k, scale) + bias_ref[0, 0]
+        ds = _ds(s, v_ref[0, 0], do_ref[0, 0], lse_ref[0, 0], dd_ref[0, 0],
+                 _drop_by(u, p_drop))
         dbias_ref[0, 0] = jnp.sum(ds, axis=0, keepdims=True)
-        contrib = jax.lax.dot_general(
-            ds.astype(q.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dq_ref[0, 0] += _dq_of(ds, k) * scale
 
-        @pl.when(j == 0)
-        def _init():
-            dq_ref[0, 0] = contrib
-
-        @pl.when(j != 0)
-        def _acc():
-            dq_ref[0, 0] += contrib
-
-    if causal:
-        pl.when(j >= 0)(_tile)
-    else:
-        _tile()
+    @pl.when(j == i)
+    def _diagonal():
+        dbias_ref[...] = jnp.zeros(dbias_ref.shape, dbias_ref.dtype)
+        for rows, parts in _diag_strips(tb, edge, rows=True):
+            q, do = q_ref[0, 0, rows], do_ref[0, 0, rows]
+            lse, dd = lse_ref[0, 0, rows], dd_ref[0, 0, rows]
+            dqs = []
+            for cols, diag in parts:
+                k = k_ref[0, 0, cols]
+                s = _scores(q, k, scale) + bias_ref[0, 0, :, cols]
+                ds = _ds(_causal_mask(s) if diag else s, v_ref[0, 0, cols],
+                         do, lse, dd, _drop_by(u, p_drop, rows, cols))
+                dbias_ref[0, 0, :, cols] += jnp.sum(ds, axis=0,
+                                                    keepdims=True)
+                dqs.append(_dq_of(ds, k))
+            dq_ref[0, 0, rows] += _total(dqs) * scale
 
 
 def _flash_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                        lse_ref, dd_ref, dk_ref, dv_ref, *, scale, p_drop,
-                       n_heads, nq, nk, causal=False):
+                       n_heads, nq, nk):
     """Split backward, half 2 — grid (B, H, nk, nq), q-tile fastest: the
     dk/dv blocks (keyed on the k-tile) accumulate over consecutive
     q-tile steps. The PRNG seed uses the same (i, j) formula as the
     forward, so the regenerated mask is bit-exact despite the
-    transposed grid order. Under ``causal`` the sweep's first j steps
-    (q-tiles wholly before the k-tile) are skipped, so the first tile
-    computed, which initialises dk and dv, is the diagonal one."""
-
+    transposed grid order. Under ``causal``: ``_flash_dkdv_causal``."""
     j, i = pl.program_id(2), pl.program_id(3)
-    first = j if causal else 0
+    q = q_ref[0, 0]                                   # [Tb, d]
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    do = do_ref[0, 0]
+    lse = lse_ref[0, 0]
+    dd = dd_ref[0, 0]
+    s = _scores(q, k, scale)
+    s = s + bias_ref[0, 0]
 
-    def _tile():
-        q = q_ref[0, 0]                               # [Tb, d]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        dd = dd_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = s + bias_ref[0, 0]
-        if causal:
-            s = _causal_mask(s, i * q.shape[0], j * k.shape[0])
-        p = jnp.exp(s - lse)
-        if p_drop > 0.0:
-            b, h = pl.program_id(0), pl.program_id(1)
-            pltpu.prng_seed(_flash_seed(seed_ref[0], b, h, i, j,
-                                        n_heads, nq, nk))
-            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-            pd = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
-        else:
-            pd = p
-        lp = q.dtype
-        dv = jax.lax.dot_general(pd.astype(lp), do,
-                                 (((0,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        dpd = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        ds = pd * dpd - p * dd
-        dk = jax.lax.dot_general(ds.astype(lp), q,
-                                 (((0,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
+    def drop(p):
+        b, h = pl.program_id(0), pl.program_id(1)
+        return _dropped(_flash_uniform(seed_ref, b, h, i, j, n_heads, nq,
+                                       nk, p.shape), p, p_drop)
 
-        @pl.when(i == first)
-        def _init():
-            dk_ref[0, 0] = dk
-            dv_ref[0, 0] = dv
+    dk, dv = _dkdv(s, q, v, do, lse, dd, drop if p_drop > 0.0 else None)
+    dk = dk * scale
 
-        @pl.when(i != first)
-        def _acc():
-            dk_ref[0, 0] += dk
-            dv_ref[0, 0] += dv
+    @pl.when(i == 0)
+    def _init():
+        dk_ref[0, 0] = dk
+        dv_ref[0, 0] = dv
 
-    if causal:
-        pl.when(i >= j)(_tile)
-    else:
-        _tile()
+    @pl.when(i != 0)
+    def _acc():
+        dk_ref[0, 0] += dk
+        dv_ref[0, 0] += dv
+
+
+def _flash_dkdv_causal(qt_ref, kt_ref, seed_ref, q_ref, k_ref, v_ref,
+                       bias_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref, *,
+                       scale, p_drop, n_heads, nt, edge):
+    """Grid (B, H, nt(nt + 1) / 2) over the causal tiles k-tile by k-tile,
+    each column from its diagonal tile down: the diagonal tile, worked in
+    column strips (``_diag_strips``), writes dk and dv; the tiles below
+    it add to them."""
+    b, h, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    i, j = qt_ref[t], kt_ref[t]
+    tb = q_ref.shape[2]
+
+    u = (_flash_uniform(seed_ref, b, h, i, j, n_heads, nt, nt, (tb, tb))
+         if p_drop > 0.0 else None)
+
+    @pl.when(j < i)
+    def _below():
+        q = q_ref[0, 0]
+        s = _scores(q, k_ref[0, 0], scale) + bias_ref[0, 0]
+        dk, dv = _dkdv(s, q, v_ref[0, 0], do_ref[0, 0], lse_ref[0, 0],
+                       dd_ref[0, 0], _drop_by(u, p_drop))
+        dk_ref[0, 0] += dk * scale
+        dv_ref[0, 0] += dv
+
+    @pl.when(j == i)
+    def _diagonal():
+        for cols, parts in _diag_strips(tb, edge, rows=False):
+            k, v = k_ref[0, 0, cols], v_ref[0, 0, cols]
+            bias = bias_ref[0, 0, :, cols]
+            dks, dvs = [], []
+            for rows, diag in parts:
+                q = q_ref[0, 0, rows]
+                s = _scores(q, k, scale) + bias
+                dk, dv = _dkdv(_causal_mask(s) if diag else s, q, v,
+                               do_ref[0, 0, rows], lse_ref[0, 0, rows],
+                               dd_ref[0, 0, rows],
+                               _drop_by(u, p_drop, rows, cols))
+                dks.append(dk)
+                dvs.append(dv)
+            dk_ref[0, 0, cols] = _total(dks) * scale
+            dv_ref[0, 0, cols] = _total(dvs)
 
 
 def _flash_specs(q, bias, causal, kfast=True, dv=None):
@@ -907,24 +1137,21 @@ def _flash_specs(q, bias, causal, kfast=True, dv=None):
     do; v and dv), which are ``qspec`` and ``kspec`` again where v is as
     wide as q and k:
     ``kfast`` has the k-tile fastest (forward, dq), else the q-tile (dk/dv).
-    Under ``causal`` the steps of a sweep that have no tile come first
-    (``_flash_ktile``; in dk/dv the q-tiles before the k-tile) and name the
-    block of the sweep's first tile, so the pipeline fetches it once, under
-    the row before, and issues no DMA for them."""
+    Under ``causal`` the grid is ``(B, H, step)`` and a step's tiles are
+    the tables' (``_causal_steps``), whatever their order: one set of
+    specs serves the three kernels."""
     B, H, S, d = q.shape
     TB = _flash_block(S)
     nt = S // TB
     hb = bias.shape[1]
 
     def tiles(*g):      # grid indices -> (b, h, q-tile, k-tile) to fetch
-        b, h, i, j = g if kfast else (g[0], g[1], g[3], g[2])
-        if causal and kfast:
-            j = jnp.maximum(j - (nt - 1 - i), 0)
-        elif causal:
-            i = jnp.maximum(i, j)
-        return b, h, i, j
+        return g if kfast else (g[0], g[1], g[3], g[2])
 
     def spec(block, index):
+        if causal:
+            return pl.BlockSpec(block, lambda b, h, t, qt, kt: index(
+                b, h, qt[t], kt[t]))
         return pl.BlockSpec(block, lambda *g: index(*tiles(*g)))
 
     qspec = spec((1, 1, TB, d), lambda b, h, i, j: (b, h, i, 0))
@@ -946,73 +1173,114 @@ def _flash_specs(q, bias, causal, kfast=True, dv=None):
     return TB, nt, qspec, kspec, bspec, rowspec, dbpspec, ospec, vspec
 
 
+def _flash_kernel(plain, causal_kernel, causal, nt, edge, **kw):
+    """``plain`` over the grid ``(B, H, nt, nt)``, or ``causal_kernel``
+    over the causal steps, given ``kw``."""
+    if causal:
+        return functools.partial(causal_kernel, nt=nt, edge=edge, **kw)
+    return functools.partial(plain, nq=nt, nk=nt, **kw)
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_calls(shape, dtype, bias_heads, dv, scale, p_drop, causal, tb,
+                 edges, interpreted):
+    """The flash tier's three ``pallas_call``s of one signature - q's
+    ``shape`` and ``dtype``, the bias's head count, v's width ``dv`` - as
+    ``{kernel: (call, its leading operands)}``, built ONCE: a
+    ``pallas_call`` caches its trace on itself, so a step's layers, the
+    replay and the lowering walk each kernel body once (as
+    ``delta_rule._calls``). ``tb`` and ``edges`` (the diagonal strips'
+    edges, forward and backward, under ``causal``) follow from the shape,
+    and ``interpreted`` (``common.interpret()``) is read as the calls are
+    built: all three are part of the key only."""
+    B, H, S, d = shape
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    bias = jax.ShapeDtypeStruct((B, bias_heads, 1, S), jnp.float32)
+    TB, nt, qspec, kspec, bspec, rowspec, dbpspec, ospec, vspec = \
+        _flash_specs(q, bias, causal, dv=dv)
+    assert TB == tb, (TB, tb)
+    f32 = jnp.float32
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    def sweep(plain, causal_kernel, edge, out_shape, by_row=True, **specs):
+        """A sweep's kernel, ``pallas_call`` keywords and leading
+        operands."""
+        kw, steps = _sweep(causal, B, H, nt, by_row, **specs)
+        return _flash_kernel(plain, causal_kernel, causal, nt, edge,
+                             scale=scale, p_drop=p_drop, n_heads=H), dict(
+            kw, out_shape=out_shape,
+            compiler_params=_FLASH_COMPILER_PARAMS), steps
+
+    kernel, kw, steps = sweep(
+        _flash_fwd_kernel, _flash_fwd_causal, edges[0],
+        [jax.ShapeDtypeStruct((B, H, S, dv), dtype),
+         jax.ShapeDtypeStruct((B, H, S, 1), f32)],
+        in_specs=[smem, qspec, kspec, vspec, bspec],
+        out_specs=[ospec, rowspec],
+        scratch_shapes=[pltpu.VMEM((TB, dv), f32), pltpu.VMEM((TB, 1), f32),
+                        pltpu.VMEM((TB, 1), f32)])
+    calls = {"fwd": (_kernel_call("attn_flash_fwd", kernel, **kw), steps)}
+    kernel, kw, steps = sweep(
+        _flash_dq_kernel, _flash_dq_causal, edges[1],
+        [jax.ShapeDtypeStruct(shape, f32),
+         jax.ShapeDtypeStruct((B, H * nt, 1, S), f32)],
+        in_specs=[smem, qspec, kspec, vspec, bspec, ospec, rowspec, rowspec],
+        out_specs=[qspec, dbpspec])
+    calls["dq"] = (_kernel_call("attn_flash_bwd_dq", kernel, **kw), steps)
+    # the k-tile is the SLOW tile dim (transposed grid; under causal the
+    # tables' order) so dk/dv accumulate over consecutive q-tile steps
+    if not causal:
+        _, _, qspec, kspec, bspec, rowspec, _, ospec, vspec = _flash_specs(
+            q, bias, causal, kfast=False, dv=dv)
+    kernel, kw, steps = sweep(
+        _flash_dkdv_kernel, _flash_dkdv_causal, edges[1],
+        [jax.ShapeDtypeStruct(shape, f32),
+         jax.ShapeDtypeStruct((B, H, S, dv), f32)], by_row=False,
+        in_specs=[smem, qspec, kspec, vspec, bspec, ospec, rowspec, rowspec],
+        out_specs=[kspec, vspec])
+    calls["dkv"] = (_kernel_call("attn_flash_bwd_dkv", kernel, **kw), steps)
+    return calls
+
+
+def _flash_call(which, q, v, bias, scale, p_drop, causal):
+    """``(call, leading operands)`` of flash kernel ``which`` for these
+    inputs, its work counted."""
+    B, H, S, d = q.shape
+    tb = _flash_block(S)
+    edges = (_diag_edge(tb, d, forward=True), _diag_edge(tb, d)) \
+        if causal else (None, None)
+    _count_flash_tiles(S // tb, causal)
+    if causal:
+        _count_causal_work("flash", S // tb, tb,
+                           edges[0 if which == "fwd" else 1])
+    call, steps = _flash_calls(q.shape, q.dtype, bias.shape[1], v.shape[-1],
+                               scale, p_drop, causal, tb, edges,
+                               _interpret())[which]
+    return call, [jnp.asarray(t) for t in steps]
+
+
 def _pallas_attention_flash(q, k, v, bias, scale, p_drop, seed,
                             causal=False):
     """Returns (o, lse): lse [B, H, S] f32 feeds the split backward."""
 
     _count_kernel("flash")
-    B, H, S, d = q.shape
-    dv = v.shape[-1]        # v's own width; q's and k's is d
-    TB, nt, qspec, kspec, bspec, rowspec, _, ospec, vspec = _flash_specs(
-        q, bias, causal, dv=dv)
-    _count_flash_tiles(nt, causal)
-    f32 = jnp.float32
-    return _kernel_call(
-        "attn_flash_fwd",
-        functools.partial(_flash_fwd_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=H, nq=nt, nk=nt, causal=causal),
-        grid=(B, H, nt, nt),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  qspec, kspec, vspec, bspec],
-        out_specs=[ospec, rowspec],
-        out_shape=[jax.ShapeDtypeStruct((B, H, S, dv), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, 1), f32)],
-        scratch_shapes=[pltpu.VMEM((TB, dv), f32),
-                        pltpu.VMEM((TB, 1), f32),
-                        pltpu.VMEM((TB, 1), f32)],
-        compiler_params=_FLASH_COMPILER_PARAMS,
-    )(seed, q, k, v, bias)
+    call, steps = _flash_call("fwd", q, v, bias, scale, p_drop, causal)
+    return call(*steps, seed, q, k, v, bias)
 
 
 def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
                                 p_drop, causal=False):
     _count_kernel("flash_bwd")
     B, H, S, d = q.shape
-    TB, nt, qspec, kspec, bspec, rowspec, dbpspec, ospec, vspec = \
-        _flash_specs(q, bias, causal, dv=v.shape[-1])
-    _count_flash_tiles(nt, causal, sites=2)     # dq, dk/dv
+    TB = _flash_block(S)
+    nt = S // TB
     f32 = jnp.float32
     dd = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1,
                  keepdims=True)                            # [B, H, S, 1]
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    dq, dbp = _kernel_call(
-        "attn_flash_bwd_dq",
-        functools.partial(_flash_dq_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=H, nq=nt, nk=nt, causal=causal),
-        grid=(B, H, nt, nt),
-        in_specs=[smem, qspec, kspec, vspec, bspec, ospec, rowspec,
-                  rowspec],
-        out_specs=[qspec, dbpspec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
-                   jax.ShapeDtypeStruct((B, H * nt, 1, S), f32)],
-        compiler_params=_FLASH_COMPILER_PARAMS,
-    )(seed, q, k, v, bias, do, lse, dd)
-    # transposed grid: k-tile is the SLOW tile dim so dk/dv accumulate
-    # over consecutive q-tile steps
-    _, _, qspec_t, kspec_t, bspec_t, rowspec_t, _, ospec_t, vspec_t = \
-        _flash_specs(q, bias, causal, kfast=False, dv=v.shape[-1])
-    dk, dv = _kernel_call(
-        "attn_flash_bwd_dkv",
-        functools.partial(_flash_dkdv_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=H, nq=nt, nk=nt, causal=causal),
-        grid=(B, H, nt, nt),
-        in_specs=[smem, qspec_t, kspec_t, vspec_t, bspec_t, ospec_t,
-                  rowspec_t, rowspec_t],
-        out_specs=[kspec_t, vspec_t],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
-                   jax.ShapeDtypeStruct(v.shape, f32)],
-        compiler_params=_FLASH_COMPILER_PARAMS,
-    )(seed, q, k, v, bias, do, lse, dd)
+    call, steps = _flash_call("dq", q, v, bias, scale, p_drop, causal)
+    dq, dbp = call(*steps, seed, q, k, v, bias, do, lse, dd)
+    call, steps = _flash_call("dkv", q, v, bias, scale, p_drop, causal)
+    dk, dv = call(*steps, seed, q, k, v, bias, do, lse, dd)
     dbp = dbp.reshape(B, H, nt, S)
     if causal:      # the partials of the tiles skipped were never written
         dbp = jnp.where(jnp.arange(S)[None, :] // TB
@@ -1035,10 +1303,12 @@ def _pallas_attention_flash_bwd(q, k, v, bias, seed, do, o, lse, scale,
 # 0 / -1e30 form and the K/V tile are fetched and made once a step and
 # serve the group, so the selection is read Hkv times a call, not H
 # times, and K and V are never repeated to the Q head count. Causal only
-# (a learned selection picks among the keys before the query): the tiles
-# wholly above the diagonal are skipped as in the flash tier
-# (``_flash_ktile``), and column > row is masked beside the selection.
-# No bias and no dropout (a caller with either gets an error).
+# (a learned selection picks among the keys before the query): the grid
+# walks the causal tiles alone, in the flash tier's orders
+# (``_causal_steps``), and the diagonal tile in its strips
+# (``_diag_strips``), column > row masked in the strips' diagonal blocks
+# beside the selection. No bias and no dropout (a caller with either gets
+# an error).
 # ---------------------------------------------------------------------------
 # Largest first: measured v5e B=1, H=32, Hkv=4, S=16384, d=128 (PR 32), ms a
 # call forward / dq / dk-dv: 17.4 / 20.0 / 25.9 at Tb=1024, 29.8 / 21.5 / 31.5
@@ -1100,58 +1370,68 @@ def count_select_pairs(S, topk, sites=1):
             labels={"kind": kind}).inc(n * sites)
 
 
-def _select_neg(sel_ref, neg_scr, qi, j):
+def _neg(keep):
+    return jnp.where(keep, 0.0, -1e30).astype(jnp.float32)
+
+
+def _select_neg(sel_ref, neg_scr, edge=None):
     """The step's selection tile as what is added to a score: 0 where the
-    query may see the key (selected, and column <= row), else -1e30; made
-    once, read by every head."""
-    keep = sel_ref[0].astype(jnp.int32) != 0
-    tb = keep.shape[0]
-    rows = qi * tb + jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)
-    cols = j * tb + jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
-    keep = jnp.logical_and(keep, cols <= rows)
-    neg_scr[...] = jnp.where(keep, 0.0, -1e30).astype(jnp.float32)
+    query may see the key, else -1e30; made once, read by every head.
+    With ``edge`` (the diagonal tile) only its strips' parts are made
+    (``_diag_strips``), column > row masked in their diagonal blocks."""
+    if edge is None:
+        neg_scr[...] = _neg(sel_ref[0].astype(jnp.int32) != 0)
+        return
+    for rows, parts in _diag_strips(neg_scr.shape[0], edge, rows=True):
+        for cols, diag in parts:
+            keep = sel_ref[0, rows, cols].astype(jnp.int32) != 0
+            if diag:
+                keep = jnp.logical_and(keep, (
+                    jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
+                    <= jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)))
+            neg_scr[rows, cols] = _neg(keep)
 
 
-def _select_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
-                       neg_scr, acc_scr, m_scr, l_scr, *, scale, G, nk):
-    """Grid (B, H/G, nq, nk), k-tile fastest: the flash forward for each
-    of the step's G heads in turn. m starts at -1e29, above a masked
-    score: a row that has seen no selected key yet keeps p = exp(-1e30 +
-    1e29) = 0 and l = 0, and the first selected key's corr = exp(-1e29 -
-    m) = 0 starts it."""
-    j, qi = _flash_ktile(nk, True)
+def _select_fwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, sel_ref, o_ref,
+                       lse_ref, neg_scr, acc_scr, m_scr, l_scr, *, scale, G,
+                       edge):
+    """Grid (B, H/G, nt(nt + 1) / 2) over the causal tiles row by row
+    (``_causal_steps``): the flash forward for each of the step's G heads
+    in turn, the diagonal tile strip by strip. m starts at -1e29, above a
+    masked score: a row that has seen no selected key yet keeps p =
+    exp(-1e30 + 1e29) = 0 and l = 0, and the first selected key's corr =
+    exp(-1e29 - m) = 0 starts it."""
+    t = pl.program_id(2)
+    i, j = qt_ref[t], kt_ref[t]
+    tb = neg_scr.shape[0]
 
-    @pl.when(j >= 0)
-    def _tile():
-        _select_neg(sel_ref, neg_scr, qi, j)
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, -1e29, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-        @pl.when(j == 0)
-        def _init():
-            m_scr[...] = jnp.full(m_scr.shape, -1e29, jnp.float32)
-            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    @pl.when(j < i)
+    def _below():
+        _select_neg(sel_ref, neg_scr)
 
         def head(g):
-            q, k, v = q_ref[0, g], k_ref[0, 0], v_ref[0, 0]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = s + neg_scr[...]
-            m_prev = m_scr[g]                             # [Tb, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[g] = l_scr[g] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[g] = acc_scr[g] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[g] = m_new
+            s = _scores(q_ref[0, g], k_ref[0, 0], scale) + neg_scr[...]
+            _online_softmax(s, v_ref[0, 0], m_scr, l_scr, acc_scr, g)
 
         _for_heads(G, head)
 
-    @pl.when(j == qi)
-    def _emit():
+    @pl.when(j == i)
+    def _diagonal():
+        _select_neg(sel_ref, neg_scr, edge)
+
         def head(g):
+            for o in range(0, tb, edge):
+                rows, keys = slice(o, o + edge), slice(0, o + edge)
+                s = (_scores(q_ref[0, g, rows], k_ref[0, 0, keys], scale)
+                     + neg_scr[rows, keys])
+                _online_softmax(s, v_ref[0, 0, keys], m_scr, l_scr, acc_scr,
+                                (g, rows))
             l = l_scr[g]
             o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
             lse_ref[0, g] = m_scr[g] + jnp.log(l)
@@ -1159,92 +1439,112 @@ def _select_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref,
         _for_heads(G, head)
 
 
-def _select_dq_kernel(q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref, dd_ref,
-                      dq_ref, neg_scr, *, scale, G, nk):
-    """Grid (B, H/G, nq, nk), k-tile fastest: dq of the step's G heads,
-    accumulated over the k-tile sweep; p = exp(s - L) from the saved
-    logsumexp, 0 where the selection masks."""
-    j, qi = _flash_ktile(nk, True)
+def _select_dq_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, sel_ref, do_ref,
+                      lse_ref, dd_ref, dq_ref, neg_scr, *, scale, G, edge):
+    """Grid (B, H/G, nt(nt + 1) / 2) over the causal tiles row by row: dq
+    of the step's G heads, gathered over the row's tiles; p = exp(s - L)
+    from the saved logsumexp, 0 where the selection masks."""
+    t = pl.program_id(2)
+    i, j = qt_ref[t], kt_ref[t]
+    tb = neg_scr.shape[0]
 
-    @pl.when(j >= 0)
-    def _tile():
-        _select_neg(sel_ref, neg_scr, qi, j)
+    @pl.when(j == 0)
+    def _init():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, dq_ref.dtype)
 
-        @pl.when(j == 0)
-        def _init():
-            dq_ref[...] = jnp.zeros(dq_ref.shape, dq_ref.dtype)
+    @pl.when(j < i)
+    def _below():
+        _select_neg(sel_ref, neg_scr)
 
         def head(g):
-            q, k, v, do = q_ref[0, g], k_ref[0, 0], v_ref[0, 0], do_ref[0, g]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            p = jnp.exp(s + neg_scr[...] - lse_ref[0, g])
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * dp - p * dd_ref[0, g]
-            dq_ref[0, g] += jax.lax.dot_general(
-                ds.astype(q.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            k = k_ref[0, 0]
+            s = _scores(q_ref[0, g], k, scale) + neg_scr[...]
+            ds = _ds(s, v_ref[0, 0], do_ref[0, g], lse_ref[0, g],
+                     dd_ref[0, g])
+            dq_ref[0, g] += _dq_of(ds, k) * scale
+
+        _for_heads(G, head)
+
+    @pl.when(j == i)
+    def _diagonal():
+        _select_neg(sel_ref, neg_scr, edge)
+
+        def head(g):
+            for rows, parts in _diag_strips(tb, edge, rows=True):
+                q, do = q_ref[0, g, rows], do_ref[0, g, rows]
+                lse, dd = lse_ref[0, g, rows], dd_ref[0, g, rows]
+                dqs = []
+                for cols, _ in parts:
+                    k = k_ref[0, 0, cols]
+                    s = _scores(q, k, scale) + neg_scr[rows, cols]
+                    dqs.append(_dq_of(_ds(s, v_ref[0, 0, cols], do, lse, dd),
+                                      k))
+                dq_ref[0, g, rows] += _total(dqs) * scale
 
         _for_heads(G, head)
 
 
-def _select_dkdv_kernel(q_ref, k_ref, v_ref, sel_ref, do_ref, lse_ref,
-                        dd_ref, dk_ref, dv_ref, neg_scr, *, scale, G):
-    """Grid (B, Hkv, nk, nq), q-tile fastest: dk and dv of the step's K/V
-    head, accumulated over the q-tile sweep and over its G query heads
-    (f32, [B, Hkv, S, d]: no repeated K/V to sum back). The sweep's first
-    j steps, above the diagonal, are skipped."""
-    j, i = pl.program_id(2), pl.program_id(3)
+def _select_dkdv_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, sel_ref, do_ref,
+                        lse_ref, dd_ref, dk_ref, dv_ref, neg_scr, *, scale, G,
+                        edge):
+    """Grid (B, Hkv, nt(nt + 1) / 2) over the causal tiles k-tile by
+    k-tile, each column from its diagonal tile down: dk and dv of the
+    step's K/V head, gathered over the column's tiles and over its G query
+    heads (f32, [B, Hkv, S, d]: no repeated K/V to sum back); the
+    diagonal tile in column strips."""
+    t = pl.program_id(2)
+    i, j = qt_ref[t], kt_ref[t]
+    tb = neg_scr.shape[0]
 
-    @pl.when(i >= j)
-    def _tile():
-        _select_neg(sel_ref, neg_scr, i, j)
-
-        @pl.when(i == j)
-        def _init():
-            dk_ref[...] = jnp.zeros(dk_ref.shape, dk_ref.dtype)
-            dv_ref[...] = jnp.zeros(dv_ref.shape, dv_ref.dtype)
+    @pl.when(j < i)
+    def _below():
+        _select_neg(sel_ref, neg_scr)
 
         def head(g):
-            q, k, v, do = q_ref[0, g], k_ref[0, 0], v_ref[0, 0], do_ref[0, g]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            p = jnp.exp(s + neg_scr[...] - lse_ref[0, g])
-            lp = q.dtype
-            dv_ref[0, 0] += jax.lax.dot_general(
-                p.astype(lp), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * dp - p * dd_ref[0, g]
-            dk_ref[0, 0] += jax.lax.dot_general(
-                ds.astype(lp), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            q = q_ref[0, g]
+            s = _scores(q, k_ref[0, 0], scale) + neg_scr[...]
+            dk, dv = _dkdv(s, q, v_ref[0, 0], do_ref[0, g], lse_ref[0, g],
+                           dd_ref[0, g])
+            dv_ref[0, 0] += dv
+            dk_ref[0, 0] += dk * scale
+
+        _for_heads(G, head)
+
+    @pl.when(j == i)
+    def _diagonal():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, dk_ref.dtype)
+        dv_ref[...] = jnp.zeros(dv_ref.shape, dv_ref.dtype)
+        _select_neg(sel_ref, neg_scr, edge)
+
+        def head(g):
+            for cols, parts in _diag_strips(tb, edge, rows=False):
+                k, v = k_ref[0, 0, cols], v_ref[0, 0, cols]
+                dks, dvs = [], []
+                for rows, _ in parts:
+                    q = q_ref[0, g, rows]
+                    s = _scores(q, k, scale) + neg_scr[rows, cols]
+                    dk, dv = _dkdv(s, q, v, do_ref[0, g, rows],
+                                   lse_ref[0, g, rows], dd_ref[0, g, rows])
+                    dks.append(dk)
+                    dvs.append(dv)
+                dv_ref[0, 0, cols] += _total(dvs)
+                dk_ref[0, 0, cols] += _total(dks) * scale
 
         _for_heads(G, head)
 
 
-def _select_specs(q, k, kfast=True):
-    """Block specs of the select kernels' grid ``(B, Hkv, slow, fast)``,
-    with ``_flash_specs``' treatment of the steps that have no tile."""
+def _select_specs(q, k):
+    """Block specs of the select kernels' grid ``(B, Hkv, step)`` over the
+    causal tiles, a step's tiles from the tables (``_causal_steps``),
+    whatever their order."""
     B, H, S, d = q.shape
     G = _select_group(H, k.shape[1])
     TB = _select_block(S)
     nt = S // TB
 
-    def tiles(*g):      # grid indices -> (b, K/V head, q-tile, k-tile)
-        b, h, i, j = g if kfast else (g[0], g[1], g[3], g[2])
-        if kfast:
-            j = jnp.maximum(j - (nt - 1 - i), 0)
-        else:
-            i = jnp.maximum(i, j)
-        return b, h, i, j
-
     def spec(block, index):
-        return pl.BlockSpec(block, lambda *g: index(*tiles(*g)))
+        return pl.BlockSpec(block, lambda b, h, t, qt, kt: index(
+            b, h, qt[t], kt[t]))
 
     qspec = spec((1, G, TB, d), lambda b, h, i, j: (b, h, i, 0))
     kspec = spec((1, 1, TB, d), lambda b, h, i, j: (b, h, j, 0))
@@ -1253,60 +1553,79 @@ def _select_specs(q, k, kfast=True):
     return G, TB, nt, qspec, kspec, sspec, rowspec
 
 
+@functools.lru_cache(maxsize=None)
+def _select_calls(shape, kv_shape, dtype, scale, tb, edges, interpreted):
+    """The select tier's three ``pallas_call``s of one signature - q's
+    ``shape`` and ``dtype``, K/V's ``kv_shape`` - built ONCE, as
+    ``_flash_calls`` builds the flash tier's; ``tb``, ``edges`` and
+    ``interpreted`` are part of the key only."""
+    B, H, S, d = shape
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    G, TB, nt, qspec, kspec, sspec, rowspec = _select_specs(
+        q, jax.ShapeDtypeStruct(kv_shape, dtype))
+    assert TB == tb, (TB, tb)
+    f32 = jnp.float32
+    neg = pltpu.VMEM((TB, TB), f32)
+
+    def sweep(kernel, edge, out_shape, by_row=True, **specs):
+        """A sweep's kernel, ``pallas_call`` keywords and leading
+        operands."""
+        kw, steps = _sweep(True, B, H // G, nt, by_row, **specs)
+        return functools.partial(kernel, scale=scale, G=G, edge=edge), dict(
+            kw, out_shape=out_shape,
+            compiler_params=_SELECT_COMPILER_PARAMS), steps
+
+    kernel, kw, steps = sweep(
+        _select_fwd_kernel, edges[0],
+        [jax.ShapeDtypeStruct(shape, dtype),
+         jax.ShapeDtypeStruct((B, H, S, 1), f32)],
+        in_specs=[qspec, kspec, kspec, sspec], out_specs=[qspec, rowspec],
+        scratch_shapes=[neg, pltpu.VMEM((G, TB, d), f32),
+                        pltpu.VMEM((G, TB, 1), f32),
+                        pltpu.VMEM((G, TB, 1), f32)])
+    calls = {"fwd": (_kernel_call("attn_select_fwd", kernel, **kw), steps)}
+    bwd_in = [qspec, kspec, kspec, sspec, qspec, rowspec, rowspec]
+    kernel, kw, steps = sweep(
+        _select_dq_kernel, edges[1], jax.ShapeDtypeStruct(shape, f32),
+        in_specs=bwd_in, out_specs=qspec, scratch_shapes=[neg])
+    calls["dq"] = (_kernel_call("attn_select_bwd_dq", kernel, **kw), steps)
+    kernel, kw, steps = sweep(
+        _select_dkdv_kernel, edges[1],
+        [jax.ShapeDtypeStruct(kv_shape, f32)] * 2, by_row=False,
+        in_specs=bwd_in, out_specs=[kspec, kspec], scratch_shapes=[neg])
+    calls["dkv"] = (_kernel_call("attn_select_bwd_dkv", kernel, **kw),
+                    steps)
+    return calls
+
+
+def _select_call(which, q, k, scale):
+    """``(call, leading operands)`` of select kernel ``which`` for these
+    inputs, its work counted."""
+    _count_kernel("select")
+    S, d = q.shape[2:]
+    tb = _select_block(S)
+    edges = (_diag_edge(tb, d, forward=True), _diag_edge(tb, d))
+    _count_causal_work("select", S // tb, tb,
+                       edges[0 if which == "fwd" else 1],
+                       heads=_select_group(q.shape[1], k.shape[1]))
+    call, steps = _select_calls(q.shape, k.shape, q.dtype, scale, tb, edges,
+                                _interpret())[which]
+    return call, [jnp.asarray(t) for t in steps]
+
+
 def _pallas_attention_select(q, k, v, select, scale):
     """Returns (o, lse); k, v at their own head count [B, Hkv, S, d]."""
-    _count_kernel("select")
-    B, H, S, d = q.shape
-    G, TB, nt, qspec, kspec, sspec, rowspec = _select_specs(q, k)
-    f32 = jnp.float32
-    return _kernel_call(
-        "attn_select_fwd",
-        functools.partial(_select_fwd_kernel, scale=scale, G=G, nk=nt),
-        grid=(B, H // G, nt, nt),
-        in_specs=[qspec, kspec, kspec, sspec],
-        out_specs=[qspec, rowspec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, 1), f32)],
-        scratch_shapes=[pltpu.VMEM((TB, TB), f32),
-                        pltpu.VMEM((G, TB, d), f32),
-                        pltpu.VMEM((G, TB, 1), f32),
-                        pltpu.VMEM((G, TB, 1), f32)],
-        compiler_params=_SELECT_COMPILER_PARAMS,
-    )(q, k, v, select)
+    call, steps = _select_call("fwd", q, k, scale)
+    return call(*steps, q, k, v, select)
 
 
 def _pallas_attention_select_bwd(q, k, v, select, do, o, lse, scale):
-    _count_kernel("select")     # dq
-    _count_kernel("select")     # dk/dv
-    B, H, S, d = q.shape
-    G, TB, nt, qspec, kspec, sspec, rowspec = _select_specs(q, k)
     f32 = jnp.float32
     dd = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1, keepdims=True)
-    neg = pltpu.VMEM((TB, TB), f32)
-    dq = _kernel_call(
-        "attn_select_bwd_dq",
-        functools.partial(_select_dq_kernel, scale=scale, G=G, nk=nt),
-        grid=(B, H // G, nt, nt),
-        in_specs=[qspec, kspec, kspec, sspec, qspec, rowspec, rowspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, f32),
-        scratch_shapes=[neg],
-        compiler_params=_SELECT_COMPILER_PARAMS,
-    )(q, k, v, select, do, lse, dd)
-    _, _, _, qspec_t, kspec_t, sspec_t, rowspec_t = _select_specs(
-        q, k, kfast=False)
-    dk, dv = _kernel_call(
-        "attn_select_bwd_dkv",
-        functools.partial(_select_dkdv_kernel, scale=scale, G=G),
-        grid=(B, H // G, nt, nt),
-        in_specs=[qspec_t, kspec_t, kspec_t, sspec_t, qspec_t, rowspec_t,
-                  rowspec_t],
-        out_specs=[kspec_t, kspec_t],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, f32),
-                   jax.ShapeDtypeStruct(k.shape, f32)],
-        scratch_shapes=[neg],
-        compiler_params=_SELECT_COMPILER_PARAMS,
-    )(q, k, v, select, do, lse, dd)
+    call, steps = _select_call("dq", q, k, scale)
+    dq = call(*steps, q, k, v, select, do, lse, dd)
+    call, steps = _select_call("dkv", q, k, scale)
+    dk, dv = call(*steps, q, k, v, select, do, lse, dd)
     return dq, dk, dv
 
 

@@ -80,8 +80,9 @@ def test_backward_kernel_compiles_for_v5e_at_the_cells_shape(compiled_text):
 
 def test_causal_flash_kernels_compile_for_v5e_at_the_cells_shape(
         compiled_text, monkeypatch):
-    """Forward, dq and dk/dv with the tiles above the diagonal skipped:
-    the clamped block indices and the ``pl.when`` bodies pass Mosaic."""
+    """Forward, dq and dk/dv over the causal tiles alone: the
+    scalar-prefetched step tables and the diagonal tile's strips pass
+    Mosaic."""
     from paddle_tpu.kernels import attention as A
 
     monkeypatch.setattr(A, "_supports_pallas", lambda: True)
@@ -91,6 +92,27 @@ def test_causal_flash_kernels_compile_for_v5e_at_the_cells_shape(
             argnums=(0, 1, 2)), [(B, HA, S, DA)] * 3)
     for name in ("attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv"):
         assert name in text
+
+
+# lfm2-24b-a2b.train-s8192's attention layer: batch, heads (K/V repeated to
+# them), head dim
+LB, LH, LD = 4, 32, 64
+
+
+def test_causal_flash_kernels_at_d64_compile_for_v5e_at_the_cells_shape(
+        compiled_text, monkeypatch):
+    """At d = 64 the diagonal tile's strips slice 64-lane q, k and v
+    blocks: they pass Mosaic in all three kernels."""
+    from paddle_tpu.kernels import attention as A
+
+    monkeypatch.setattr(A, "_supports_pallas", lambda: True)
+    text = compiled_text(
+        jax.grad(lambda q, k, v: jnp.sum(A.fused_attention(
+            q, k, v, scale=LD ** -0.5, causal=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)), [(LB, LH, S, LD)] * 3)
+    for name in ("attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv"):
+        assert name in text
+    assert A._diag_edge(A._flash_block(S), LD) < A._flash_block(S)
 
 
 # keye-vl-2.0-30b-a3b.train-s16384: batch, Q heads, KV heads, sequence, head
