@@ -60,8 +60,9 @@ def _replay_forward_checkpointed(ctx, prior_ops, wrt_names, overrides,
     set the grad transform saves is those boundary values - and what a
     producer inside the segment has marked with
     ``kernels.common.keep_across_recompute`` (a kernel's output that is
-    dear to make and small to hold: attention's ``o`` and row logsumexp,
-    ``sparse_index``'s mask). Everything else in a segment is made again
+    dear to make for what it holds: attention's ``o`` and row logsumexp,
+    ``sparse_index``'s mask, the delta rule's ``o``, states and
+    inverses). Everything else in a segment is made again
     in the backward pass; a segment with no marked value lowers as under a
     bare ``jax.checkpoint``.
     """

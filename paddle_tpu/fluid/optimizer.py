@@ -879,11 +879,12 @@ class RecomputeOptimizer:
     op; its lowering wraps forward segments in ``jax.checkpoint`` so XLA
     rematerializes instead of saving activations. What survives a segment's
     boundary: the checkpoint vars, and the values a producer inside the
-    segment marked as dear to make and small to hold
+    segment marked as dear to make for what they hold
     (``kernels.common.keep_across_recompute``: the flash and select
     attention tiers' output and row logsumexp, ``sparse_index``'s mask,
     the expert layer's dispatch plan and the router's chosen scores and
-    ids) - the producer decides, there is nothing to set here."""
+    ids, the delta rule kernel's output, entering states and inverses) -
+    the producer decides, there is nothing to set here."""
 
     def __init__(self, optimizer):
         self._optimizer = optimizer

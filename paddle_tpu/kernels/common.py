@@ -70,15 +70,26 @@ def keep_across_recompute(x, what):
     marked anything.
 
     The rule a producer decides by: mark a value when a kernel or a
-    multi-pass op made it, it is deterministic given the segment's inputs,
-    and making it again costs well over 0.03 ms a MB held. The three
-    marked today (chip runs, PR 32): the select tier's ``o`` and row
-    logsumexp, 17.4 ms for 136 MB; ``sparse_index``'s byte mask, 10.6 ms
-    for 268 MB; the flash tier's ``o`` and logsumexp, 8.96 ms for 135 MB. A
-    projection's output costs far less a MB and is not marked. Since PR 37
-    also ``moe_experts``' dispatch plan (``moe_plan``: a layer's rows,
-    spans and weight table, 1-2.75 MB) and ``moe_route``'s chosen scores
-    and ids (``moe_route``: 1.45 ms of ``top_k`` for 1.3 MB).
+    multi-pass op made it and it is deterministic given the segment's
+    inputs, and then by one of two tests. A value that XLA ops make again
+    is marked where that costs well over 0.03 ms a MB held. The outputs of
+    a kernel call are weighed TOGETHER: where the kept set is everything
+    the replay wants of the call (its outputs used downstream and the
+    residuals its backward reads), keeping it takes the whole call out of
+    the replay, and the call's time is set against the step's free memory,
+    not against a per-MB rule. Marked today, with what remaking costs (chip
+    runs on a v5e): the select tier's ``o`` and row logsumexp, 17.4 ms for
+    136 MB; ``sparse_index``'s byte mask, 10.6 ms for 268 MB; the flash
+    tier's ``o`` and logsumexp, 8.96 ms for 135 MB; ``moe_experts``'
+    dispatch plan (``moe_plan``: a layer's rows, spans and weight table,
+    1-2.75 MB); ``moe_route``'s chosen scores and ids (``moe_route``: 1.45
+    ms of ``top_k`` for 1.3 MB); the delta rule's ``o``, the state
+    entering each 128-row group and the groups' inverses (``delta_rule``,
+    the whole forward kernel: 13.10 ms of ``kda_chunk_fwd`` for 671 MB a
+    layer at 16,384 positions and 32 heads of 128, 5.08 ms of
+    ``gdn_chunk_fwd`` for 671 MB at 2 x 8192 - 0.0195 and 0.0076 ms a MB,
+    under the first test). A projection's output costs far less a MB and
+    is not marked.
 
     ``recompute_kept_bytes_total{what}`` counts the bytes, once a site
     traced inside a checkpointed segment (trace-time, not per step)."""

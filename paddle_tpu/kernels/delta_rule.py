@@ -37,7 +37,11 @@ carried in VMEM. The forward it differentiates kept, a group, the state
 that entered it and its chunks' inverses (both f32); the rest of the group
 is recomputed and ``jax.vjp`` of the same group function gives the
 cotangents, so the derivation is the forward's own, and dq and dk arrive
-summed over the value heads of a key head.
+summed over the value heads of a key head. Inside a recomputed segment
+``o``, the states and the inverses are marked to be kept across it
+(``common.keep_across_recompute``): the replay then has no use for the
+forward kernel, which runs once a layer a step, and the backward reads
+what the primal pass made.
 
 THE CHANNEL-GATED RULE (Kimi Delta Attention: ``kda_chunk_fwd``,
 ``kda_chunk_bwd``; ``gated_delta_rule_pallas`` takes it where g comes ``[B,
@@ -80,7 +84,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret, named_pallas_call, supports_pallas
+from .common import (interpret, keep_across_recompute, named_pallas_call,
+                     supports_pallas)
 
 # the name of every ``pallas_call`` here: never ``attn_*`` (the attention
 # metrics read that prefix)
@@ -573,8 +578,11 @@ def _core(q3, k3, v3, gc, beta, dk, dv, C, eps):
 
 
 def _core_fwd(q3, k3, v3, gc, beta, dk, dv, C, eps):
-    o3, states, inverses = _call("fwd_keep", q3, v3, gc, dk, dv, C, eps)(
-        q3, k3, v3, gc, beta)
+    # a recomputed segment keeps all three: its replay then has no use for
+    # the forward kernel, and no forward call runs in it
+    o3, states, inverses = (
+        keep_across_recompute(x, "delta_rule") for x in
+        _call("fwd_keep", q3, v3, gc, dk, dv, C, eps)(q3, k3, v3, gc, beta))
     return o3, (q3, k3, v3, gc, beta, states, inverses)
 
 

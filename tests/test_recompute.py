@@ -383,3 +383,106 @@ def test_without_checkpoints_the_plans_mark_lowers_to_nothing(monkeypatch):
     tagged = _lowered(*_moe_program(False))
     monkeypatch.setattr(common, "keep_across_recompute", lambda x, what: x)
     assert _lowered(*_moe_program(False)) == tagged
+
+
+# -- the delta rule's residuals: the forward kernel once a layer-step --------
+# One layer of projections -> gated delta rule (head dims of 128, so the
+# Pallas kernels on the interpreter) -> output projection, a checkpoint
+# after it; the scalar rule (two value heads on one key head) and the
+# channel-gated rule (a decay a key channel, two heads).
+_DR_S, _DR_D, _DR_HV = 128, 128, 2
+_DR_HK = {"scalar": 1, "channel": 2}
+_DR_FWD = {"scalar": "gdn_chunk_fwd", "channel": "kda_chunk_fwd"}
+# a layer, a value head each: o in the program's float32, the state
+# entering each group of 128 positions and the group's inverses in float32
+_DR_KEPT_BYTES = 4 * _DR_HV * (_DR_S * _DR_D
+                               + _DR_S // 128 * (_DR_D * _DR_D + 128 * 128))
+
+
+def _delta_rule_program(rule, use_recompute):
+    def proj(x, width):
+        return layers.fc(x, width, num_flatten_dims=2, bias_attr=False)
+
+    def heads(x, n):
+        return layers.reshape(proj(x, n * _DR_D), [0, 0, n, _DR_D])
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = 5
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        h = layers.data("x", shape=[_DR_S, 32], dtype="float32")
+        hk = _DR_HK[rule]
+        gate = heads(h, _DR_HV) if rule == "channel" else proj(h, _DR_HV)
+        o = layers.gated_delta_rule(heads(h, hk), heads(h, hk),
+                                    heads(h, _DR_HV), gate, proj(h, _DR_HV))
+        h = h + proj(layers.reshape(o, [0, 0, _DR_HV * _DR_D]), 32)
+        loss = layers.mean(proj(h, 1))
+        opt = optimizer.SGD(learning_rate=0.1)
+        if use_recompute:
+            opt = optimizer.RecomputeOptimizer(opt)
+            opt._set_checkpoints([h])
+        opt.minimize(loss)
+    feed = {"x": np.random.RandomState(0).randn(1, _DR_S, 32).astype(
+        np.float32)}
+    return main, startup, loss, feed
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "bare"])
+@pytest.mark.parametrize("rule", ["scalar", "channel"])
+def test_the_delta_rule_kernel_runs_once_a_layer(interpreted_kernels,
+                                                 monkeypatch, rule, kept):
+    """Under a checkpoint the compiled step holds the forward kernel at one
+    site (the primal pass): its ``o``, entering states and inverses cross
+    the boundary, and the backward kernel reads them. Under the bare
+    checkpoint the replay runs the forward kernel again."""
+    import collections
+
+    import jax
+    from test_autodiff_one_forward import _count
+
+    from paddle_tpu.fluid import profiler
+
+    if not kept:
+        _bare_checkpoint(monkeypatch)
+    before = _kept_bytes("delta_rule")
+    program = _delta_rule_program(rule, True)
+    live = _live_jaxpr(*program)
+    assert _kept_bytes("delta_rule") - before == _DR_KEPT_BYTES
+    counts = collections.Counter()
+    _count(live, counts)
+    fwd = _DR_FWD[rule]
+    bwd = fwd.replace("_fwd", "_bwd")
+    assert counts[(fwd, False)] == 1
+    assert counts[(fwd, True)] == (0 if kept else 1)
+    assert counts[(bwd, True)] == 1
+    # the compiled step: a call site = the scope its instructions sit under
+    fn, args = _step_function(*program)
+    names = profiler.op_names_of(jax.jit(fn).lower(*args).compile()
+                                 .as_text())
+    mark = "/%s/" % fwd
+    sites = {n.split(mark)[0] for n in names.values() if mark in n}
+    assert len(sites) == (1 if kept else 2), sorted(sites)
+
+
+@pytest.mark.parametrize("rule", ["scalar", "channel"])
+def test_kept_delta_rule_residuals_change_no_number(interpreted_kernels,
+                                                    monkeypatch, rule):
+    _assert_as_under_the_bare_checkpoint(
+        monkeypatch, lambda: _delta_rule_program(rule, True), 8)
+
+
+@pytest.mark.parametrize("rule", ["scalar", "channel"])
+def test_without_checkpoints_the_delta_rule_mark_lowers_to_nothing(
+        interpreted_kernels, monkeypatch, rule):
+    from paddle_tpu.kernels import delta_rule
+
+    before = _kept_bytes("delta_rule")
+    tagged = _lowered(*_delta_rule_program(rule, False))
+    assert _kept_bytes("delta_rule") == before
+    monkeypatch.setattr(delta_rule, "keep_across_recompute",
+                        lambda x, what: x)
+    assert _lowered(*_delta_rule_program(rule, False)) == tagged
