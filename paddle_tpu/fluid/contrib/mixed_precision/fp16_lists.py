@@ -16,7 +16,6 @@ white_list = {
     # accumulation (softmax stays f32 internally), so bf16 inputs hit
     # the MXU at full rate
     "fused_multihead_attention",
-    "fused_multihead_attention_packed",
 }
 
 # numerically sensitive ops kept in fp32

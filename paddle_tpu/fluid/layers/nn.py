@@ -36,7 +36,7 @@ __all__ = [
     "hard_swish", "uniform_random", "gelu", "erf", "topk", "unique",
     "autoincreased_step_counter", "smooth_l1", "dice_loss", "py_func",
     "linear_chain_crf", "crf_decoding", "ctc_greedy_decoder",
-    "shard_tensor", "fused_attention", "fused_attention_packed",
+    "shard_tensor", "fused_attention",
     "einsum", "rms_norm", "swiglu", "rotary_embedding", "sparse_index",
     "causal_conv1d", "gated_short_conv",
     "gated_delta_rule", "moe_route", "moe_experts",
@@ -1673,28 +1673,6 @@ def einsum(equation, *operands, name=None):
                      inputs={"Operands": list(operands)},
                      outputs={"Out": [out]},
                      attrs={"equation": equation})
-    return out
-
-
-def fused_attention_packed(q, k, v, n_heads, attn_bias=None, scale=None,
-                           dropout_prob=0.0, is_test=False, name=None):
-    """Multi-head attention on PACKED [B, S, H*d] q/k/v — consumes the
-    QKV projections' native layout so the graph carries no head
-    split/merge transposes (those layout copies dominate small-S
-    attention cost); heads are strided inside one Pallas kernel per
-    batch block (kernels/attention.py packed tier). Returns
-    [B, S, H*d]."""
-    helper = LayerHelper("fused_multihead_attention_packed", **locals())
-    out = helper.create_variable_for_type_inference(q.dtype)
-    inputs = {"Q": [q], "K": [k], "V": [v]}
-    if attn_bias is not None:
-        inputs["Bias"] = [attn_bias]
-    attrs = {"dropout_prob": float(dropout_prob), "is_test": is_test,
-             "n_heads": int(n_heads)}
-    if scale is not None:
-        attrs["scale"] = float(scale)
-    helper.append_op(type="fused_multihead_attention_packed",
-                     inputs=inputs, outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

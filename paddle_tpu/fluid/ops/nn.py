@@ -745,28 +745,6 @@ def _fused_multihead_attention(ctx, op):
         causal=bool(op.attr("causal", False)), select=select))
 
 
-@register("fused_multihead_attention_packed", has_state=True)
-def _fused_multihead_attention_packed(ctx, op):
-    """Packed-layout ([B, S, H*d]) variant: heads strided inside the
-    kernel, no [B, H, S, d] transposes in the graph
-    (kernels/attention.py packed tier)."""
-    from ...kernels.attention import fused_attention_packed
-
-    q = ctx.get_input(op, "Q")
-    k = ctx.get_input(op, "K")
-    v = ctx.get_input(op, "V")
-    bias = ctx.get_input(op, "Bias")
-    p = float(op.attr("dropout_prob", 0.0))
-    is_test = bool(op.attr("is_test", False))
-    scale = op.attr("scale", None)
-    n_heads = int(op.attr("n_heads", 1))
-    drop = 0.0 if is_test else p
-    key = ctx.next_rng() if drop > 0.0 else None
-    ctx.set_output(op, "Out", fused_attention_packed(
-        q, k, v, bias, n_heads=n_heads, scale=scale, dropout_prob=drop,
-        rng_key=key))
-
-
 @register("sequence_parallel_attention", has_state=True)
 def _sequence_parallel_attention(ctx, op):
     """Long-context attention with the sequence dim sharded over the

@@ -13,10 +13,10 @@ Dropout inside the kernel draws from the TPU PRNG
 (``pltpu.prng_seed``/``prng_random_bits``) seeded per (batch, head); the
 backward reseeds identically, so the regenerated mask is bit-exact.
 
-Tier dispatch. ``PADDLE_TPU_ATTN_FORCE`` (read ONLY through
-``_attn_force()``) is the single authority that overrides every gate
-below; any value outside ``_ATTN_FORCE_VALUES`` raises instead of
-silently routing to the default tier.
+Tier dispatch reads what the call shows: shapes, dtypes, dropout and
+the platform. Tests reach a tier at a small shape by patching the
+thresholds below (``_MAX_FUSED_SEQ``, ``_MAX_LONG_SEQ``,
+``_FLASH_BLOCK_CANDIDATES``).
 
 Training attention, single chip (``fused_attention`` -> ``_fused``):
   S <= 1024  — batch-blocked kernel, full [S, S] score tile in VMEM.
@@ -26,9 +26,9 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       across the q-tile grid dim. Measured v5e BERT-base s=2048: 3.1x
       over the blockwise fallback (20k -> 63k tokens/sec), and +1.5%
       over the flash tier (r5 interleaved pairs: 64.3k vs 63.3k,
-      spread ±0.4%), so the tier stays. FORCE=flash bypasses it.
-  S > 4096 (or FORCE=flash) — flash tier (_flash_*): BOTH q and k are
-      tiled, so no VMEM term scales with S². The forward runs online
+      spread ±0.4%), so the tier stays.
+  S > 4096 — flash tier (_flash_*): BOTH q and k are tiled, so no
+      VMEM term scales with S². The forward runs online
       softmax over k-tiles in VMEM scratch and saves per-row logsumexp;
       the backward is the flash-attention-2 SPLIT pair — one kernel
       accumulates dq over k-tiles, a second accumulates dk/dv over
@@ -89,13 +89,9 @@ Training attention, single chip (``fused_attention`` -> ``_fused``):
       below, which is the kernels' oracle. Without ``select`` nothing
       here is reached.
 
-Packed layout (``fused_attention_packed``, FORCE=packed): q/k/v stay in
-the fc-native [B, S, H*d] layout with heads handled inside the kernel;
-dispatches resident head-pair tier, then chunked, then the fallback.
-
 Decode (``attention_with_cache``): q [B, H, 1, d] against a KV ring
 buffer [B, H, C, d].
-  C >= 1024 (or FORCE=decode) — Pallas decode tier
+  C >= 1024 — Pallas decode tier
       (_decode_fwd_kernel): online softmax over cache blocks with the
       per-sequence valid length in SMEM. Inference-only, no backward.
   fallback — masked-length one-pass reference (_ref_attention_cache).
@@ -104,7 +100,7 @@ Paged decode (``paged_attention_cache``): the KV cache is a SHARED pool
 [P, H, ptok, d] indexed by per-slot page tables [B, npages]
 (PagedAttention layout; ``paged_kv_cache_update`` is the block-granular
 scatter).
-  capacity >= 1024 (or FORCE=paged) — Pallas paged tier
+  capacity >= 1024 — Pallas paged tier
       (_paged_decode_fwd_kernel): the decode kernel's online softmax
       with the page table + lengths as SMEM scalar-prefetch operands —
       each K/V block DMAs straight from the pool row the table names,
@@ -113,8 +109,8 @@ scatter).
       masked-length reference: bit-identical to the dense ring path.
 
 Sequence-parallel (``sequence_parallel_attention``): S sharded over a
-mesh axis, selected per call (strategy attr / auto) with FORCE=ring |
-ulysses as the escape hatch.
+mesh axis, selected per call by the op's ``strategy`` attribute
+(``auto``: Ulysses when the axis divides the heads, else ring).
   ring — KV chunks rotate around ICI neighbors via ``lax.ppermute``
       inside ``shard_map``; each hop runs the flash forward
       (``_pallas_attention_flash``, when the chunk tiles) as the inner
@@ -126,26 +122,10 @@ ulysses as the escape hatch.
       runs FULL-sequence attention over H/n heads through the
       single-chip ``_fused`` dispatch above; auto-picked when the axis
       size divides H (ring is the general fallback).
-
-There is also a PACKED entry (``fused_attention_packed``): q/k/v in the
-fc-native [B, S, H*d] layout with heads handled inside the kernel,
-eliminating the head transposes from the graph. It dispatches to the
-RESIDENT head-pair tier (r5; see the resident section below) with the
-r4 chunked kernel as fallback. Honest status from v5e measurement at
-BERT-base b=128/s=128 — every in-kernel design loses to XLA's
-batched-GEMM chain end-to-end:
-  einsum chain 87-89 ms | resident 122 ms | per-head fused 126 ms |
-  packed-chunked 157 ms; ablation puts the attention core at ~16 ms of
-  the 88 ms step, so the chain leaves little on the table that kernel
-  relayout/latency costs don't eat.
-They are kept as correct, tested building blocks for shapes with
-larger S·heads per block; BERT's ``use_fused_attention="auto"`` picks
-the GEMM chain below S=256.
 """
 
 import functools
 import math
-import os
 
 import numpy as np
 
@@ -172,8 +152,6 @@ KERNEL_NAMES = (
     "attn_block_fwd", "attn_block_bwd", "attn_long_fwd", "attn_long_bwd",
     "attn_flash_fwd", "attn_flash_bwd_dq", "attn_flash_bwd_dkv",
     "attn_select_fwd", "attn_select_bwd_dq", "attn_select_bwd_dkv",
-    "attn_packed_fwd", "attn_packed_bwd",
-    "attn_res_fwd", "attn_res_bwd_dq", "attn_res_bwd_dkv",
     "attn_decode", "attn_paged")
 
 
@@ -210,25 +188,6 @@ def _count_flash_tiles(nt, causal):
             "skipped as wholly above the causal diagonal (trace-time: "
             "once a traced kernel site, not per step)",
             labels={"kind": kind}).inc(n)
-
-
-_ATTN_FORCE_VALUES = ("flash", "packed", "decode", "paged", "ring",
-                      "ulysses")
-
-
-def _attn_force():
-    """The ONE read site for the PADDLE_TPU_ATTN_FORCE escape hatch.
-
-    Returns "" (no forcing) or one of ``_ATTN_FORCE_VALUES``; any other
-    value raises instead of silently routing to the default tier (a typo
-    like FORCE=falsh used to measure exactly the path the user was
-    trying to bypass)."""
-    v = os.environ.get("PADDLE_TPU_ATTN_FORCE", "")
-    if v and v not in _ATTN_FORCE_VALUES:
-        raise ValueError(
-            "PADDLE_TPU_ATTN_FORCE=%r not understood; expected one of "
-            "%s (or unset)" % (v, ", ".join(_ATTN_FORCE_VALUES)))
-    return v
 
 
 def _uniform_from_bits(bits):
@@ -599,8 +558,6 @@ def _use_long_kernel(q, p_drop, bias):
     B, H, S, d = q.shape
     if not _supports_pallas():
         return False
-    if _attn_force() == "flash":
-        return False        # measurement escape hatch: skip to flash
     if not (_MAX_FUSED_SEQ < S <= _MAX_LONG_SEQ) or _long_qb(S, d) is None:
         return False
     if bias.shape[1] == 1 and bias.shape[2] != 1 and H > 1:
@@ -1674,531 +1631,6 @@ def _selected_bwd(scale, causal, res, do):
 _selected.defvjp(_selected_fwd, _selected_bwd)
 
 
-_PACKED_MAX_SEQ = 256  # past this even hc=1 chunks overflow the temp
-                       # budget (22 live [S, S] f32 tiles, _packed_hc)
-
-
-def _packed_hc(n_heads, S):
-    """Heads per inner chunk: largest divisor of H whose live
-    [hc, S, S] f32 score-family temporaries stay under 8 MB. Measured
-    anchor: 12 unchunked heads at S=128 allocated 17.45 MB of kernel
-    stack — ~22 live [S, S] f32 tiles per head once Mosaic's scheduler
-    is done, hence the 22x coefficient."""
-    for hc in range(n_heads, 0, -1):
-        if n_heads % hc:
-            continue
-        if 22 * hc * S * S * 4 <= 8 * 1024 * 1024:
-            return hc
-    return None
-
-
-def _packed_bb(B, S, HD, n_heads):
-    """Batch block for the packed kernels: largest divisor of B whose
-    backward DMA set (7 double-buffered [Bb, S, H, d] bf16 in/out blocks
-    + their in-VMEM transposed copies) plus the chunked ~8 MB temp
-    budget fits scoped VMEM. The backward bound is used for the forward
-    too so the dropout PRNG draw shapes line up (cf. _fwd_budget)."""
-    if _packed_hc(n_heads, S) is None:
-        return None
-    best = None
-    for bb in range(1, B + 1):
-        if B % bb:
-            continue
-        est = 42 * bb * S * HD + 8 * 1024 * 1024
-        if est <= 15 * 1024 * 1024:
-            best = bb
-    return best
-
-
-def _use_packed_kernel(q3, n_heads, p_drop, bias):
-    """Packed tier: q/k/v in the fc-native [B, S, H*d] layout, heads
-    looped inside the kernel. Kills BOTH failure modes of small-S
-    attention: the XLA chain's HBM-materialized [B,H,S,S] probability
-    tensors, and the layout copies/transposes the per-head kernel's
-    [B,H,S,d] operands force around every custom call."""
-    B, S, HD = q3.shape
-    if not _supports_pallas() or S > _PACKED_MAX_SEQ:
-        return False
-    if HD % n_heads or _packed_bb(B, S, HD, n_heads) is None:
-        return False
-    if bias.shape[2] != 1 or bias.shape[1] not in (1, n_heads):
-        return False
-    return not (_interpret() and p_drop > 0.0)
-
-
-def _split_heads_vmem(t):
-    """[Bb, S, H, d] -> [Bb*H, S, d] entirely in VMEM — ONE transpose per
-    operand (per-head lane slices of a packed [.., H*d] block at d=64
-    would trigger a sub-128-lane relayout for every head; splitting the
-    lane dim in-kernel is an unsupported Mosaic shape cast, so the 4D
-    view is bitcast OUTSIDE the kernel). Heads merge into the single
-    batch dim Mosaic's tpu.matmul supports."""
-    Bb, S, H, d = t.shape
-    return jnp.swapaxes(t, 1, 2).reshape(Bb * H, S, d)
-
-
-def _merge_heads_vmem(t, n_heads):
-    """Inverse of _split_heads_vmem: [Bb*H, S, d] -> [Bb, S, H, d]."""
-    BH, S, d = t.shape
-    Bb = BH // n_heads
-    return jnp.swapaxes(t.reshape(Bb, n_heads, S, d), 1, 2)
-
-
-def _packed_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
-                       scale, p_drop, n_heads):
-    """Grid (B/Bb,): one step = Bb batches, ALL heads, one multi-batch
-    dot over (Bb, H): scores -> softmax -> dropout -> PV with the
-    [Bb, H, S, S] tile never leaving VMEM; the head split/merge is an
-    in-VMEM relayout, so HBM only ever sees the packed layout."""
-
-    q = _split_heads_vmem(q_ref[...])             # [Bb*H, S, d], b-major
-    k = _split_heads_vmem(k_ref[...])
-    v = _split_heads_vmem(v_ref[...])
-    BH, S, d = q.shape
-    H = n_heads
-    hc = _packed_hc(H, S)
-    i = pl.program_id(0)
-    dn = (((2,), (2,)), ((0,), (0,)))
-    outs = []
-    for ci in range(BH // hc):
-        # contiguous (b, head-chunk) rows bound the live [hc, S, S] f32
-        # temporaries; leading-dim slices cost no relayout
-        b, c = (ci * hc) // H, (ci * hc) % H
-        rows = slice(ci * hc, (ci + 1) * hc)
-        qc, kc, vc = q[rows], k[rows], v[rows]
-        s = jax.lax.dot_general(qc, kc, dn,
-                                preferred_element_type=jnp.float32) * scale
-        bsl = (bias_ref[b, c:c + hc] if bias_ref.shape[1] > 1
-               else bias_ref[b, 0:1])               # [hc|1, 1, S]
-        s = s + bsl
-        m = jnp.max(s, axis=-1, keepdims=True)
-        e = jnp.exp(s - m)
-        p = e / jnp.sum(e, axis=-1, keepdims=True)
-        if p_drop > 0.0:
-            pltpu.prng_seed(seed_ref[0] + i * BH + ci)
-            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-            p = jnp.where(u >= p_drop, p / (1.0 - p_drop), 0.0)
-        outs.append(jax.lax.dot_general(
-            p.astype(vc.dtype), vc, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32))
-    o = jnp.concatenate(outs, axis=0)
-    o_ref[...] = _merge_heads_vmem(o, n_heads).astype(o_ref.dtype)
-
-
-def _packed_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                       dq_ref, dk_ref, dv_ref, dbias_ref, *, scale, p_drop,
-                       n_heads):
-    q = _split_heads_vmem(q_ref[...])             # [Bb*H, S, d], b-major
-    k = _split_heads_vmem(k_ref[...])
-    v = _split_heads_vmem(v_ref[...])
-    do = _split_heads_vmem(do_ref[...])
-    BH, S, d = q.shape
-    H = n_heads
-    Bb = BH // H
-    hc = _packed_hc(H, S)
-    i = pl.program_id(0)
-    per_head_bias = dbias_ref.shape[1] == n_heads
-    dn = (((2,), (2,)), ((0,), (0,)))
-    lp = q.dtype
-    dqs, dks, dvs, dbs = [], [], [], []
-    for ci in range(BH // hc):
-        b, c = (ci * hc) // H, (ci * hc) % H
-        rows = slice(ci * hc, (ci + 1) * hc)
-        qc, kc, vc, doc = q[rows], k[rows], v[rows], do[rows]
-        s = jax.lax.dot_general(qc, kc, dn,
-                                preferred_element_type=jnp.float32) * scale
-        bsl = (bias_ref[b, c:c + hc] if bias_ref.shape[1] > 1
-               else bias_ref[b, 0:1])
-        s = s + bsl
-        m = jnp.max(s, axis=-1, keepdims=True)
-        e = jnp.exp(s - m)
-        p = e / jnp.sum(e, axis=-1, keepdims=True)
-        if p_drop > 0.0:
-            pltpu.prng_seed(seed_ref[0] + i * BH + ci)  # fwd's stream
-            u = _uniform_from_bits(pltpu.prng_random_bits(p.shape))
-            keep = u >= p_drop
-            pd = jnp.where(keep, p / (1.0 - p_drop), 0.0)
-        else:
-            keep = None
-            pd = p
-        dv_ = jax.lax.dot_general(pd.astype(lp), doc,
-                                  (((1,), (1,)), ((0,), (0,))),
-                                  preferred_element_type=jnp.float32)
-        dpd = jax.lax.dot_general(doc, vc, (((2,), (2,)), ((0,), (0,))),
-                                  preferred_element_type=jnp.float32)
-        dp = dpd if keep is None else jnp.where(keep, dpd / (1.0 - p_drop),
-                                                0.0)
-        ds = p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))
-        ds_lp = ds.astype(lp)
-        dqs.append(jax.lax.dot_general(
-            ds_lp, kc, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale)
-        dks.append(jax.lax.dot_general(
-            ds_lp, qc, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale)
-        dvs.append(dv_)
-        dbs.append(jnp.sum(ds, axis=1))           # [hc, S]
-    dq = jnp.concatenate(dqs, axis=0)
-    dk = jnp.concatenate(dks, axis=0)
-    dv = jnp.concatenate(dvs, axis=0)
-    dq_ref[...] = _merge_heads_vmem(dq, n_heads).astype(dq_ref.dtype)
-    dk_ref[...] = _merge_heads_vmem(dk, n_heads).astype(dk_ref.dtype)
-    dv_ref[...] = _merge_heads_vmem(dv, n_heads).astype(dv_ref.dtype)
-    dsb = jnp.concatenate(dbs, axis=0).reshape(Bb, H, 1, S)
-    if per_head_bias:
-        dbias_ref[...] = dsb                      # [Bb, H, 1, S]
-    else:
-        dbias_ref[...] = jnp.sum(dsb, axis=1, keepdims=True)
-
-
-def _packed_specs4(B, S, H, d, bias, Bb):
-    # q/k/v ride as 4D [B, S, H, d] bitcast views (free outside the
-    # kernel): block minor dims (H, d) equal the array dims, satisfying
-    # the TPU block-shape rule, and the kernel's head transpose happens
-    # once per operand in VMEM
-    qspec = pl.BlockSpec((Bb, S, H, d), lambda i: (i, 0, 0, 0))
-    bspec = pl.BlockSpec((Bb, bias.shape[1], 1, S), lambda i: (i, 0, 0, 0))
-    return qspec, bspec
-
-
-def _pallas_attention_packed(q3, k3, v3, bias, scale, p_drop, seed,
-                             n_heads):
-    B, S, HD = q3.shape
-    d = HD // n_heads
-    Bb = _packed_bb(B, S, HD, n_heads)
-    qspec, bspec = _packed_specs4(B, S, n_heads, d, bias, Bb)
-    v4 = lambda t: t.reshape(B, S, n_heads, d)
-    o4 = _kernel_call(
-        "attn_packed_fwd",
-        functools.partial(_packed_fwd_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=n_heads),
-        grid=(B // Bb,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  qspec, qspec, qspec, bspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B, S, n_heads, d), q3.dtype),
-    )(seed, v4(q3), v4(k3), v4(v3), bias)
-    return o4.reshape(B, S, HD)
-
-
-def _pallas_attention_packed_bwd(q3, k3, v3, bias, seed, do, scale,
-                                 p_drop, n_heads):
-    B, S, HD = q3.shape
-    d = HD // n_heads
-    Bb = _packed_bb(B, S, HD, n_heads)
-    qspec, bspec = _packed_specs4(B, S, n_heads, d, bias, Bb)
-    dbias_shape = (B, bias.shape[1], 1, S)
-    v4 = lambda t: t.reshape(B, S, n_heads, d)
-    shape4 = jax.ShapeDtypeStruct((B, S, n_heads, d), q3.dtype)
-    dq, dk, dv, dbias = _kernel_call(
-        "attn_packed_bwd",
-        functools.partial(_packed_bwd_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=n_heads),
-        grid=(B // Bb,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  qspec, qspec, qspec, bspec, qspec],
-        out_specs=[qspec, qspec, qspec, bspec],
-        out_shape=[shape4, shape4, shape4,
-                   jax.ShapeDtypeStruct(dbias_shape, jnp.float32)],
-    )(seed, v4(q3), v4(k3), v4(v3), bias, v4(do))
-    return (dq.reshape(B, S, HD), dk.reshape(B, S, HD),
-            dv.reshape(B, S, HD), dbias)
-
-
-# -- RESIDENT tier: fc-native operands, per-(batch-block, head) grid ----
-#
-# Same batched-dot math as the fused tier (_attn_block_fwd/_bwd), but
-# the operands keep the layout the QKV projections produce: blocks span
-# ALL heads with an index map CONSTANT in the head grid dim, so each
-# q/k/v block DMAs once per batch block and is revisited across the H
-# head steps; the kernel extracts head h with a dynamic slice in VMEM.
-# No [B, H, S, d] relayout in the graph (the per-head tier's 16.5 ms of
-# copies) and no in-VMEM swapaxes + per-chunk python loop (the packed
-# tier's latency trap). The backward splits into a dq/dbias kernel and
-# a dk/dv kernel so each call's revisited in/out blocks fit VMEM.
-#
-# Mosaic constraints force the HEAD-PAIR design (measured on v5e):
-# dynamic lane offsets must be provable multiples of 128 and dynamic
-# sublane offsets multiples of 8, so neither a [B, S, H, d] view with a
-# dynamic head index (also NOT a free bitcast — Mosaic pads (H, d) =
-# (12, 64) to (16, 128)) nor a d=64-wide dynamic lane slice compiles.
-# A PAIR of heads is a 2d=128-wide dynamic lane slice (hp*128 —
-# provably aligned); the two 64-lane halves split with STATIC slices,
-# which Mosaic supports as an in-VMEM relayout. One grid step therefore
-# computes two heads.
-
-
-def _res_bb(B, S, HD, itemsize, n_io, n_live):
-    """Largest divisor of B whose revisited IO blocks (double-buffered
-    [Bb, S, HD] in the operand dtype) plus live f32 [Bb, S, S]
-    score-family temporaries stay inside the 13 MB acceptance bound
-    (16 MB scoped VMEM minus headroom; same bound the long tier uses)."""
-    best = None
-    for bb in range(1, B + 1):
-        if B % bb:
-            continue
-        est = n_io * bb * S * HD * itemsize * 2 + n_live * bb * S * S * 4
-        if est <= 13 * 1024 * 1024:
-            best = bb
-    return best
-
-
-def _res_blocks(B, S, HD, itemsize):
-    # ONE block size for every resident kernel: the dropout PRNG draw
-    # shape [Bb, S, S] per (b, h) stream must match between the forward
-    # and both backward kernels (the dk/dv call is the tightest: 6 io
-    # blocks, ~10 live tiles)
-    return _res_bb(B, S, HD, itemsize, n_io=6, n_live=10)
-
-
-def _use_res_kernel(q3, n_heads, p_drop, bias):
-    B, S, HD = q3.shape
-    if not _supports_pallas() or S > _MAX_FUSED_SEQ:
-        return False
-    if _attn_force() == "packed":
-        return False        # measurement/bypass hatch: old packed tier
-    d = HD // n_heads
-    # head pairs: 2d must hit the 128-lane alignment Mosaic can prove
-    if HD % n_heads or n_heads % 2 or (2 * d) % 128:
-        return False
-    if _res_blocks(B, S, HD, jnp.dtype(q3.dtype).itemsize) is None:
-        return False
-    if bias.shape[2] != 1 or bias.shape[1] not in (1, n_heads):
-        return False
-    return not (_interpret() and p_drop > 0.0)
-
-
-def _res_pair(ref, hp, d):
-    """Load the 128-lane-aligned head PAIR ``hp`` and split it into two
-    [Bb, S, d] halves (static sub-128 slices relayout in VMEM)."""
-
-    pair = ref[:, :, pl.dslice(hp * 2 * d, 2 * d)]
-    return pair[:, :, :d], pair[:, :, d:]
-
-
-def _res_put_pair(ref, hp, d, a, b):
-    ref[:, :, pl.dslice(hp * 2 * d, 2 * d)] = jnp.concatenate(
-        [a, b], axis=-1)
-
-
-def _res_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, *,
-                    scale, p_drop, n_heads, d):
-    b, hp = pl.program_id(0), pl.program_id(1)
-    qs = _res_pair(q_ref, hp, d)
-    ks = _res_pair(k_ref, hp, d)
-    vs = _res_pair(v_ref, hp, d)
-    outs = []
-    for j in (0, 1):
-        bias_b = _res_bias(bias_ref, j)
-        o = _attn_block_fwd(qs[j], ks[j], vs[j], bias_b, seed_ref,
-                            scale, p_drop, b * n_heads + hp * 2 + j)
-        outs.append(o.astype(o_ref.dtype))
-    _res_put_pair(o_ref, hp, d, outs[0], outs[1])
-
-
-def _res_bias(bias_ref, j):
-    # broadcast bias blocks are (Bb, 1, 1, S); per-head blocks carry the
-    # PAIR (Bb, 2, 1, S) and half j selects its head's row
-    if bias_ref.shape[1] == 2:
-        return bias_ref[:, j]
-    return bias_ref[:, 0]
-
-
-def _res_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                   dq_ref, dbias_ref, *, scale, p_drop, n_heads, d,
-                   acc_heads):
-    b, hp = pl.program_id(0), pl.program_id(1)
-    qs = _res_pair(q_ref, hp, d)
-    ks = _res_pair(k_ref, hp, d)
-    vs = _res_pair(v_ref, hp, d)
-    dos = _res_pair(do_ref, hp, d)
-    dqs, contribs = [], []
-    for j in (0, 1):
-        dq, _, _, ds = _attn_block_bwd(
-            qs[j], ks[j], vs[j], dos[j], _res_bias(bias_ref, j),
-            seed_ref, scale, p_drop, b * n_heads + hp * 2 + j)
-        dqs.append(dq.astype(dq_ref.dtype))
-        contribs.append(jnp.sum(ds, axis=1, keepdims=True))  # [Bb, 1, S]
-    _res_put_pair(dq_ref, hp, d, dqs[0], dqs[1])
-    if acc_heads:
-        both = contribs[0] + contribs[1]
-
-        @pl.when(hp == 0)
-        def _init():
-            dbias_ref[:, 0] = both
-
-        @pl.when(hp != 0)
-        def _acc():
-            dbias_ref[:, 0] += both
-    else:
-        dbias_ref[:, 0] = contribs[0]
-        dbias_ref[:, 1] = contribs[1]
-
-
-def _res_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                     dk_ref, dv_ref, *, scale, p_drop, n_heads, d):
-    b, hp = pl.program_id(0), pl.program_id(1)
-    qs = _res_pair(q_ref, hp, d)
-    ks = _res_pair(k_ref, hp, d)
-    vs = _res_pair(v_ref, hp, d)
-    dos = _res_pair(do_ref, hp, d)
-    dks, dvs = [], []
-    for j in (0, 1):
-        _, dk, dv, _ = _attn_block_bwd(
-            qs[j], ks[j], vs[j], dos[j], _res_bias(bias_ref, j),
-            seed_ref, scale, p_drop, b * n_heads + hp * 2 + j)
-        dks.append(dk.astype(dk_ref.dtype))
-        dvs.append(dv.astype(dv_ref.dtype))
-    _res_put_pair(dk_ref, hp, d, dks[0], dks[1])
-    _res_put_pair(dv_ref, hp, d, dvs[0], dvs[1])
-
-
-def _res_specs(q3, n_heads, bias):
-    B, S, HD = q3.shape
-    d = HD // n_heads
-    Bb = _res_blocks(B, S, HD, jnp.dtype(q3.dtype).itemsize)
-    grid = (B // Bb, n_heads // 2)
-    qspec = pl.BlockSpec((Bb, S, HD), lambda b, hp: (b, 0, 0))
-    per_head = bias.shape[1] > 1
-    bspec = pl.BlockSpec((Bb, 2 if per_head else 1, 1, S),
-                         lambda b, hp, _ph=per_head:
-                         (b, hp if _ph else 0, 0, 0))
-    return grid, qspec, bspec, d
-
-
-def _pallas_attention_res(q3, k3, v3, bias, scale, p_drop, seed, n_heads):
-    grid, qspec, bspec, d = _res_specs(q3, n_heads, bias)
-    return _kernel_call(
-        "attn_res_fwd",
-        functools.partial(_res_fwd_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=n_heads, d=d),
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  qspec, qspec, qspec, bspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-    )(seed, q3, k3, v3, bias)
-
-
-def _pallas_attention_res_bwd(q3, k3, v3, bias, seed, do, scale, p_drop,
-                              n_heads):
-    B, S, HD = q3.shape
-    grid, qspec, bspec, d = _res_specs(q3, n_heads, bias)
-    acc_heads = bias.shape[1] == 1
-    dbias_shape = (B, bias.shape[1], 1, S)
-    ops = (seed, q3, k3, v3, bias, do)
-    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
-                qspec, qspec, qspec, bspec, qspec]
-    dq, dbias = _kernel_call(
-        "attn_res_bwd_dq",
-        functools.partial(_res_dq_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=n_heads, d=d, acc_heads=acc_heads),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[qspec, bspec],
-        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-                   jax.ShapeDtypeStruct(dbias_shape, jnp.float32)],
-    )(*ops)
-    dk, dv = _kernel_call(
-        "attn_res_bwd_dkv",
-        functools.partial(_res_dkdv_kernel, scale=scale, p_drop=p_drop,
-                          n_heads=n_heads, d=d),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[qspec, qspec],
-        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-                   jax.ShapeDtypeStruct(q3.shape, q3.dtype)],
-    )(*ops)
-    return dq, dk, dv, dbias
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _packed(q3, k3, v3, bias, scale, p_drop, n_heads, seed):
-    if _use_res_kernel(q3, n_heads, p_drop, bias):
-        return _pallas_attention_res(q3, k3, v3, bias, scale, p_drop,
-                                     seed, n_heads)
-    if _use_packed_kernel(q3, n_heads, p_drop, bias):
-        return _pallas_attention_packed(q3, k3, v3, bias, scale, p_drop,
-                                        seed, n_heads)
-    return _packed_fallback(q3, k3, v3, bias, scale, p_drop, n_heads, seed)
-
-
-def _packed_fallback(q3, k3, v3, bias, scale, p_drop, n_heads, seed):
-    """Reshape/transpose into [B, H, S, d] and ride the per-head dispatch
-    (which itself falls back to jnp off-TPU)."""
-    B, S, HD = q3.shape
-    d = HD // n_heads
-
-    def split(t):
-        return jnp.transpose(t.reshape(B, S, n_heads, d), (0, 2, 1, 3))
-
-    o = _fused(split(q3), split(k3), split(v3), bias, scale, p_drop, seed)
-    return jnp.transpose(o, (0, 2, 1, 3)).reshape(B, S, HD)
-
-
-def _packed_fwd(q3, k3, v3, bias, scale, p_drop, n_heads, seed):
-    return (_packed(q3, k3, v3, bias, scale, p_drop, n_heads, seed),
-            (q3, k3, v3, bias, seed))
-
-
-def _packed_bwd(scale, p_drop, n_heads, res, do):
-    q3, k3, v3, bias, seed = res
-    if _use_res_kernel(q3, n_heads, p_drop, bias):
-        dq, dk, dv, dbias = _pallas_attention_res_bwd(
-            q3, k3, v3, bias, seed, do, scale, p_drop, n_heads)
-        return dq, dk, dv, dbias.astype(bias.dtype), _seed_ct(seed)
-    if _use_packed_kernel(q3, n_heads, p_drop, bias):
-        dq, dk, dv, dbias = _pallas_attention_packed_bwd(
-            q3, k3, v3, bias, seed, do, scale, p_drop, n_heads)
-        return dq, dk, dv, dbias.astype(bias.dtype), _seed_ct(seed)
-
-    def f(q_, k_, v_, b_):
-        return _packed_fallback(q_, k_, v_, b_, scale, p_drop, n_heads,
-                                seed)
-
-    _, vjp = jax.vjp(f, q3, k3, v3, bias)
-    dq, dk, dv, dbias = vjp(do)
-    return dq, dk, dv, dbias, _seed_ct(seed)
-
-
-_packed.defvjp(_packed_fwd, _packed_bwd)
-
-
-def fused_attention_packed(q, k, v, bias=None, n_heads=1, scale=None,
-                           dropout_prob=0.0, rng_key=None):
-    """Multi-head attention on PACKED [B, S, H*d] q/k/v (the layout the
-    QKV projections produce) — no head split/merge transposes in the
-    graph; the kernel strides over head slices in VMEM. bias
-    broadcastable [B, 1|H, 1, S] additive; returns [B, S, H*d]."""
-    B, S, HD = q.shape
-    d = HD // n_heads
-    scale, bias, seed = _prep_bias_seed(B, S, d, bias, scale,
-                                        dropout_prob, rng_key)
-    return _packed(q, k, v, bias, scale, float(dropout_prob),
-                   int(n_heads), seed)
-
-
-def _prep_bias_seed(B, S, d, bias, scale, dropout_prob, rng_key):
-    """Shared entry-point epilogue for fused_attention and
-    fused_attention_packed: default scale, f32 bias broadcast to the
-    batch, and the int32 dropout seed derived from rng_key — factored so
-    the two wrappers' dropout streams cannot drift apart."""
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
-    if bias is None:
-        bias = jnp.zeros((B, 1, 1, S), jnp.float32)
-    bias = jnp.broadcast_to(bias.astype(jnp.float32),
-                            (B, bias.shape[1], bias.shape[2], S))
-    if dropout_prob > 0.0:
-        if rng_key is None:
-            raise ValueError("dropout_prob > 0 requires rng_key")
-        seed = jax.random.randint(rng_key, (1,), 0, 2 ** 31 - 1,
-                                  dtype=jnp.int32)
-    else:
-        seed = jnp.zeros((1,), jnp.int32)
-    return float(scale), bias, seed
-
-
 def _batch_block(B, S, tile_budget):
     """Largest divisor of B whose [Bb, S, S] fp32 score tile stays under
     ``tile_budget`` bytes (the fwd kernel holds ~4 such temporaries, the
@@ -2377,9 +1809,20 @@ def fused_attention(q, k, v, bias=None, scale=None, dropout_prob=0.0,
         return _selected(q, k, v, select,
                          float(scale) if scale else 1.0 / math.sqrt(d),
                          bool(causal))
-    scale, bias, seed = _prep_bias_seed(B, S, d, bias, scale,
-                                        dropout_prob, rng_key)
-    return _fused(q, k, v, bias, scale, float(dropout_prob), seed,
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    if bias is None:
+        bias = jnp.zeros((B, 1, 1, S), jnp.float32)
+    bias = jnp.broadcast_to(bias.astype(jnp.float32),
+                            (B, bias.shape[1], bias.shape[2], S))
+    if dropout_prob > 0.0:
+        if rng_key is None:
+            raise ValueError("dropout_prob > 0 requires rng_key")
+        seed = jax.random.randint(rng_key, (1,), 0, 2 ** 31 - 1,
+                                  dtype=jnp.int32)
+    else:
+        seed = jnp.zeros((1,), jnp.int32)
+    return _fused(q, k, v, bias, float(scale), float(dropout_prob), seed,
                   bool(causal))
 
 
@@ -2459,14 +1902,9 @@ def _decode_kb(C):
 
 def _use_decode_kernel(k_cache):
     """Pallas decode tier: same dispatch shape as training attention —
-    the S>=1024 regime where the Pallas tiers win, with
-    PADDLE_TPU_ATTN_FORCE=decode as the escape hatch that forces the
-    kernel at any capacity (tests run it on CPU under interpret)."""
-    if not _supports_pallas():
-        return False
-    if _attn_force() == "decode":
-        return True
-    return k_cache.shape[2] >= _MAX_FUSED_SEQ
+    the capacity >= ``_MAX_FUSED_SEQ`` regime where the Pallas tiers
+    win."""
+    return _supports_pallas() and k_cache.shape[2] >= _MAX_FUSED_SEQ
 
 
 def _decode_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -2520,7 +1958,7 @@ def _pallas_attention_decode(q, k_cache, v_cache, cache_len, scale,
     C = k_cache.shape[2]
     KB = _decode_kb(C)
     if KB is None:
-        # odd/prime capacity (forced-kernel case): pad the cache to the
+        # a capacity no candidate divides (odd, prime): pad the cache to the
         # next 128 multiple — padded columns sit past the valid length
         # and mask out like any empty slot
         KB = _DECODE_KB_CANDIDATES[-1]
@@ -2632,14 +2070,9 @@ def gather_paged_cache(pool, page_table):
 
 def _use_paged_kernel(page_table, ptok):
     """Same dispatch shape as the dense decode tier: the big-capacity
-    regime (or FORCE=paged), gated on Pallas availability. The kernel
-    additionally needs the page size to tile the lane/sublane rules
-    (interpret mode is exempt, like every other tier)."""
-    if not _supports_pallas():
-        return False
-    if _attn_force() == "paged":
-        return True
-    return page_table.shape[1] * ptok >= _MAX_FUSED_SEQ
+    regime, gated on Pallas availability."""
+    return (_supports_pallas()
+            and page_table.shape[1] * ptok >= _MAX_FUSED_SEQ)
 
 
 def _paged_decode_fwd_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref,
@@ -3087,8 +2520,7 @@ def sequence_parallel_attention(q, k, v, n_heads, bias=None, mesh=None,
 
     strategy: "auto" picks ulysses when the axis size divides H (lower
     comm volume: 2 all_to_alls of activations vs n-1 K/V hops), ring
-    otherwise; PADDLE_TPU_ATTN_FORCE=ring|ulysses overrides everything.
-    With ``mesh=None`` (or no seq_axis in it) the same math runs
+    otherwise. With ``mesh=None`` (or no seq_axis in it) the same math runs
     single-shard with no collectives.
     """
     B, S, HD = q.shape
@@ -3120,9 +2552,6 @@ def sequence_parallel_attention(q, k, v, n_heads, bias=None, mesh=None,
     n = 1
     if mesh is not None and seq_axis in mesh.shape:
         n = int(mesh.shape[seq_axis])
-    force = _attn_force()
-    if force in ("ring", "ulysses"):
-        strategy = force
     if strategy == "auto":
         strategy = "ulysses" if H % n == 0 else "ring"
     if strategy not in ("ring", "ulysses"):

@@ -14,6 +14,10 @@ import math
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers, optimizer
 
+# attention takes the fused kernel from this length, XLA's einsum chain
+# below it (the measured choice: ROADMAP.md S1)
+_FUSED_MIN_SEQ = 256
+
 
 class BertConfig:
     def __init__(self, vocab_size=30522, hidden=768, n_layers=12, n_heads=12,
@@ -64,20 +68,7 @@ def _mha(x, attn_bias, cfg, prefix):
     v = layers.fc(x, h, num_flatten_dims=2, name=prefix + "_v",
                   param_attr=_tp_attr(cfg, "col"))
 
-    seq = x.shape[1]
-    use_fused = getattr(cfg, "use_fused_attention", "auto")
-    if use_fused == "auto":
-        # measured on v5e: at S=128 the XLA einsum-GEMM path wins — the
-        # fused per-head kernel drowns in layout glue (126 ms step vs
-        # 86) and the packed kernel in per-chunk latency (157 ms); from
-        # S>=256 the in-VMEM fusion pays for itself
-        use_fused = seq >= 256
-    if use_fused == "packed":
-        # q/k/v stay in the fc-native [B, S, H*d] layout end to end
-        ctx = layers.fused_attention_packed(
-            q, k, v, n_heads, attn_bias,
-            dropout_prob=cfg.attn_dropout or 0.0)
-    elif use_fused:
+    if x.shape[1] >= _FUSED_MIN_SEQ:
         # one pallas kernel per (batch-block, head): scores/softmax/
         # dropout/PV stay in VMEM (jnp fallback off-TPU) —
         # paddle_tpu/kernels/attention.py

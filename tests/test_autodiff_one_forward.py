@@ -21,14 +21,14 @@ from test_sparse import _build_emb_sgd
 SEQ = 64
 
 
-def _bert_train_program(mode):
+def _bert_train_program(mode, monkeypatch):
     """``BertConfig.tiny()`` pretraining on the fused (Pallas) attention, as
     ``bert.build_pretrain_program`` builds it but for the optimizer's
     wrapper; ``recompute`` checkpoints layer 0's output: two segments, the
     first rematerialized in the backward."""
     cfg = bert.BertConfig.tiny()
     cfg.hidden_dropout = cfg.attn_dropout = 0.0   # no interpreter PRNG
-    cfg.use_fused_attention = True
+    monkeypatch.setattr(bert, "_FUSED_MIN_SEQ", SEQ)
     n_pred = bert.max_predictions(SEQ)
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = 7
@@ -79,7 +79,7 @@ def _live_counts(main, startup, loss, feed):
 @pytest.mark.parametrize("mode", ["plain", "amp", "recompute"])
 def test_the_step_holds_one_forward(mode, monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
-    cfg, main, startup, loss = _bert_train_program(mode)
+    cfg, main, startup, loss = _bert_train_program(mode, monkeypatch)
     counts = _live_counts(main, startup, loss,
                           bert.synthetic_batch(cfg, 2, SEQ))
     # q, k, v, out, ffn1, ffn2 a layer, the MLM transform and the decoder
@@ -188,7 +188,7 @@ def test_the_bypass_keeps_the_primal_forward(monkeypatch):
     holds the second forward again."""
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     _primal_bound(monkeypatch)
-    cfg, main, startup, loss = _bert_train_program("plain")
+    cfg, main, startup, loss = _bert_train_program("plain", monkeypatch)
     counts = _live_counts(main, startup, loss,
                           bert.synthetic_batch(cfg, 2, SEQ))
     assert counts[("attn_block_fwd", False)] == 2 * cfg.n_layers

@@ -14,20 +14,6 @@ from paddle_tpu.fluid import monitor
 pytestmark = pytest.mark.decode
 
 
-# -- PADDLE_TPU_ATTN_FORCE centralization ----------------------------------
-def test_attn_force_rejects_unknown_values(monkeypatch):
-    from paddle_tpu.kernels import attention
-
-    monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "banana")
-    with pytest.raises(ValueError, match="banana"):
-        attention._attn_force()
-    for ok in ("flash", "packed", "decode"):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", ok)
-        assert attention._attn_force() == ok
-    monkeypatch.delenv("PADDLE_TPU_ATTN_FORCE")
-    assert attention._attn_force() == ""
-
-
 # -- ring-buffer cache update ----------------------------------------------
 def test_kv_cache_update_ring_wraparound():
     from paddle_tpu.kernels.attention import kv_cache_update
@@ -98,8 +84,8 @@ def test_cache_attention_matches_full_recompute():
 
 @pytest.mark.parametrize("C", [7, 13, 128, 256])
 def test_pallas_decode_kernel_matches_fallback(monkeypatch, C):
-    """PADDLE_TPU_ATTN_FORCE=decode + PALLAS_INTERPRET=1 exercises the
-    Pallas decode tier on CPU — including prime/odd capacities, which
+    """The capacity threshold patched to C + PALLAS_INTERPRET=1 exercises
+    the Pallas decode tier on CPU — including prime/odd capacities, which
     take the pad-to-128 path."""
     from paddle_tpu.kernels import attention
 
@@ -112,7 +98,9 @@ def test_pallas_decode_kernel_matches_fallback(monkeypatch, C):
     # one partially-filled sequence, one wrapped past capacity
     lens = np.array([max(1, C // 2), C + 3], np.int32)
     want = np.asarray(attention.attention_with_cache(q, k, v, lens))
-    monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "decode")
+    assert not attention._use_decode_kernel(k)
+    monkeypatch.setattr(attention, "_MAX_FUSED_SEQ", C)
+    assert attention._use_decode_kernel(k)
     got = np.asarray(attention.attention_with_cache(q, k, v, lens))
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
 

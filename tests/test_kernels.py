@@ -452,121 +452,62 @@ class TestFlashKernels:
                                        rtol=3e-4, atol=3e-5)
 
 
-@pytest.mark.skipif(not _supports_pallas(), reason="no pallas")
-class TestPackedKernels:
-    """Packed-layout tier (fused_attention_packed): [B, S, H*d] q/k/v,
-    heads split/merged inside the kernel (interpret mode runs the real
-    body; off-TPU the wrapper falls back through the per-head dispatch)."""
+def _dispatch_case(A, monkeypatch, tier):
+    """The call that, at its shape, takes ``tier`` (None: the jnp
+    fallback) with the thresholds patched down to interpreter sizes."""
+    rng = np.random.RandomState(17)
 
-    def _setup(self, bias_shape):
-        from paddle_tpu.kernels import attention as A
+    def mk(*shape):
+        return jnp.asarray((rng.randn(*shape) * 0.4).astype(np.float32))
 
-        rng = np.random.RandomState(13)
-        b, s, h, d = 4, 64, 3, 8
-        hd = h * d
-        q = jnp.asarray((rng.randn(b, s, hd) * 0.4).astype(np.float32))
-        k = jnp.asarray((rng.randn(b, s, hd) * 0.4).astype(np.float32))
-        v = jnp.asarray((rng.randn(b, s, hd) * 0.4).astype(np.float32))
-        bias = np.zeros(bias_shape, np.float32)
-        bias[..., -5:] = -1e4
-        return A, q, k, v, jnp.asarray(bias), h, d
-
-    def _ref(self, A, q, k, v, bias, h, d):
-        B, S, HD = q.shape
-
-        def split(t):
-            return jnp.transpose(t.reshape(B, S, h, d), (0, 2, 1, 3))
-
-        o = A._ref_attention(split(q), split(k), split(v), bias,
-                             1.0 / np.sqrt(d), 0.0,
-                             jnp.zeros((1,), jnp.int32))
-        return jnp.transpose(o, (0, 2, 1, 3)).reshape(B, S, HD)
-
-    @pytest.mark.parametrize("bias_shape", [(4, 1, 1, 64), (4, 3, 1, 64)])
-    def test_forward_and_grads_match_reference(self, bias_shape):
-        A, q, k, v, bias, h, d = self._setup(bias_shape)
-        assert A._use_packed_kernel(q, h, 0.0, bias)
-        out = A.fused_attention_packed(q, k, v, bias, n_heads=h)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(self._ref(A, q, k, v, bias, h, d)),
-            rtol=2e-4, atol=2e-5)
-
-        gp = jax.grad(lambda *a: (A.fused_attention_packed(
-            *a, n_heads=h) ** 2).sum(), argnums=(0, 1, 2, 3))(q, k, v, bias)
-        gr = jax.grad(lambda *a: (self._ref(A, *a, h, d) ** 2).sum(),
-                      argnums=(0, 1, 2, 3))(q, k, v, bias)
-        for a, b in zip(gp, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=3e-4, atol=1e-4)
-
-    def test_layer_through_program(self):
-        """fused_multihead_attention_packed drives through a Program and
-        its grads flow (packed layout end to end, no transposes)."""
-        import paddle_tpu.fluid as fluid
-        from paddle_tpu.fluid import layers
-
-        b, s, h, d = 2, 32, 2, 8
-        rng = np.random.RandomState(5)
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(main, startup):
-            q = layers.data("q", shape=[b, s, h * d], dtype="float32")
-            w = layers.create_parameter(
-                [h * d], "float32",
-                default_initializer=fluid.initializer.Constant(1.0))
-            out = layers.fused_attention_packed(q, q, q * w, h)
-            loss = layers.reduce_mean(out * out)
-            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
-        assert any(op.type == "fused_multihead_attention_packed"
-                   for op in main.blocks[0].ops)
-        exe = fluid.Executor()
-        feed = {"q": rng.randn(b, s, h * d).astype(np.float32)}
-        with fluid.scope_guard(fluid.Scope()):
-            exe.run(startup)
-            (l0,) = exe.run(main, feed=feed, fetch_list=[loss])
-            assert np.isfinite(np.asarray(l0)).all()
+    if tier in ("decode", "paged"):
+        b, h, d, ptok, npages = 2, 2, 8, 8, 4
+        monkeypatch.setattr(A, "_MAX_FUSED_SEQ", npages * ptok)
+        q, lens = mk(b, h, 1, d), np.array([5, 29], np.int32)
+        if tier == "decode":
+            return lambda: A.attention_with_cache(
+                q, mk(b, h, npages * ptok, d), mk(b, h, npages * ptok, d),
+                lens)
+        table = np.arange(1, b * npages + 1, dtype=np.int32).reshape(
+            b, npages)
+        pool = (mk(b * npages + 1, h, ptok, d),
+                mk(b * npages + 1, h, ptok, d))
+        return lambda: A.paged_attention_cache(q, *pool, table, lens)
+    # training attention: S alone walks block -> long -> flash -> fallback
+    s = {"block": 64, "long": 256, "flash": 128, "select": 128,
+         None: 96}[tier]
+    if tier == "long":
+        monkeypatch.setattr(A, "_MAX_FUSED_SEQ", 128)
+    elif tier in ("flash", None):
+        monkeypatch.setattr(A, "_MAX_FUSED_SEQ", 64)
+        monkeypatch.setattr(A, "_MAX_LONG_SEQ", 0)
+        monkeypatch.setattr(A, "_FLASH_BLOCK_CANDIDATES", (64,))
+    q, k, v = mk(1, 2, s, 8), mk(1, 2, s, 8), mk(1, 2, s, 8)
+    if tier == "select":
+        keep = np.tril(rng.randint(0, 2, (1, s, s))) | np.eye(s, dtype=int)
+        sel = jnp.asarray(keep.astype(np.int8))
+        return lambda: A.fused_attention(q, k, v, causal=True, select=sel)
+    return lambda: A.fused_attention(q, k, v)
 
 
 @pytest.mark.skipif(not _supports_pallas(), reason="no pallas")
-class TestResidentKernels:
-    """Resident tier: fc-native [B, S, H*d] operands, head-PAIR grid
-    (128-lane-aligned dynamic slices, static half splits in VMEM).
-    Gate needs even H and 2d % 128 == 0."""
+@pytest.mark.parametrize("tier", ["block", "long", "flash", "select",
+                                  "decode", "paged", None])
+def test_shape_alone_picks_tier(monkeypatch, tier):
+    """Each tier is reached by the call's shapes and the module's
+    thresholds alone: exactly its ``attn_kernel_dispatch_total{tier}``
+    moves (none for the jnp fallback, at a length no tile divides)."""
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.kernels import attention as A
 
-    def _setup(self, bias_shape):
-        from paddle_tpu.kernels import attention as A
+    def counts():
+        return {t: monitor.counter("attn_kernel_dispatch_total",
+                                   labels={"tier": t}).value
+                for t in A.KERNEL_TIERS}
 
-        rng = np.random.RandomState(29)
-        b, s, h, d = 4, 64, 4, 64
-        hd = h * d
-        mk = lambda: jnp.asarray((rng.randn(b, s, hd) * 0.4)
-                                 .astype(np.float32))
-        bias = np.zeros(bias_shape, np.float32)
-        bias[..., -5:] = -1e4
-        return A, mk(), mk(), mk(), jnp.asarray(bias), h, d
-
-    def _ref(self, A, q, k, v, bias, h, d):
-        B, S, HD = q.shape
-
-        def split(t):
-            return jnp.transpose(t.reshape(B, S, h, d), (0, 2, 1, 3))
-
-        o = A._ref_attention(split(q), split(k), split(v), bias,
-                             1.0 / np.sqrt(d), 0.0,
-                             jnp.zeros((1,), jnp.int32))
-        return jnp.transpose(o, (0, 2, 1, 3)).reshape(B, S, HD)
-
-    @pytest.mark.parametrize("bias_shape", [(4, 1, 1, 64), (4, 4, 1, 64)])
-    def test_matches_reference(self, bias_shape):
-        A, q, k, v, bias, h, d = self._setup(bias_shape)
-        assert A._use_res_kernel(q, h, 0.0, bias)
-        out = A.fused_attention_packed(q, k, v, bias, n_heads=h)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(self._ref(A, q, k, v, bias, h, d)),
-            rtol=2e-4, atol=2e-5)
-        gp = jax.grad(lambda *a: (A.fused_attention_packed(
-            *a, n_heads=h) ** 2).sum(), argnums=(0, 1, 2, 3))(q, k, v, bias)
-        gr = jax.grad(lambda *a: (self._ref(A, *a, h, d) ** 2).sum(),
-                      argnums=(0, 1, 2, 3))(q, k, v, bias)
-        for a, b in zip(gp, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=3e-4, atol=1e-4)
+    call = _dispatch_case(A, monkeypatch, tier)
+    before = counts()
+    jax.block_until_ready(call())
+    after = counts()
+    moved = {t for t in A.KERNEL_TIERS if after[t] != before[t]}
+    assert moved == ({tier} if tier else set()), moved

@@ -62,57 +62,19 @@ def _run(strategy, n_sp, bias, causal, p_drop=0.0, rng_key=None, n_dp=1):
         rng_key=rng_key, strategy=strategy)
 
 
-# -- dispatch: every advertised PADDLE_TPU_ATTN_FORCE value ---------------
-class TestAttnForceDispatch:
-    def test_bogus_value_enumerates_all(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "warp")
-        with pytest.raises(ValueError) as ei:
-            A._attn_force()
-        for v in A._ATTN_FORCE_VALUES:
-            assert v in str(ei.value)
-
-    def test_flash_skips_long_tier(self, monkeypatch):
-        q = jnp.zeros((1, 2, 2048, 64), jnp.float32)
-        bias = jnp.zeros((1, 1, 1, 2048), jnp.float32)
-        assert A._use_long_kernel(q, 0.0, bias)
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "flash")
-        assert not A._use_long_kernel(q, 0.0, bias)
-
-    def test_packed_skips_res_tier(self, monkeypatch):
-        q3 = jnp.zeros((2, 256, 4 * 64), jnp.float32)
-        bias = jnp.zeros((2, 1, 1, 256), jnp.float32)
-        assert A._use_res_kernel(q3, 4, 0.0, bias)
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "packed")
-        assert not A._use_res_kernel(q3, 4, 0.0, bias)
-        assert A._use_packed_kernel(q3, 4, 0.0, bias)
-
-    def test_decode_forces_kernel_at_any_capacity(self, monkeypatch):
-        small = jnp.zeros((1, 2, 64, 16), jnp.float32)
-        assert not A._use_decode_kernel(small)
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "decode")
-        assert A._use_decode_kernel(small)
-
-    def test_ring_forced_over_auto_ulysses(self, monkeypatch):
-        # H=4 divides n=4, so auto would pick ulysses; the force must
-        # route to ring — observable as ring hops on the counter
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "ring")
+# -- dispatch: the strategy argument, and auto's rule ----------------------
+class TestStrategyDispatch:
+    def test_auto_picks_ring_when_axis_does_not_divide_heads(self):
+        # H=4 and n=8: auto cannot split the heads, so it routes to ring
+        # — observable as ring hops on the counter
         hops = monitor.counter("attn_ring_hops_total")
         before = hops.value
-        _run("auto", 4, None, False)
-        assert hops.value == before + 3    # n - 1 hops per ring pass
+        _run("auto", 8, None, False)
+        assert hops.value == before + 7    # n - 1 hops per ring pass
 
-    def test_ulysses_forced_over_ring_arg(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "ulysses")
-        hops = monitor.counter("attn_ring_hops_total")
-        before = hops.value
-        _run("ring", 4, None, False)       # force beats the argument
-        assert hops.value == before        # no ring pass traced
-        assert monitor.gauge("attn_seq_shards").value == 4
-
-    def test_forced_ulysses_rejects_indivisible_heads(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "ulysses")
+    def test_forced_ulysses_rejects_indivisible_heads(self):
         with pytest.raises(ValueError, match="divide"):
-            _run("auto", 3, None, False)   # H=4, n=3
+            _run("ulysses", 3, None, False)   # H=4, n=3
 
 
 # -- numerics: sharded vs single-device ----------------------------------

@@ -223,12 +223,13 @@ def test_speculative_identity_compiles_and_acceptance_ceiling():
 # -- Pallas paged kernel ----------------------------------------------------
 def test_paged_kernel_matches_gather_oracle_at_odd_page_counts(
         monkeypatch):
-    """Force the Pallas paged tier (interpret mode on CPU) at odd and
-    prime pages-per-stream and check it against gather+dense-reference
-    — the exact fallback the sessions use below the kernel threshold."""
+    """Take the Pallas paged tier (interpret mode on CPU, the capacity
+    threshold patched low) at odd and prime pages-per-stream and check it
+    against gather+dense-reference — the exact fallback the sessions use
+    below the kernel threshold."""
     from paddle_tpu.kernels import attention as A
 
-    monkeypatch.setenv("PADDLE_TPU_ATTN_FORCE", "paged")
+    monkeypatch.setattr(A, "_MAX_FUSED_SEQ", 3 * 8)
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     B, H, d, ptok = 2, 2, 8, 8
     rng = np.random.RandomState(6)
@@ -247,7 +248,7 @@ def test_paged_kernel_matches_gather_oracle_at_odd_page_counts(
             q, k_pool, v_pool, table, lens))
         c1 = monitor.counter("attn_kernel_dispatch_total",
                              labels={"tier": "paged"}).value
-        assert c1 > c0, "forced paged tier fell back (npages=%d)" % npages
+        assert c1 > c0, "paged tier fell back (npages=%d)" % npages
         want = np.asarray(A._ref_attention_cache(
             q, A.gather_paged_cache(k_pool, table),
             A.gather_paged_cache(v_pool, table), lens,
